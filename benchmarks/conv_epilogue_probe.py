@@ -68,6 +68,8 @@ def epilogue_pallas(y, scale, bias, res, interpret=False):
 
 
 def main():
+    from mxnet_tpu import runtime
+    runtime.enable_compile_cache()
     platform = jax.devices()[0].platform
     on_tpu = platform == "tpu"
     if on_tpu:

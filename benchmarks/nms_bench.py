@@ -38,6 +38,8 @@ def _sequential_nms_one(rows, overlap_thresh, k):
 
 
 def main():
+    from mxnet_tpu import runtime
+    runtime.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=400)
     ap.add_argument("--batch", type=int, default=32)

@@ -38,6 +38,7 @@ class MLMWrapper(gluon.HybridBlock):
 
 
 def main():
+    mx.runtime.enable_compile_cache()
     logging.basicConfig(level=logging.INFO)
     p = argparse.ArgumentParser()
     p.add_argument("--model", default="bert_12_768_12")

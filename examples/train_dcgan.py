@@ -69,6 +69,7 @@ def real_batch(rng, n, size=16):
 
 
 def main():
+    mx.runtime.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=32)
     ap.add_argument("--steps", type=int, default=200)

@@ -19,6 +19,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 
 def main():
+    from mxnet_tpu import runtime
+    runtime.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=2)

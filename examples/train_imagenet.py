@@ -26,6 +26,7 @@ from mxnet_tpu.gluon.model_zoo import vision
 
 
 def main():
+    mx.runtime.enable_compile_cache()
     logging.basicConfig(level=logging.INFO)
     p = argparse.ArgumentParser()
     p.add_argument("--network", default="resnet50_v1")
@@ -67,7 +68,7 @@ def main():
     if args.model_parallel > 1:
         from mxnet_tpu.parallel import PartitionSpec as P
         rules = [(r".*dense\d+_weight", P("model", None)),
-                 (r".*stage4_.*conv\d+_weight", P("model", None, None,
+                 (r".*stage4_.*conv2d\d+_weight", P("model", None, None,
                                                   None))]
     trainer = parallel.ShardedTrainer(
         net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",

@@ -113,6 +113,7 @@ def train_module(args, train, val):
 
 
 def main():
+    mx.runtime.enable_compile_cache()
     logging.basicConfig(level=logging.INFO)
     p = argparse.ArgumentParser()
     p.add_argument("--data-dir", default=os.path.expanduser(

@@ -30,6 +30,7 @@ def make_batch(rng, batch_size, seq_len, vocab):
 
 
 def main():
+    mx.runtime.enable_compile_cache()
     logging.basicConfig(level=logging.INFO)
     p = argparse.ArgumentParser()
     p.add_argument("--vocab", type=int, default=64)

@@ -104,6 +104,7 @@ def run_moe(args):
 
 
 def main():
+    mx.runtime.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["pp", "moe"], default="pp")
     ap.add_argument("--vocab", type=int, default=64)

@@ -36,6 +36,7 @@ def synthetic_batch(rng, batch_size, size, classes):
 
 
 def main():
+    mx.runtime.enable_compile_cache()
     logging.basicConfig(level=logging.INFO)
     p = argparse.ArgumentParser()
     p.add_argument("--network", default="resnet18_v1")

@@ -63,6 +63,7 @@ class VAE(gluon.HybridBlock):
 
 
 def main():
+    mx.runtime.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=32)
     ap.add_argument("--steps", type=int, default=400)

@@ -70,6 +70,7 @@ def batchify(toks, batch, seq):
 
 
 def main():
+    mx.runtime.enable_compile_cache()
     logging.basicConfig(level=logging.INFO)
     p = argparse.ArgumentParser()
     p.add_argument("--vocab", type=int, default=50)

@@ -5,10 +5,10 @@ predicate — the same contracts the runtime enforces, reused at
 search time so the tuner can only propose configurations the runtime
 would accept:
 
-- Pallas block shapes must tile the kernel's 2D view exactly (the
-  ``grid=(r // br, c // bc)`` contract in pallas/kernels.py — a
-  non-divisor block would leave remainder rows unwritten, which is why
-  the kernels clamp invalid tuned blocks back to the default);
+- Pallas block shapes must be ones the chip's compiler takes
+  (``pallas.registry.block_ok``: last two dims multiples of (8, 128) or
+  the whole array dim — the kernels clamp any other tuned block back to
+  the default);
 - bucket lattices must keep :meth:`BucketGrid.grid_bound` under the
   compile budget (the PR-4 bounded-compile guarantee);
 - serving/router/decode scalars must stay in their documented ranges.
@@ -21,7 +21,7 @@ import itertools
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Optional, Tuple
 
-__all__ = ["Choice", "Space", "divisors", "pallas_block_space",
+__all__ = ["Choice", "Space", "pallas_block_space",
            "serving_space", "router_space", "decode_space",
            "bucket_space"]
 
@@ -111,44 +111,31 @@ class Space:
 # ---------------------------------------------------------------------------
 # concrete spaces
 # ---------------------------------------------------------------------------
-def divisors(n: int, cap: int, floor: int = 1) -> Tuple[int, ...]:
-    """Divisors of ``n`` in ``[floor, cap]`` — the exact-tiling domain
-    of a Pallas block axis."""
-    return tuple(d for d in range(1, min(int(n), int(cap)) + 1)
-                 if n % d == 0 and d >= floor)
-
-
-def _default_block(n: int, cap: int) -> int:
-    """Mirror of pallas/kernels.py ``_block``: largest divisor <= cap."""
-    for b in range(min(cap, n), 0, -1):
-        if n % b == 0:
-            return b
-    return 1
-
-
-def pallas_block_space(kernel: str, r: int, c: int, row_cap: int = 512,
-                       col_cap: int = 256) -> Space:
+def pallas_block_space(kernel: str, r: int, c: int) -> Space:
     """Block-shape space for one epilogue kernel at one (r, c) shape
-    class.  Validity = the kernel's own grid contract: each block axis
-    must divide its dim exactly (and a degenerate 1-wide minor block is
-    excluded — the repack-debt shapes perf_notes.md flags are exactly
-    the ones whose best divisor is tiny)."""
+    class.  Validity = the kernel's own contract (``block_ok``): each
+    block axis is a multiple of its alignment (8 rows, 128 lanes) up to
+    the built-in cap, or the whole dim."""
+    from ..pallas.registry import BLOCK_CAPS, block_ok, default_block
     r, c = int(r), int(c)
-    rows = divisors(r, row_cap) or (1,)
-    cols = divisors(c, col_cap) or (1,)
+    row_cap, col_cap = BLOCK_CAPS
+    rows = tuple(range(8, min(r, row_cap) + 1, 8)) + \
+        ((r,) if r <= row_cap and r % 8 else ())
+    cols = tuple(range(128, min(c, col_cap) + 1, 128)) + \
+        ((c,) if c <= col_cap and c % 128 else ())
+    br0, bc0 = default_block(r, c)
 
     def validate(cfg):
         br, bc = cfg["block_r"], cfg["block_c"]
-        if r % br or c % bc:
-            return f"block_not_divisor:{br}x{bc}_vs_{r}x{c}"
+        if not block_ok(r, c, br, bc):
+            return f"block_not_aligned:{br}x{bc}_vs_{r}x{c}"
         return None
 
     return Space(
         name=f"pallas:{kernel}:{r}x{c}",
         params={"block_r": Choice("block_r", rows),
                 "block_c": Choice("block_c", cols)},
-        default={"block_r": _default_block(r, row_cap),
-                 "block_c": _default_block(c, col_cap)},
+        default={"block_r": br0, "block_c": bc0},
         validate=validate,
         table_map={"block_r": ("pallas", f"{kernel}.{r}x{c}.block_r"),
                    "block_c": ("pallas", f"{kernel}.{r}x{c}.block_c")})

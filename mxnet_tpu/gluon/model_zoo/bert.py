@@ -90,7 +90,7 @@ class PositionwiseFFN(HybridBlock):
     Both halves ride the guarded pallas matmul-epilogue tier
     (docs/pallas.md): ffn_1's bias+gelu and ffn_2's bias+dropout each run
     as ONE pass over the matmul output (dropout-in-epilogue — the BERT
-    MFU lever, docs/roadmap.md items 3-4) instead of separate bias /
+    MFU lever, ROADMAP.md S3/S4) instead of separate bias /
     activation / mask ops. Same params, same math; non-fusable
     activations keep the classic layout."""
 

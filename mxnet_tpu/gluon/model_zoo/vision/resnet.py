@@ -1,6 +1,6 @@
 """ResNet V1/V2 families (ref: python/mxnet/gluon/model_zoo/vision/resnet.py).
 
-Same architecture taxonomy as the reference: BasicBlock for 18/34,
+Same architecture families as the reference: BasicBlock for 18/34,
 BottleNeck for 50/101/152, each in the V1 (post-activation, He 2015) and V2
 (pre-activation, He 2016) arrangement. TPU notes: channel-first NCHW layout
 at the API (matching the reference); XLA relayouts internally for the MXU,

@@ -101,7 +101,7 @@ class Dense(HybridBlock):
     one VMEM pass on TPU, the parity-gated XLA reference elsewhere.
     ``epilogue_dropout`` folds an inverted dropout (training only) into
     the same pass — the dropout-in-epilogue lever from
-    docs/roadmap.md items 3-4."""
+    ROADMAP.md S3/S4."""
 
     def __init__(self, units, activation=None, use_bias=True, flatten=True,
                  dtype="float32", weight_initializer=None,

@@ -32,44 +32,59 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import MXNetError
-from .registry import dispatch, register_kernel
+from .registry import (block_ok, default_block, dispatch,
+                       register_kernel)
 
 __all__ = ["fused_conv_epilogue", "fused_matmul_epilogue", "dropout_bits",
            "keep_threshold", "EPILOGUE_ACTS"]
 
 
-def _block(n, cap):
-    """Largest divisor of n that is <= cap (the grid must tile n exactly —
-    a floor-divided grid would leave the remainder rows unwritten)."""
-    for b in range(min(cap, n), 0, -1):
-        if n % b == 0:
-            return b
-    return 1
-
-
-def _block_pair(r, c, block, row_cap=512, col_cap=256):
-    """Resolve the (block_r, block_c) tiling for an (r, c) view. An
-    explicit/tuned ``block`` wins when it tiles the view exactly;
-    anything else clamps to the default — the epilogues are elementwise
-    over the tile grid, so every exact tiling is bit-identical, and a
-    non-divisor block (stale tuned table, wrong shape class) must degrade
-    to the default rather than leave remainder rows unwritten."""
+def _block_pair(r, c, block):
+    """Resolve the (block_r, block_c) tiling for an (r, c) view: an
+    explicit/tuned ``block`` where the chip's compiler takes it
+    (registry.block_ok), else the default. The grid is ``pl.cdiv`` — edge
+    blocks are padded on read and masked on write — and the epilogues are
+    elementwise, so every tiling gives bit-identical results."""
     if block is not None:
         try:
             br, bc = int(block[0]), int(block[1])
         except (TypeError, ValueError, IndexError):
             br = bc = 0
-        if 0 < br <= r and 0 < bc <= c and r % br == 0 and c % bc == 0:
+        if block_ok(r, c, br, bc):
             return br, bc
-    return _block(r, row_cap), _block(c, col_cap)
+    return default_block(r, c)
 
 
-def _act_fn(act_type):
+def _erf(x):
+    """erf for float32 from primitives the TPU kernel compiler lowers
+    (it has no ``erf``/``erfc``): the clamped rational approximation
+    x·P(x²)/Q(x²) that Eigen and XLA use for float32. Its error against
+    the exact function stays under 1e-6, so GELU built on it is inside
+    the epilogues' registered 1e-5 tolerance (tests/test_pallas.py)."""
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    p = jnp.float32(-2.72614225801306e-10)
+    for a in (2.77068142495902e-08, -2.10102402082508e-06,
+              -5.69250639462346e-05, -7.34990630326855e-04,
+              -2.95459980854025e-03, -1.60960333262415e-02):
+        p = p * x2 + jnp.float32(a)
+    q = jnp.float32(-1.45660718464996e-05)
+    for b in (-2.13374055278905e-04, -1.68282697438203e-03,
+              -7.37332916720468e-03, -1.42647390514189e-02):
+        q = q * x2 + jnp.float32(b)
+    return x * p / q
+
+
+def _act_fn(act_type, in_kernel=False):
+    """Activation by MXNet name. MXNet's ``gelu`` is the erf form; inside
+    a kernel it is built on :func:`_erf`, in the reference on XLA's own."""
     fns = {
         None: lambda x: x,
         "identity": lambda x: x,
         "relu": lambda x: jnp.maximum(x, 0.0),
-        "gelu": lambda x: jax.nn.gelu(x, approximate=False),
+        "gelu": ((lambda x: 0.5 * x * (1.0 + _erf(x * np.float32(0.5 ** 0.5))))
+                 if in_kernel else
+                 (lambda x: jax.nn.gelu(x, approximate=False))),
         "tanh": jnp.tanh,
         "sigmoid": jax.nn.sigmoid,
     }
@@ -83,19 +98,35 @@ def _act_fn(act_type):
 EPILOGUE_ACTS = ("identity", "relu", "gelu", "tanh", "sigmoid")
 
 
+def _out_struct(y, args):
+    """Output type of an elementwise epilogue over ``args``: y's shape and
+    dtype, varying over every manual mesh axis any operand varies over
+    (inside a ``shard_map`` the call has to say so; outside there are none)."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in args))
+    return jax.ShapeDtypeStruct(y.shape, y.dtype, vma=vma)
+
+
 def _vec_spec(shape, br, bc):
     """BlockSpec for a (1, C) column-broadcast or (R, 1) row-broadcast
     vector riding next to (br, bc) data blocks."""
     from jax.experimental import pallas as pl
-    if shape[0] == 1:
-        return pl.BlockSpec((1, bc), lambda i, j: (0, j))
-    return pl.BlockSpec((br, 1), lambda i, j: (i, 0))
+    if shape[1] == 1:       # (R, 1); a (1, C) vector has C >= 8 (supports)
+        return pl.BlockSpec((br, 1), lambda i, j: (i, 0))
+    return pl.BlockSpec((1, bc), lambda i, j: (0, j))
 
 
 def _check_vec(name, v, y):
     if v.shape not in ((1, y.shape[1]), (y.shape[0], 1)):
         return (f"shape:{name}{v.shape}_vs_y{y.shape} (want (1, C) or "
                 f"(R, 1))")
+    return _check_dtype(name, v)
+
+
+def _check_dtype(name, x):
+    """float32 and bfloat16 are the float types the chip's kernel compiler
+    loads as vectors; it refuses float16 ("Invalid vector type")."""
+    if x.dtype not in (jnp.float32, jnp.bfloat16):
+        return f"dtype:{name}_{x.dtype}"
     return None
 
 
@@ -127,7 +158,7 @@ def _conv_epilogue_call(y, scale, bias, res, act_type, interpret, block):
     from jax.experimental import pallas as pl
     r, c = y.shape
     br, bc = _block_pair(r, c, block)
-    act = _act_fn(act_type)
+    act = _act_fn(act_type, in_kernel=True)
     data = pl.BlockSpec((br, bc), lambda i, j: (i, j))
 
     def kernel(y_ref, s_ref, b_ref, *rest):
@@ -146,8 +177,9 @@ def _conv_epilogue_call(y, scale, bias, res, act_type, interpret, block):
         in_specs.append(data)
         args.append(res)
     return pl.pallas_call(
-        kernel, grid=(r // br, c // bc), in_specs=in_specs, out_specs=data,
-        out_shape=jax.ShapeDtypeStruct((r, c), y.dtype),
+        kernel, grid=(pl.cdiv(r, br), pl.cdiv(c, bc)), in_specs=in_specs,
+        out_specs=data,
+        out_shape=_out_struct(y, args),
         interpret=interpret)(*args)
 
 
@@ -202,18 +234,21 @@ def _conv_epilogue_supports(y, scale, bias, res=None, act_type="relu",
         return f"not_2d:{y.shape}"
     if y.size == 0:
         return "empty"
-    if not jnp.issubdtype(y.dtype, jnp.floating):
-        return f"dtype:{y.dtype}"
     if y.shape[1] < 8:
         return f"minor_dim_tiny:{y.shape[1]}"
+    bad = _check_dtype("y", y)
     for name, v in (("scale", scale), ("bias", bias)):
-        bad = _check_vec(name, v, y)
-        if bad:
-            return bad
+        bad = bad or _check_vec(name, v, y)
+    if bad:
+        return bad
     if scale.shape != bias.shape:
         return f"shape:scale{scale.shape}_vs_bias{bias.shape}"
-    if res is not None and res.shape != y.shape:
-        return f"shape:res{res.shape}_vs_y{y.shape}"
+    if res is not None:
+        if res.shape != y.shape:
+            return f"shape:res{res.shape}_vs_y{y.shape}"
+        bad = _check_dtype("res", res)
+        if bad:
+            return bad
     if act_type not in (None,) + EPILOGUE_ACTS:
         return f"act:{act_type}"
     return None
@@ -242,8 +277,8 @@ def _conv_epilogue_example():
         "RN50 conv-fusion bandwidth lever (docs/perf_notes.md; promoted "
         "from benchmarks/conv_epilogue_probe.py). scale/bias broadcast "
         "as (1, C) columns or (R, 1) rows. block=(br, bc) overrides the "
-        "default tiling (tuned tables; any exact tiling is bit-identical, "
-        "invalid blocks clamp to the default).",
+        "default tiling (tuned tables; every tiling is bit-identical, a "
+        "block the chip's compiler would refuse clamps to the default).",
     tune_key=_epilogue_tune_key)
 def _conv_epilogue_pallas(y, scale, bias, res=None, interpret=False,
                           act_type="relu", block=None):
@@ -286,7 +321,7 @@ def _matmul_epilogue_call(y, bias, bits, act_type, p, interpret, block):
     from jax.experimental import pallas as pl
     r, c = y.shape
     br, bc = _block_pair(r, c, block)
-    act = _act_fn(act_type)
+    act = _act_fn(act_type, in_kernel=True)
     data = pl.BlockSpec((br, bc), lambda i, j: (i, j))
     thresh = keep_threshold(p)
     inv = 1.0 / (1.0 - p) if p < 1.0 else 0.0
@@ -296,7 +331,8 @@ def _matmul_epilogue_call(y, bias, bits, act_type, p, interpret, block):
         out = act(y_ref[...].astype(jnp.float32)
                   + b_ref[...].astype(jnp.float32))
         if len(rest) == 2:
-            keep = rest[0][...] >= jnp.uint8(thresh)
+            # the chip has no uint8 vector compare: widen the bytes first
+            keep = rest[0][...].astype(jnp.int32) >= thresh
             out = jnp.where(keep, out * inv, 0.0)
         o_ref[...] = out.astype(o_ref.dtype)
 
@@ -306,8 +342,9 @@ def _matmul_epilogue_call(y, bias, bits, act_type, p, interpret, block):
         in_specs.append(data)
         args.append(bits)
     return pl.pallas_call(
-        kernel, grid=(r // br, c // bc), in_specs=in_specs, out_specs=data,
-        out_shape=jax.ShapeDtypeStruct((r, c), y.dtype),
+        kernel, grid=(pl.cdiv(r, br), pl.cdiv(c, bc)), in_specs=in_specs,
+        out_specs=data,
+        out_shape=_out_struct(y, args),
         interpret=interpret)(*args)
 
 
@@ -361,11 +398,9 @@ def _matmul_epilogue_supports(y, bias, bits=None, act_type="gelu", p=0.0,
         return f"not_2d:{y.shape}"
     if y.size == 0:
         return "empty"
-    if not jnp.issubdtype(y.dtype, jnp.floating):
-        return f"dtype:{y.dtype}"
     if y.shape[1] < 8:
         return f"minor_dim_tiny:{y.shape[1]}"
-    bad = _check_vec("bias", bias, y)
+    bad = _check_dtype("y", y) or _check_vec("bias", bias, y)
     if bad:
         return bad
     if bits is not None:
@@ -399,11 +434,11 @@ def _matmul_epilogue_example():
     example=_matmul_epilogue_example,
     doc="dropout(act(y + bias)) in one pass over a matmul output — the "
         "BERT MFU lever (docs/perf_notes.md: dropout-in-epilogue, "
-        "docs/roadmap.md items 3-4). Mask semantics bit-identical to "
+        "ROADMAP.md S3/S4). Mask semantics bit-identical to "
         "ops/nn.py Dropout; bits come from dropout_bits() under the "
         "PR-1 (layer, tick, shard) fold discipline. block=(br, bc) "
-        "overrides the default tiling (tuned tables; invalid blocks "
-        "clamp to the default).",
+        "overrides the default tiling (tuned tables; a block the chip's "
+        "compiler would refuse clamps to the default).",
     tune_key=_epilogue_tune_key)
 def _matmul_epilogue_pallas(y, bias, bits=None, interpret=False,
                             act_type="gelu", p=0.0, block=None):

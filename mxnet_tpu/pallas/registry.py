@@ -28,6 +28,7 @@ parity gate, the fallback matrix, and the journal story for free.
 """
 from __future__ import annotations
 
+import functools
 import os
 import threading
 from dataclasses import dataclass
@@ -37,9 +38,29 @@ from ..base import MXNetError
 
 __all__ = ["KernelSpec", "register_kernel", "get_kernel", "kernels",
            "dispatch", "mode", "set_mode", "tier_provenance",
-           "reset_provenance", "MODES"]
+           "reset_provenance", "MODES", "block_ok", "default_block"]
 
 MODES = ("auto", "on", "off")
+_PORTABLE = frozenset(("cpu", "gpu", "tpu"))
+
+# The tiling rule of the 2D epilogue kernels, in one place for the kernels,
+# the tuned-table check and the autotuner's space. The chip's compiler
+# takes a block whose last two dims are multiples of (8, 128) or span the
+# whole array dim; tests/test_chip_compile.py holds the rule to a v5e.
+BLOCK_CAPS = (512, 256)
+
+
+def block_ok(r: int, c: int, br: int, bc: int) -> bool:
+    """True when the chip's compiler takes a (br, bc) block of an (r, c)
+    array. The block need not divide the array: the grid is ``pl.cdiv``."""
+    return (0 < br <= r and 0 < bc <= c
+            and (br % 8 == 0 or br == r) and (bc % 128 == 0 or bc == c))
+
+
+def default_block(r: int, c: int) -> Tuple[int, int]:
+    """The built-in tiling: the whole dim where it is under the cap, else
+    the cap (itself a multiple of the alignment)."""
+    return min(r, BLOCK_CAPS[0]), min(c, BLOCK_CAPS[1])
 
 _REGISTRY: Dict[str, "KernelSpec"] = {}
 _lock = threading.Lock()
@@ -150,10 +171,52 @@ def _backend() -> str:
     """Call-time backend name. ``jax.default_backend()`` here is a
     call-time dial like ops/contrib.py's — never at import (G1)."""
     import jax
-    try:
-        return jax.default_backend()
-    except RuntimeError:        # backend not initializable: act like CPU
-        return "cpu"
+    return jax.default_backend()
+
+
+def runs_on(args) -> Tuple[str, bool]:
+    """``(platform, staged)``: where a computation over ``args`` runs.
+
+    Concrete operands: the platform they live on — on a TPU host an array
+    made on ``mx.cpu()`` (the reference's default context) is computed on
+    the host, whatever the process's default backend. Traced operands
+    (inside ``jit``): the default backend with ``staged=True`` — the real
+    platform is only known when the program is lowered, so a caller with a
+    platform-specific kernel stages both paths with
+    ``lax.platform_dependent`` and the lowering keeps the one that fits."""
+    import jax
+    arrays = [a for a in jax.tree_util.tree_leaves(args)
+              if isinstance(a, jax.Array)]
+    if arrays and not any(isinstance(a, jax.core.Tracer) for a in arrays):
+        return next(iter(arrays[0].devices())).platform, False
+    return _backend(), True
+
+
+def _auto_partitioned(args, staged: bool) -> int:
+    """Over how many devices the COMPILER would have to partition a kernel
+    call on ``args`` (1 = not at all). It cannot partition a Mosaic kernel
+    ("wrap the call in a shard_map"), so such a call falls back in the
+    open. Partitioned by hand — inside a ``shard_map`` whose axes are all
+    manual — every shard runs the kernel on its own block, which is fine.
+
+    Traced operands: the non-manual axes of the abstract mesh inside a
+    ``shard_map``; else the mesh of the enclosing ``parallel.use_mesh``
+    scope (how ShardedTrainer, PipelinedTrainer and the tensor-parallel
+    predictor trace their GSPMD programs); no scope means a one-device
+    program. Concrete operands: the devices the arrays are laid out over."""
+    import math
+
+    import jax
+    if not staged:
+        return max(len(a.devices()) for a in jax.tree_util.tree_leaves(args)
+                   if isinstance(a, jax.Array))
+    am = jax.sharding.get_abstract_mesh()
+    if not am.empty:
+        return math.prod(am.shape[n] for n in am.axis_names
+                         if n not in am.manual_axes)
+    from ..parallel.mesh import active_mesh
+    mesh = active_mesh()
+    return 1 if mesh is None else int(mesh.devices.size)
 
 
 # ---------------------------------------------------------------------------
@@ -179,9 +242,9 @@ def _tuned_block(spec: "KernelSpec", args, params):
     """The tuned block for this dispatch, or None: consult the active
     tuned table (MXNET_TPU_TUNED_TABLE via autotune.table.tuned_for —
     cached, validated, never raises) at the kernel's shape class.  An
-    entry that would not tile the class exactly is refused here with a
-    journaled ``tuned_fallback`` (the kernels would clamp it anyway —
-    refusing early keeps the journal truthful about what actually ran)."""
+    entry the chip's compiler would refuse (:func:`block_ok`) is refused
+    here with a journaled ``tuned_fallback`` (the kernels would clamp it
+    anyway — refusing early keeps the journal truthful about what ran)."""
     from ..autotune import table as _tt
     doc = _tt.tuned_for("pallas")
     if doc is None:
@@ -197,7 +260,7 @@ def _tuned_block(spec: "KernelSpec", args, params):
     try:
         r, c = (int(v) for v in cls.split("x"))
         br, bc = int(blk[0]), int(blk[1])
-        ok = 0 < br <= r and 0 < bc <= c and r % br == 0 and c % bc == 0
+        ok = block_ok(r, c, br, bc)
     except (TypeError, ValueError):
         ok = False
     with _lock:
@@ -261,9 +324,23 @@ def dispatch(name: str, *args, interpret: bool = False, **params):
     2. ``supports`` rejects the concrete shapes/dtypes — unsupported
        inputs fall back *before* the backend gate so the reason an
        operator sees on any host names the real blocker.
-    3. backend not in ``spec.backends`` — unless ``interpret=True``,
-       which runs the custom impl in interpret mode (the CPU parity
-       gate's path; never the default on any backend).
+    3. the platform the operands run on (:func:`runs_on`) is not in
+       ``spec.backends`` — unless ``interpret=True``, which runs the
+       custom impl in interpret mode (the CPU parity gate's path; never
+       the default on any backend).
+    4. the compiler would have to partition the call over several devices
+       (``auto_partition:<n>dev``, :func:`_auto_partitioned`): it refuses
+       to partition a Mosaic kernel, so under a GSPMD mesh of more than one
+       device the reference runs, and the kernel only inside a
+       ``shard_map`` or on a one-device program.
+
+    Inside ``jit`` the platform is only known at lowering, so there a
+    kernel that is not portable is staged beside its reference with
+    ``lax.platform_dependent``: the program lowered for a TPU holds the
+    kernel, the same function lowered for the host CPU of a TPU machine
+    holds the reference, and the compiler sees no conditional either way.
+    The provenance count ``pallas`` then reads "the kernel, wherever the
+    program is lowered for a platform the kernel has".
 
     ``mode() == "on"`` does not force an unsupported kernel onto the
     hardware — it makes every fallback LOUD (a ``RuntimeWarning`` on top
@@ -277,10 +354,17 @@ def dispatch(name: str, *args, interpret: bool = False, **params):
         reason = "mode_off"
     if reason is None and spec.supports is not None:
         reason = spec.supports(*args, **params)
+    # a kernel with every platform among its backends is plain JAX; the
+    # others are Mosaic kernels, which only a TPU lowering takes and the
+    # compiler cannot partition
+    mosaic = not _PORTABLE <= set(spec.backends)
+    staged = False
     if reason is None and not interpret:
-        backend = _backend()
-        if backend not in spec.backends:
-            reason = f"backend:{backend}"
+        platform, staged = runs_on(args)
+        if platform not in spec.backends:
+            reason = f"backend:{platform}"
+        elif mosaic and (n := _auto_partitioned(args, staged)) > 1:
+            reason = f"auto_partition:{n}dev"
     # dispatch decisions ride the active trace span (if any): a traced
     # step's span says which kernel tier compiled into it, and why a
     # fallback happened (docs/observability.md)
@@ -294,6 +378,13 @@ def dispatch(name: str, *args, interpret: bool = False, **params):
                 params = dict(params, block=blk)
         _note(name, "pallas")
         _trace.annotate(**{f"pallas.{name}": "pallas"})
+        if staged and mosaic:
+            from jax import lax
+            custom = functools.partial(spec.pallas_impl, **params)
+            return lax.platform_dependent(
+                *args,
+                default=functools.partial(spec.xla_reference, **params),
+                **{p: custom for p in spec.backends})
         return spec.pallas_impl(*args, interpret=interpret, **params)
     _note(name, "xla", reason)
     _trace.annotate(**{f"pallas.{name}": f"xla:{reason}"})

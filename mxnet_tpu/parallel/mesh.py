@@ -76,6 +76,12 @@ def current_mesh() -> Mesh:
     return default_mesh()
 
 
+def active_mesh():
+    """The innermost ``use_mesh`` scope, or None outside any — unlike
+    :func:`current_mesh` it builds nothing and touches no device."""
+    return _mesh_stack[-1] if _mesh_stack else None
+
+
 @contextmanager
 def use_mesh(mesh: Mesh):
     """Scope a mesh as the framework-wide default (analog of the reference's

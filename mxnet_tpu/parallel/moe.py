@@ -22,15 +22,10 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..base import MXNetError
-
-try:
-    from jax import shard_map
-except ImportError:                      # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 __all__ = ["moe_apply", "moe_apply_topk"]
 

@@ -27,15 +27,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..base import MXNetError
 
-try:
-    from jax import shard_map
-except ImportError:                      # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 __all__ = ["pipeline_apply", "pipeline_schedule_info"]
 
@@ -198,8 +194,15 @@ def pipeline_apply(stage_fn, stage_params, x, mesh: Mesh = None,
                           {"layer": 0, "tick": 0, "shard": 0})
                  if stage_ctx else stage_fn(probe_params, embedded[0]))
         act0 = jnp.zeros_like(probe)
-        # broadcast act0 in so the buffer carries the same varying-axis
-        # type as the ppermute outputs that update it (shard_map vma)
+        # scan wants the initial carry to have the type the body returns,
+        # varying manual axes included: what a tick hands on came through
+        # ppermute over the pipe axis (and is a shard of the batch), while
+        # the probe of a stage that ignores its params or its input is not
+        # varying over that axis yet
+        want = {axis_name} | ({data_axis} if data_axis is not None else set())
+        missing = tuple(sorted(want - set(jax.typeof(act0).vma)))
+        if missing:
+            act0 = lax.pcast(act0, missing, to="varying")
         wrap0 = jnp.zeros((m,) + act0.shape, act0.dtype) + act0
         _, ys = lax.scan(tick, (wrap0, act0), jnp.arange(ticks))
         # microbatch i exits its LAST pass on device P-1 at tick

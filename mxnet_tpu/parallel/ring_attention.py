@@ -24,15 +24,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..base import MXNetError
 
-try:                                    # jax>=0.8 top-level; older versions
-    from jax import shard_map           # under jax.experimental
-except ImportError:                     # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 __all__ = ["blockwise_attention", "ring_attention",
            "ulysses_attention", "attention_reference"]

@@ -651,6 +651,26 @@ class ShardedTrainer(GuardedTrainerMixin):
                 self._after_step(t, loss_val, finite, gnorm)
         return nd.NDArray(loss_val, _skip_device_put=True)
 
+    def step_program_text(self, *batch) -> str:
+        """Optimized HLO of the compiled :meth:`step` for this batch — where
+        a caller reads which collectives (``all-reduce``) and custom kernels
+        (``tpu_custom_call``) the compiler put into the program. Lowers and
+        compiles the step again (a persistent compile cache makes that a
+        reload) with the arguments :meth:`step` passes; takes no step."""
+        self._prepare(batch[:-1])
+        if self._step_fn is None:
+            self._step_fn = self._build_step(len(batch) - 1)
+        one = jnp.float32(1.0)
+        from .mesh import use_mesh
+        with use_mesh(self.mesh):
+            lowered = self._step_fn.lower(
+                [p._data[0]._data for p in self._trainable],
+                [p._data[0]._data for p in self._aux],
+                self._states, self._guard_state, _rng.next_key(),
+                one, one, one, one,
+                *[self._shard_batch_arg(b) for b in batch])
+        return lowered.compile().as_text()
+
     # -- guard bookkeeping: GuardedTrainerMixin (docs/guardrails.md) ----------
     def _reinit_guard_state(self):
         return tuple(self._shard(s, PartitionSpec())

@@ -1035,6 +1035,8 @@ def main(argv=None) -> int:
                     else "BENCH_serving_tenants.json" if args.tenants > 0
                     else "BENCH_serving_pool.json" if args.replicas > 1
                     else "BENCH_serving.json")
+    from .. import runtime
+    runtime.enable_compile_cache()
     try:
         return args.fn(args)
     except Exception as e:              # structured line, never a bare crash

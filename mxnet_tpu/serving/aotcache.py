@@ -210,14 +210,11 @@ class AOTCache:
         if payload is None or trees is None:
             return self._fallback(path, "missing_section")
         try:
-            from ..diagnostics import guard
-            backend = guard.devices(local=True)[0].client
             with _obs.aot_load_span(site, path=path,
                                     bytes=len(payload) + len(trees),
                                     shape=list(shape)):
                 pred = CompiledPredictor.from_serialized(
-                    block, payload, trees, ctx=ctx, backend=backend,
-                    plan=plan)
+                    block, payload, trees, ctx=ctx, plan=plan)
         except Exception as exc:
             return self._fallback(path,
                                   f"deserialize:{type(exc).__name__}")

@@ -16,6 +16,7 @@ record and the compile-bound acceptance test.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import OrderedDict
@@ -24,6 +25,7 @@ import jax
 
 from .. import _rng
 from ..gluon.block import functional_apply
+from ..parallel.mesh import use_mesh
 
 __all__ = ["CompiledPredictor", "PredictorCache"]
 
@@ -80,9 +82,15 @@ class CompiledPredictor:
         self.aot = None                # None | "compiled" | "loaded"
 
         def fn(key, tr_datas, aux_datas, x):
-            outs, treedef, _aux_new = functional_apply(
-                block, key, tr_datas, aux_datas, [x],
-                training=False, ctx=ctx)
+            # trace under the plan's mesh, as the trainers do: mesh-aware
+            # ops and the kernel tier read it (a Mosaic kernel is not
+            # staged into a program the compiler partitions)
+            scope = (contextlib.nullcontext() if plan is None
+                     else use_mesh(plan.mesh))
+            with scope:
+                outs, treedef, _aux_new = functional_apply(
+                    block, key, tr_datas, aux_datas, [x],
+                    training=False, ctx=ctx)
             # inference never writes aux state back (BatchNorm running
             # stats stay frozen); treedef is captured at trace time
             self._treedef = treedef
@@ -174,8 +182,7 @@ class CompiledPredictor:
         return payload, trees
 
     @classmethod
-    def from_serialized(cls, block, payload, trees, ctx=None,
-                        backend=None, plan=None):
+    def from_serialized(cls, block, payload, trees, ctx=None, plan=None):
         """Rebuild a predictor from persisted bytes WITHOUT tracing or
         compiling.  ``payload``/``trees`` must already be CRC- and
         envelope-validated by the caller (serving/aotcache.py is the one
@@ -183,10 +190,19 @@ class CompiledPredictor:
         import pickle
 
         from jax.experimental import serialize_executable as _se
+
+        from ..diagnostics import guard
         obj = cls(block, ctx=ctx, plan=plan)
         in_tree, out_tree, treedef = pickle.loads(trees)
+        # load onto the devices the program was compiled for — the plan's
+        # own, or the default device of an unsharded lowering. Left to
+        # default, the executable is spread over every local device and
+        # refused at its first call on a host with more than it uses.
+        devices = (list(plan.mesh.devices.flat) if plan is not None
+                   else guard.devices(local=True)[:1])
         obj._compiled = _se.deserialize_and_load(
-            payload, in_tree, out_tree, backend=backend)
+            payload, in_tree, out_tree, backend=devices[0].client,
+            execution_devices=devices)
         obj._treedef = treedef
         obj.aot = "loaded"
         return obj

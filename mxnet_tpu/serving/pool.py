@@ -690,6 +690,14 @@ class ReplicaPool:
                             deadline_s=self.cfg.deadline_s,
                             run_id=self.run_id,
                             trace_dir=self.cfg.trace_dir)
+        # one process for each chip: subprocess replicas are given no
+        # device of their own, so those that may use the TPU are refused
+        # on a host with chips (docs/serving.md; in-process replicas are
+        # the way to serve from several chips of one host)
+        from ..diagnostics import guard
+        guard.check_chip_children(
+            [rep.env for rep in self.replicas.values()
+             if rep.kind == "proc"], "serving pool subprocess replicas")
         for rep in self.replicas.values():
             rep.start()
         if wait_ready and not self.wait_ready():

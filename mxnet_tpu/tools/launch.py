@@ -8,8 +8,12 @@ launches one worker process per host that calls
 scheduler; GSPMD over DCN replaces ps-lite (SURVEY §5.8).
 
   # 4 local processes faking a 4-host job (the reference's `--launcher
-  # local` test mode, used by tests/nightly/dist_sync_kvstore.py):
-  python tools/launch.py -n 4 --launcher local python train.py
+  # local` test mode, used by tests/nightly/dist_sync_kvstore.py). A CPU
+  # rehearsal: a chip belongs to one process and local workers are given
+  # none of their own, so on a host with chips several workers that may
+  # use the TPU are refused — one process drives all of a host's chips:
+  JAX_PLATFORMS=cpu python tools/launch.py -n 4 --launcher local \
+      python train.py
 
   # ssh to hosts in a hostfile:
   python tools/launch.py -n 2 -H hosts --launcher ssh python train.py
@@ -52,7 +56,14 @@ def launch_local(args, command):
     — the training script resumes from its own latest checkpoint, like
     the reference's recovery story)."""
     import time
+
+    from ..diagnostics import guard
     coord = f"127.0.0.1:{args.port}"
+    # one process for each chip: local workers are given no device of
+    # their own, so on a host with chips more than one that may use the
+    # TPU is refused (a CPU rehearsal pins JAX_PLATFORMS=cpu)
+    guard.check_chip_children([None] * args.num_workers,
+                              "launch.py --launcher local")
     attempts = 0
     # bounded by the restart budget: the body returns 1 past
     # --max-restarts, so the condition is the loop's honest contract

@@ -296,13 +296,21 @@ class TestConcurrentApply:
 # ---------------------------------------------------------------------------
 class TestSpacesAndSearch:
     def test_pallas_space_only_valid_tilings_smoke(self):
+        # valid = what the chip's compiler takes: rows in multiples of 8
+        # and lanes in multiples of 128, or the whole dim
         sp = atspace.pallas_block_space("conv_epilogue", 48, 20)
         rng = random.Random(0)
         for _ in range(50):
             cfg = sp.sample(rng)
-            assert 48 % cfg["block_r"] == 0 and 20 % cfg["block_c"] == 0
+            assert cfg["block_r"] % 8 == 0 and cfg["block_c"] == 20
         assert sp.reason({"block_r": 7, "block_c": 4}) is not None
+        assert sp.reason({"block_r": 16, "block_c": 10}) is not None
         assert sp.reason(dict(sp.default)) is None
+        # a real width whose default does not divide it (3136 = 12.25x256)
+        big = atspace.pallas_block_space("conv_epilogue", 65536, 3136)
+        assert big.default == {"block_r": 512, "block_c": 256}
+        assert big.reason(dict(big.default)) is None
+        assert big.reason({"block_r": 512, "block_c": 224}) is not None
 
     def test_bucket_space_enforces_grid_bound(self):
         sp = atspace.bucket_space(max_batch=8, compile_cap=2)
@@ -508,7 +516,7 @@ class TestConsumers:
                                  act_type="relu", interpret=True)
         attable.commit_table(
             _table_doc(pallas={"conv_epilogue":
-                               {"64x32": {"block": [16, 16]}}}),
+                               {"64x32": {"block": [16, 32]}}}),
             tuned_env)
         attable.reset_cache()
         registry.reset_provenance()
@@ -517,7 +525,7 @@ class TestConsumers:
         assert (np.asarray(base) == np.asarray(tuned)).all()
         loads = [r for r in _records(journal_file, "tuned_load")
                  if r["site"] == "pallas"]
-        assert loads and loads[0]["block"] == [16, 16]
+        assert loads and loads[0]["block"] == [16, 32]
         assert loads[0]["kernel"] == "conv_epilogue"
         assert loads[0]["shape_class"] == "64x32"
 
@@ -529,8 +537,9 @@ class TestConsumers:
         y = jnp.asarray(rng.randn(64, 32), np.float32)
         sc = jnp.asarray(rng.rand(1, 32) + 0.5, np.float32)
         b = jnp.asarray(rng.randn(1, 32) * 0.1, np.float32)
-        # 48 does not divide 64: table is schema-valid but wrong for
-        # this shape class — dispatch must refuse it, journaled
+        # 16 lanes of 32 is neither a multiple of 128 nor the whole dim:
+        # the table is schema-valid but the chip's compiler would refuse
+        # the block — dispatch must refuse it first, journaled
         attable.commit_table(
             _table_doc(pallas={"conv_epilogue":
                                {"64x32": {"block": [48, 16]}}}),
@@ -556,7 +565,7 @@ class TestConsumers:
         b = jnp.asarray(rng.randn(1, 16) * 0.1, np.float32)
         base = registry.dispatch("conv_epilogue", y, sc, b, None,
                                  act_type="relu", interpret=True)
-        for blk in ((8, 8), (32, 16), (1, 16), (7, 3)):  # last clamps
+        for blk in ((8, 16), (32, 16), (24, 16), (7, 3)):  # last clamps
             out = registry.dispatch("conv_epilogue", y, sc, b, None,
                                     act_type="relu", interpret=True,
                                     block=blk)

@@ -136,6 +136,92 @@ def test_fallback_non_tpu_backend_is_journaled_smoke(clean_tier, tmp_path):
     assert pallas.tier_provenance()["conv_epilogue"]["xla"] == 2
 
 
+def _epilogue_args():
+    import jax.numpy as jnp
+    rng = np.random.RandomState(3)
+    return (jnp.asarray(rng.randn(16, 128), jnp.float32),
+            jnp.asarray(rng.rand(1, 128) + 0.5, jnp.float32),
+            jnp.asarray(rng.randn(1, 128) * 0.1, jnp.float32))
+
+
+def test_concrete_operands_decide_by_where_they_live(clean_tier,
+                                                     monkeypatch):
+    """On a TPU host the default backend says "tpu" while an array made on
+    mx.cpu() — the reference's default context — is computed on the host:
+    the gate reads the operands, not the process."""
+    from mxnet_tpu.pallas import registry
+    monkeypatch.setattr(registry, "_backend", lambda: "tpu")
+    y, s, b = _epilogue_args()
+    assert registry.runs_on((y, s, None)) == ("cpu", False)
+    out = pallas.dispatch("conv_epilogue", y, s, b, None, act_type="relu")
+    ref = pallas.get_kernel("conv_epilogue").xla_reference(
+        y, s, b, None, act_type="relu")
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    prov = pallas.tier_provenance()["conv_epilogue"]
+    assert prov["fallback_reasons"] == {"backend:cpu": 1}
+
+
+def test_traced_dispatch_stages_kernel_and_reference(clean_tier,
+                                                     monkeypatch):
+    """Inside jit the platform is only known at lowering: with a TPU as the
+    default backend the kernel is staged beside its reference, and the same
+    program lowered for the host CPU holds the reference — it runs, where
+    a kernel chosen in Python would die in the CPU lowering."""
+    import jax
+    from mxnet_tpu.pallas import registry
+    monkeypatch.setattr(registry, "_backend", lambda: "tpu")
+    y, s, b = _epilogue_args()
+
+    def f(y, s, b):
+        return pallas.dispatch("conv_epilogue", y, s, b, None,
+                               act_type="relu")
+
+    text = str(jax.make_jaxpr(f)(y, s, b))
+    assert "pallas_call" in text and "platform_index" in text
+    out = jax.jit(f)(y, s, b)               # lowered for the CPU here
+    ref = pallas.get_kernel("conv_epilogue").xla_reference(
+        y, s, b, None, act_type="relu")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-6, atol=1e-6)
+    g = jax.jit(jax.grad(lambda y: f(y, s, b).sum()))(y)
+    assert g.shape == y.shape and np.isfinite(np.asarray(g)).all()
+    prov = pallas.tier_provenance()["conv_epilogue"]
+    assert prov["pallas"] >= 2 and prov["xla"] == 0
+
+
+def test_kernel_is_not_staged_into_a_program_the_compiler_partitions(
+        clean_tier, monkeypatch):
+    """The compiler refuses to partition a Mosaic kernel: under a GSPMD
+    mesh of several devices the reference runs, named in the open; inside
+    a shard_map the shards are cut by hand and the kernel stays."""
+    import jax
+    from jax import shard_map
+    from mxnet_tpu import parallel
+    from mxnet_tpu.pallas import registry
+    from mxnet_tpu.parallel import PartitionSpec as P
+    monkeypatch.setattr(registry, "_backend", lambda: "tpu")
+    y, s, b = _epilogue_args()
+    mesh = parallel.make_mesh({"data": 4}, devices=jax.devices()[:4])
+
+    def fresh():        # a new function each time: traces are cached
+        return lambda y, s, b: pallas.dispatch(
+            "conv_epilogue", y, s, b, None, act_type="relu")
+
+    with parallel.use_mesh(mesh):
+        assert "pallas_call" not in str(jax.make_jaxpr(fresh())(y, s, b))
+    prov = pallas.tier_provenance()["conv_epilogue"]
+    assert prov["fallback_reasons"] == {"auto_partition:4dev": 1}
+
+    by_hand = shard_map(fresh(), mesh=mesh, in_specs=(P("data"), P(), P()),
+                        out_specs=P("data"))
+    with parallel.use_mesh(mesh):
+        assert "pallas_call" in str(jax.make_jaxpr(by_hand)(y, s, b))
+    # and on the mesh of one device a one-chip trainer uses, it stays too
+    with parallel.use_mesh(parallel.make_mesh({"data": 1},
+                                              devices=jax.devices()[:1])):
+        assert "pallas_call" in str(jax.make_jaxpr(fresh())(y, s, b))
+
+
 def test_fallback_unsupported_shape(clean_tier):
     """supports() rejection falls back with the concrete reason — even
     when interpret would otherwise force the custom path."""
@@ -250,8 +336,8 @@ def test_dense_fused_epilogue_matches_unfused(clean_tier):
 def test_dense_epilogue_dropout_train_eval(clean_tier):
     from mxnet_tpu import autograd, gluon
     rng = np.random.RandomState(1)
-    x = nd.array(rng.randn(8, 32).astype(np.float32))
-    net = gluon.nn.Dense(64, activation="relu", in_units=32,
+    x = nd.array(rng.randn(256, 32).astype(np.float32))
+    net = gluon.nn.Dense(512, activation="relu", in_units=32,
                          epilogue_dropout=0.5)
     net.initialize()
     y_eval = net(x).asnumpy()           # inference: dropout is a no-op
@@ -266,7 +352,9 @@ def test_dense_epilogue_dropout_train_eval(clean_tier):
     # inverted dropout: kept activations are scaled by 1/(1-p)
     np.testing.assert_allclose(y_tr[kept], (y_eval * 2.0)[kept],
                                rtol=1e-5, atol=1e-6)
-    assert 0.2 < float(kept.mean()) < 0.9
+    # relu keeps about half and dropout half of those: 0.25 expected, and
+    # 256x512 samples put the bounds many standard errors away
+    assert 0.2 < float(kept.mean()) < 0.3
 
 
 def test_batchnorm_activation_fused_parity(clean_tier):
@@ -351,7 +439,7 @@ def test_blockwise_attention_routes_through_registry(clean_tier,
 
 def test_bench_pallas_flag(clean_tier, monkeypatch, capsys):
     """bench.py --pallas {on,off,auto}: valid modes export the env knob
-    for the deadlined child; an invalid mode is a structured one-line
+    a deployment would set; an invalid mode is a structured one-line
     diagnostic, not a crash."""
     import importlib
     bench = importlib.import_module("bench")
@@ -366,10 +454,9 @@ def test_bench_pallas_flag(clean_tier, monkeypatch, capsys):
     rec = json.loads(line)
     assert rec["error"] == "bad_flag"
     assert rec["metric"] == bench.METRIC
-    # valid flag exports the knob (parent env -> child inherits)
-    monkeypatch.setattr("sys.argv", ["bench.py", "--pallas", "off",
-                                     "--body"])
-    monkeypatch.setattr(bench, "_run_body", lambda: 0)
+    # valid flag exports the knob before the body runs
+    monkeypatch.setattr("sys.argv", ["bench.py", "--pallas", "off"])
+    monkeypatch.setattr(bench, "_run_body", lambda: {"metric": bench.METRIC})
     try:
         assert bench.main() == 0
         assert os.environ["MXNET_TPU_PALLAS"] == "off"
