@@ -219,8 +219,8 @@ class Trainer:
             # none). Without this the guard would be silently inert on
             # the kv path: a NaN push corrupts the params on the store.
             self._step_count += 1
-            with _obs.trace.span("gluon_trainer.step",
-                                 step=self._step_count, on_kvstore=True):
+            with _obs.call_span("gluon_trainer", "step",
+                                step=self._step_count, on_kvstore=True):
                 if (scaler is not None or cfg is not None) \
                         and not self._prepush_guard_ok(scaler, loss):
                     return
@@ -232,7 +232,7 @@ class Trainer:
         self._step_count += 1
         # telemetry (docs/observability.md): always-on phase summaries
         # (host clock only), spans under MXNET_TPU_TRACE
-        with _obs.trace.span("gluon_trainer.step", step=self._step_count):
+        with _obs.call_span("gluon_trainer", "step", step=self._step_count):
             with _obs.step_phase("gluon_trainer", "allreduce"):
                 self._allreduce_grads()
             if scaler is not None or cfg is not None:

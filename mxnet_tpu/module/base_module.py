@@ -247,7 +247,7 @@ class BaseModule:
                 # the epoch span covers the whole epoch including the
                 # end-of-epoch callbacks — a do_checkpoint commit nests
                 # under the epoch it belongs to
-                with _obs.trace.span("module_fit.epoch", epoch=epoch):
+                with _obs.call_span("module_fit", "epoch", epoch=epoch):
                     stop = self._fit_epoch(
                         train_data, eval_metric, epoch, monitor,
                         anomaly_monitor, checkpoint_prefix,
@@ -294,8 +294,8 @@ class BaseModule:
                     nbatch, data_batch = next(batches)
                 except StopIteration:
                     break
-            with _obs.trace.span("module_fit.step", epoch=epoch,
-                                 nbatch=nbatch, step=global_step + 1):
+            with _obs.call_span("module_fit", "step", epoch=epoch,
+                                nbatch=nbatch, step=global_step + 1):
                 if monitor is not None:
                     monitor.tic()
                 with _obs.step_phase("module_fit", "forward_backward"):

@@ -3,11 +3,16 @@
 The four trainers, the serving predictor cache and the checkpoint
 commit protocol all record the same two shapes of signal:
 
-- **step phases** (data wait / compiled step / guard fetch): a
-  monotonic-timed scope observed into the always-on
+- **step phases** (data wait / host args / compiled step / guard
+  fetch): a monotonic-timed scope observed into the always-on
   ``mxnet_tpu_step_phase_ms{trainer,phase}`` summary (host arithmetic
   only — the per-step cost is two ``perf_counter`` reads and one lock),
-  plus a nested trace span when ``MXNET_TPU_TRACE`` is on;
+  plus a nested trace span when ``MXNET_TPU_TRACE`` is on, inside one
+  :func:`call_span` per trainer call. Both also enter a
+  ``jax.profiler.TraceAnnotation`` named ``mxnet_tpu.<trainer>.<phase>``
+  (``.step`` / ``.run_steps`` for the call), so a profiler session
+  shows the phases on the clock of the device trace; with no session an
+  annotation is a flag test in C++, so there is no switch for it;
 - **compile events**: every jit-cache-miss site wraps its build in
   :func:`compile_span`, so XLA trace/lower/compile time lands in
   ``mxnet_tpu_xla_compiles_total{site}`` /
@@ -26,8 +31,9 @@ import time
 from . import trace
 from .metrics import default_registry
 
-__all__ = ["aot_load_span", "compile_span", "maybe_compile_span",
-           "step_phase", "PHASE_METRIC", "COMPILE_COUNT_METRIC",
+__all__ = ["aot_load_span", "call_span", "compile_span",
+           "maybe_compile_span", "step_phase", "ANNOTATION_PREFIX",
+           "PHASE_METRIC", "COMPILE_COUNT_METRIC",
            "COMPILE_MS_METRIC", "AOT_LOAD_COUNT_METRIC",
            "AOT_LOAD_MS_METRIC"]
 
@@ -36,6 +42,21 @@ COMPILE_COUNT_METRIC = "mxnet_tpu_xla_compiles_total"
 COMPILE_MS_METRIC = "mxnet_tpu_xla_compile_ms"
 AOT_LOAD_COUNT_METRIC = "mxnet_tpu_aot_loads_total"
 AOT_LOAD_MS_METRIC = "mxnet_tpu_aot_load_ms"
+ANNOTATION_PREFIX = "mxnet_tpu."
+
+_TraceAnnotation = None
+
+
+def _annotation(name, **attrs):
+    """``jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name)``. The
+    import waits for the first trainer call: this package loads without
+    jax (the journal's exporters must work while everything else is
+    wedged)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(ANNOTATION_PREFIX + name, **attrs)
 
 
 _phase_cache = None
@@ -60,14 +81,27 @@ def _phase_summary():
 @contextlib.contextmanager
 def step_phase(trainer, phase, **attrs):
     """One training-step phase: always observed into the phase summary,
-    traced as ``<trainer>.<phase>`` when tracing is on."""
+    annotated as ``mxnet_tpu.<trainer>.<phase>`` for a profiler session,
+    traced as ``<trainer>.<phase>`` when ``MXNET_TPU_TRACE`` is on."""
+    name = f"{trainer}.{phase}"
     t0 = time.perf_counter()
-    with trace.span(f"{trainer}.{phase}", **attrs):
+    with trace.span(name, **attrs), _annotation(name, **attrs):
         try:
             yield
         finally:
             _phase_summary().labels(trainer=trainer, phase=phase).observe(
                 (time.perf_counter() - t0) * 1000.0)
+
+
+@contextlib.contextmanager
+def call_span(trainer, call, **attrs):
+    """One trainer call (``step`` / ``run_steps``), the parent of its
+    phases by containment: the ``<trainer>.<call>`` trace span and the
+    ``mxnet_tpu.<trainer>.<call>`` profiler annotation, both with the
+    same host-scalar ``attrs`` (the step number)."""
+    name = f"{trainer}.{call}"
+    with trace.span(name, **attrs), _annotation(name, **attrs):
+        yield
 
 
 @contextlib.contextmanager

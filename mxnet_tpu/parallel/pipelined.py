@@ -384,7 +384,7 @@ class PipelinedTrainer(GuardedTrainerMixin):
         t = self._num_update
         # telemetry (docs/observability.md): always-on phase summaries
         # (host clock only), spans under MXNET_TPU_TRACE
-        with _obs.trace.span("pipelined_trainer.step", step=t):
+        with _obs.call_span("pipelined_trainer", "step", step=t):
             with _obs.step_phase("pipelined_trainer", "data_wait"):
                 xd = x._data if isinstance(x, nd.NDArray) \
                     else jnp.asarray(x)
@@ -459,8 +459,8 @@ class PipelinedTrainer(GuardedTrainerMixin):
                 donate_argnums=donate)
         t = self._num_update + 1
         self._num_update += num_steps
-        with _obs.trace.span("pipelined_trainer.run_steps", start_step=t,
-                             num_steps=num_steps):
+        with _obs.call_span("pipelined_trainer", "run_steps", start_step=t,
+                            num_steps=num_steps):
             with _obs.step_phase("pipelined_trainer", "data_wait"):
                 xd = x._data if isinstance(x, nd.NDArray) \
                     else jnp.asarray(x)

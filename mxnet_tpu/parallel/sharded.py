@@ -611,21 +611,28 @@ class ShardedTrainer(GuardedTrainerMixin):
         self._num_update += 1
         t = self._num_update
         # telemetry (docs/observability.md): phases always feed the
-        # step-phase summary (host perf_counter only); spans are live
-        # only under MXNET_TPU_TRACE — attrs are host scalars, so the
-        # deferred-mode zero-device-read contract is untouched
-        with _obs.trace.span("sharded_trainer.step", step=t):
+        # step-phase summary (host perf_counter only) and a profiler
+        # annotation (mxnet_tpu.sharded_trainer.<phase>, a flag test with
+        # no profiler session); spans are live only under MXNET_TPU_TRACE
+        # — attrs are host scalars, so the deferred-mode zero-device-read
+        # contract is untouched
+        with _obs.call_span("sharded_trainer", "step", step=t):
             with _obs.step_phase("sharded_trainer", "data_wait"):
                 batch_datas = [self._shard_batch_arg(b) for b in batch]
-            self._optimizer.num_update = t
-            lr = _lr_at(self._optimizer, t)
-            rescale = self._optimizer.rescale_grad
-            lscale = (self._scaler.loss_scale
-                      if self._scaler is not None else 1.0)
-            tr = [p._data[0]._data for p in self._trainable]
-            aux = [p._data[0]._data for p in self._aux]
-            cshapes = ([list(map(int, np.shape(b))) for b in batch]
-                       if compiling else None)
+            # host_args: every small device program step() starts beside
+            # the step (the key split, the scalar conversions) starts here
+            with _obs.step_phase("sharded_trainer", "host_args"):
+                self._optimizer.num_update = t
+                lr = _lr_at(self._optimizer, t)
+                rescale = self._optimizer.rescale_grad
+                lscale = (self._scaler.loss_scale
+                          if self._scaler is not None else 1.0)
+                tr = [p._data[0]._data for p in self._trainable]
+                aux = [p._data[0]._data for p in self._aux]
+                cshapes = ([list(map(int, np.shape(b))) for b in batch]
+                           if compiling else None)
+                scalars = (_rng.next_key(), jnp.float32(lr), jnp.float32(t),
+                           jnp.float32(rescale), jnp.float32(lscale))
             from .mesh import use_mesh
             # mesh-aware ops (ring attention) trace under use_mesh
             with _obs.step_phase("sharded_trainer", "compiled_step"), \
@@ -635,9 +642,7 @@ class ShardedTrainer(GuardedTrainerMixin):
                     use_mesh(self.mesh):
                 (new_tr, aux_new, new_states, gstate, loss_val,
                  (finite, gnorm), outs) = self._step_fn(
-                    tr, aux, self._states, self._guard_state,
-                    _rng.next_key(), jnp.float32(lr), jnp.float32(t),
-                    jnp.float32(rescale), jnp.float32(lscale),
+                    tr, aux, self._states, self._guard_state, *scalars,
                     *batch_datas)
             for p, w in zip(self._trainable, new_tr):
                 p._data[0]._rebind(w)
@@ -734,22 +739,27 @@ class ShardedTrainer(GuardedTrainerMixin):
                 donate_argnums=donate)
         t = self._num_update + 1
         self._num_update += num_steps
-        with _obs.trace.span("sharded_trainer.run_steps", start_step=t,
-                             num_steps=num_steps):
+        with _obs.call_span("sharded_trainer", "run_steps", start_step=t,
+                            num_steps=num_steps):
             with _obs.step_phase("sharded_trainer", "data_wait"):
                 batch_datas = [self._shard_batch_arg(b) for b in batch]
-            self._optimizer.num_update = self._num_update
-            lrs = _lr_sequence(self._optimizer, t, num_steps)
-            # fp16 note (docs/guardrails.md): the loss scale is one traced
-            # input for the WHOLE window — overflow inside a scanned window
-            # skips those steps in-program, and the scaler adjusts once per
-            # window from the per-step flags below
-            lscale = (self._scaler.loss_scale
-                      if self._scaler is not None else 1.0)
-            tr = [p._data[0]._data for p in self._trainable]
-            aux = [p._data[0]._data for p in self._aux]
-            cshapes = ([list(map(int, np.shape(b))) for b in batch]
-                       if compiling else None)
+            with _obs.step_phase("sharded_trainer", "host_args"):
+                self._optimizer.num_update = self._num_update
+                lrs = _lr_sequence(self._optimizer, t, num_steps)
+                # fp16 note (docs/guardrails.md): the loss scale is one
+                # traced input for the WHOLE window — overflow inside a
+                # scanned window skips those steps in-program, and the
+                # scaler adjusts once per window from the per-step flags
+                # below
+                lscale = (self._scaler.loss_scale
+                          if self._scaler is not None else 1.0)
+                tr = [p._data[0]._data for p in self._trainable]
+                aux = [p._data[0]._data for p in self._aux]
+                cshapes = ([list(map(int, np.shape(b))) for b in batch]
+                           if compiling else None)
+                scalars = (_rng.next_key(), lrs, jnp.float32(t),
+                           jnp.float32(self._optimizer.rescale_grad),
+                           jnp.float32(lscale))
             from .mesh import use_mesh
             with _obs.step_phase("sharded_trainer", "compiled_step"), \
                     _obs.maybe_compile_span(compiling,
@@ -759,10 +769,8 @@ class ShardedTrainer(GuardedTrainerMixin):
                     use_mesh(self.mesh):
                 (new_tr, aux_new, new_states, gstate, losses, fins,
                  gns) = self._multi_fns[key](
-                    tr, aux, self._states, self._guard_state,
-                    _rng.next_key(), lrs, jnp.float32(t),
-                    jnp.float32(self._optimizer.rescale_grad),
-                    jnp.float32(lscale), *batch_datas)
+                    tr, aux, self._states, self._guard_state, *scalars,
+                    *batch_datas)
             for p, w in zip(self._trainable, new_tr):
                 p._data[0]._rebind(w)
             for p, a in zip(self._aux, aux_new):

@@ -551,6 +551,134 @@ def test_trace_off_zero_host_reads_gluon_trainer_and_module():
     assert not stopped and steps == 4
 
 
+# -- the phases on the profiler's clock (mxnet_tpu.* annotations) ------------
+
+def _dropout_sharded(seed):
+    mx.random.seed(seed)
+    net = gluon.nn.HybridSequential()
+    with net.name_scope():
+        net.add(gluon.nn.Dense(16, activation="relu", in_units=8))
+        net.add(gluon.nn.Dropout(0.5))
+        net.add(gluon.nn.Dense(4, in_units=16))
+    net.initialize()
+    tr = parallel.ShardedTrainer(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+        optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+        mesh=parallel.make_mesh({"data": -1}))
+    rng = np.random.RandomState(0)
+    return tr, rng.randn(16, 8).astype(np.float32), rng.randint(0, 4, (16,))
+
+
+def _profiled_host_events(tmp_path, work):
+    """``[(name, start_ns, end_ns, stats), ...]`` of the ``mxnet_tpu.*``
+    annotations and ``PjitFunction(...)`` calls that ``work()`` leaves on
+    ``/host:CPU`` of a ``jax.profiler`` session."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    (host,) = [p for p in ProfileData.from_file(path).planes
+               if p.name == "/host:CPU"]
+    return sorted(
+        ((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+         for line in host.lines for ev in line.events
+         if ev.name.startswith((instrument.ANNOTATION_PREFIX,
+                                "PjitFunction("))),
+        key=lambda e: e[1])
+
+
+@pytest.mark.parametrize("call, program", [("step", "step"),
+                                           ("run_steps", "multi")])
+def test_profiler_session_holds_one_span_tree_per_trainer_call(
+        tmp_path, call, program):
+    assert trace.mode() == "off"            # no MXNET_TPU_TRACE needed
+    tr, x, y = _sharded()
+
+    def work():
+        if call == "step":
+            return tr.step(x, y).asscalar()
+        return tr.run_steps(x, y, num_steps=2).asscalar()
+
+    work()                                  # compile + warm
+    events = _profiled_host_events(tmp_path, work)
+    prefix = instrument.ANNOTATION_PREFIX + "sharded_trainer."
+    (outer,) = [e for e in events if e[0] == prefix + call]
+    want = {"step": {"step": 2},
+            "run_steps": {"start_step": 3, "num_steps": 2}}[call]
+    assert outer[3] == want                 # host scalars, the span's own
+    phases = [e for e in events
+              if e[0].startswith(prefix) and e is not outer]
+    assert [e[0][len(prefix):] for e in phases] == [
+        "data_wait", "host_args", "compiled_step", "guard_fetch"]
+    reach = outer[1]
+    for _, start, end, _ in phases:         # in that order, inside the call
+        assert reach <= start <= end <= outer[2]
+        reach = end
+    started = {}
+    for name, start, end, _ in events:
+        if name.startswith("PjitFunction(") and outer[1] <= start \
+                and end <= outer[2]:
+            # every program the call starts is started inside one phase:
+            # none in the trainer's self time
+            (phase,) = [p[0][len(prefix):] for p in phases
+                        if p[1] <= start and end <= p[2]]
+            started.setdefault(phase, set()).add(name)
+    # the step's own in compiled_step and alone there, the small ones before
+    assert started.pop("compiled_step") == {f"PjitFunction({program})"}
+    assert set(started) == {"host_args"}
+    assert "PjitFunction(convert_element_type)" in started["host_args"]
+
+
+def test_no_profiler_session_no_ring_span_no_device_read():
+    import jax
+    assert trace.mode() == "off"
+    tr, x, y = _sharded(guard=GuardConfig(mode="deferred"))
+    tr.step(x, y)
+    tr.run_steps(x, y, num_steps=2)         # compile + warm both programs
+    xb = [tr._shard_batch_arg(b) for b in (x, y)]
+    with jax.transfer_guard_device_to_host("disallow"):
+        tr.step(*xb)
+        tr.run_steps(*xb, num_steps=2)
+    snap = observability.snapshot()
+    assert snap["trace"]["recorded"] == 0 and trace.get_tracer().spans() == []
+    phases = snap["metrics"][instrument.PHASE_METRIC]["values"]
+    for phase in ("data_wait", "host_args", "compiled_step", "guard_fetch"):
+        assert phases[f"trainer=sharded_trainer,phase={phase}"]["count"] == 4
+
+
+def test_ring_holds_the_host_args_span_between_its_neighbours(ring):
+    tr, x, y = _sharded()
+    tr.step(x, y)
+    names = [s["name"] for s in sorted(ring.spans(), key=lambda s: s["span_id"])
+             if s["name"].startswith("sharded_trainer.")]
+    assert names == ["sharded_trainer.step", "sharded_trainer.data_wait",
+                     "sharded_trainer.host_args",
+                     "sharded_trainer.compiled_step",
+                     "sharded_trainer.guard_fetch"]
+
+
+def test_fixed_seed_losses_are_those_of_the_parent_commit():
+    """``host_args`` draws the key at the point of the RNG stream where
+    the call of the compiled program drew it: three ``step()`` losses and
+    one ``run_steps(4)`` loss of a net with dropout, bit for bit as
+    commit 7f8459b gave them on the CPU."""
+    tr, x, y = _dropout_sharded(1234)
+    got = [float(tr.step(x, y).asscalar()).hex() for _ in range(3)]
+    got.append(float(tr.run_steps(x, y, num_steps=4).asscalar()).hex())
+    assert got == ["0x1.619db00000000p+0", "0x1.61e60c0000000p+0",
+                   "0x1.600cc00000000p+0", "0x1.56dac80000000p+0"]
+    tr2, _, _ = _dropout_sharded(4321)      # the key does reach the loss
+    assert float(tr2.step(x, y).asscalar()).hex() != got[0]
+
+
 # -- reports + doctor surfaces ------------------------------------------------
 
 def test_trace_report_summarizes_journal(tmp_path, jfile):
