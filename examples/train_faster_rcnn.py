@@ -45,13 +45,13 @@ def main():
     trainer = gluon.Trainer(net.collect_params(), "adam",
                             {"learning_rate": args.lr})
 
-    def synth_batch():
-        x = np.random.rand(args.batch, 3, H, H).astype(np.float32)
+    def synth_batch(rng=np.random):
+        x = rng.rand(args.batch, 3, H, H).astype(np.float32)
         gt = np.full((args.batch, 2, 5), -1.0, np.float32)
         for i in range(args.batch):
-            cls = np.random.randint(0, args.classes)
-            x0, y0 = np.random.randint(0, H // 2, 2)
-            w, h = np.random.randint(H // 4, H // 2, 2)
+            cls = rng.randint(0, args.classes)
+            x0, y0 = rng.randint(0, H // 2, 2)
+            w, h = rng.randint(H // 4, H // 2, 2)
             gt[i, 0] = [cls, x0, y0, min(x0 + w, H - 1),
                         min(y0 + h, H - 1)]
             # paint the object region so there is signal to localize
@@ -59,6 +59,23 @@ def main():
         return x, gt
 
     im_info = np.array([[H, H, 1.0]] * args.batch, np.float32)
+
+    # progress is read on batches held out before training: every training
+    # batch is fresh and its loss swings by a factor of two, so the lines
+    # printed along the way say little about learning
+    held_rng = np.random.RandomState(12345)
+    held_out = [synth_batch(held_rng) for _ in range(4)]
+
+    def held_out_loss():
+        total = 0.0
+        for x, gt in held_out:
+            with autograd.record():     # batch statistics, as in training
+                outs = net(nd.array(x), nd.array(im_info))
+                total += float(loss_fn(outs, nd.array(gt),
+                                       (H, H)).asscalar())
+        return total / len(held_out)
+
+    before = held_out_loss()
     t0 = time.time()
     for step in range(args.steps):
         x, gt = synth_batch()
@@ -70,6 +87,7 @@ def main():
         if step % 10 == 0 or step == args.steps - 1:
             print(f"step {step:4d}  loss {float(loss.asscalar()):8.4f}  "
                   f"({time.time() - t0:.1f}s)")
+    print(f"held-out loss {before:.4f} -> {held_out_loss():.4f}")
     print("done")
 
 

@@ -32,8 +32,8 @@ class BasicBlockV1(HybridBlock):
         super().__init__(**kwargs)
         self.body = nn.HybridSequential(prefix="")
         self.body.add(_conv3x3(channels, stride, in_channels))
-        # BN + relu fused into one normalize-epilogue pass (the guarded
-        # pallas conv_epilogue tier, docs/pallas.md; no extra params so
+        # BN + relu as one op: relu(x*scale + offset) in fp32, cast back
+        # once (ops/nn.py BatchNorm act_type=; no extra params, so
         # checkpoints stay interchangeable with a BN + Activation pair)
         self.body.add(nn.BatchNorm(activation="relu"))
         self.body.add(_conv3x3(channels, 1, channels))
@@ -52,8 +52,8 @@ class BasicBlockV1(HybridBlock):
         x = self.body(x)
         if self.downsample:
             residual = self.downsample(residual)
-        # residual add + relu as ONE fused epilogue pass — the stage-3/4
-        # bottleneck epilogue benchmarks/conv_epilogue_probe.py targeted
+        # residual add + relu in fp32, cast back once; plain jax.numpy
+        # that XLA fuses with the BatchNorm before it
         return F.contrib.conv_epilogue(x, residual)
 
 
@@ -66,8 +66,7 @@ class BottleneckV1(HybridBlock):
         self.body = nn.HybridSequential(prefix="")
         self.body.add(nn.Conv2D(channels // 4, kernel_size=1, strides=stride,
                                 use_bias=False))
-        # BN + relu pairs fused into one normalize-epilogue pass each
-        # (pallas conv_epilogue tier, docs/pallas.md)
+        # BN + relu pairs, one op each (ops/nn.py BatchNorm act_type=)
         self.body.add(nn.BatchNorm(activation="relu"))
         self.body.add(_conv3x3(channels // 4, 1, channels // 4))
         self.body.add(nn.BatchNorm(activation="relu"))
@@ -88,8 +87,8 @@ class BottleneckV1(HybridBlock):
         x = self.body(x)
         if self.downsample:
             residual = self.downsample(residual)
-        # residual add + relu as ONE fused epilogue pass — the stage-3/4
-        # bottleneck epilogue benchmarks/conv_epilogue_probe.py targeted
+        # residual add + relu in fp32, cast back once; plain jax.numpy
+        # that XLA fuses with the BatchNorm before it
         return F.contrib.conv_epilogue(x, residual)
 
 

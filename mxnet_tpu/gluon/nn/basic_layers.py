@@ -197,10 +197,10 @@ class BatchNorm(HybridBlock):
         self._center = center
         self._scale = scale
         self._use_global_stats = use_global_stats
-        # activation fused into the normalize pass (docs/pallas.md):
-        # scale*x+offset and the activation run as one conv-epilogue
-        # kernel pass on TPU; no extra params, so checkpoints are
-        # interchangeable with a BatchNorm + Activation pair
+        # activation applied in the normalize pass (ops/nn.py BatchNorm
+        # act_type=): act(scale*x+offset) in fp32, cast back once; no
+        # extra params, so checkpoints are interchangeable with a
+        # BatchNorm + Activation pair
         self._activation = activation
         with self.name_scope():
             self.gamma = self.params.get(

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..base import MXNetError
+from .nn import _epilogue_act
 from .registry import OpParam, register
 
 
@@ -408,16 +409,13 @@ def _flash_attention(q, k, v, block_size=512, causal=False, sm_scale=None):
 
 @register("_contrib_conv_epilogue", num_inputs=2,
           params=[OpParam("act_type", str, "relu")],
-          doc="Fused residual epilogue act(x + res) in one VMEM pass — the "
-              "RN50 conv-fusion bandwidth lever (docs/pallas.md; promoted "
-              "from benchmarks/conv_epilogue_probe.py). Dispatches the "
-              "mxnet_tpu.pallas conv_epilogue kernel on TPU; everywhere "
-              "else the parity-gated XLA reference runs (journaled "
-              "fallback), so numerics are identical across tiers within "
-              "the registered tolerance.")
+          doc="Residual epilogue act(x + res): the add and the activation "
+              "(identity, relu, gelu, tanh, sigmoid) in float32, cast back "
+              "to x's dtype once. Plain jax.numpy on the arrays as they "
+              "are; XLA fuses it into one elementwise pass.")
 def _conv_epilogue_contrib(x, res, act_type="relu"):
-    from ..pallas import fused_conv_epilogue
-    return fused_conv_epilogue(x, res=res, act_type=act_type)
+    return _epilogue_act(x.astype(jnp.float32) + res.astype(jnp.float32),
+                         act_type, x.dtype)
 
 
 @register("_contrib_matmul_epilogue", num_inputs=2, needs_rng=True,

@@ -246,6 +246,20 @@ def _leaky_relu(x, *gamma, act_type="leaky", slope=0.25, lower_bound=0.125,
     raise MXNetError(f"LeakyReLU: unknown act_type {act_type!r}")
 
 
+def _epilogue_act(out, act_type, dtype):
+    """``act(out)`` cast to ``dtype``: the tail BatchNorm's ``act_type=``
+    and ``contrib.conv_epilogue`` share. ``out`` is the float32 value the
+    caller folded; the one cast back comes after the activation."""
+    if act_type == "gelu":
+        out = _leaky_relu(out, act_type="gelu")
+    elif act_type in ("relu", "tanh", "sigmoid"):
+        out = _activation(out, act_type=act_type)
+    elif act_type not in (None, "identity"):
+        raise MXNetError(f"epilogue: unknown act_type {act_type!r}; one of "
+                         "identity, relu, gelu, tanh, sigmoid")
+    return out.astype(dtype)
+
+
 @register("softmax", params=[OpParam("axis", int, -1),
                              OpParam("temperature", float, None),
                              OpParam("length", tuple, None),
@@ -299,13 +313,12 @@ def _softmax_activation(x, mode="instance"):
                   OpParam("axis", int, 1),
                   OpParam("cudnn_off", bool, False),
                   OpParam("act_type", str, None,
-                          doc="fuse an activation into the normalize pass "
-                              "(the conv-epilogue lever, docs/pallas.md): "
-                              "the scale*x+offset multiply-add and the "
-                              "activation run as ONE VMEM pass through the "
-                              "mxnet_tpu.pallas conv_epilogue kernel on "
-                              "TPU, with a parity-gated XLA fallback "
-                              "elsewhere")],
+                          doc="apply an activation (identity, relu, gelu, "
+                              "tanh, sigmoid) in the normalize pass: "
+                              "act(x*scale + offset) computed in float32 "
+                              "on the array as it is and cast back once, "
+                              "plain jax.numpy that XLA fuses into one "
+                              "elementwise pass on every backend")],
           doc="Batch normalization. Inputs: data, gamma, beta, moving_mean, "
               "moving_var. Outputs: (out, batch_mean, batch_var) — like the "
               "reference's three NNVM outputs; running-stat update is done "
@@ -314,8 +327,11 @@ def _batch_norm(x, gamma, beta, moving_mean, moving_var, eps=1e-3, momentum=0.9,
                 fix_gamma=True, use_global_stats=False, output_mean_var=False,
                 axis=1, cudnn_off=False, act_type=None, training=False):
     axes = tuple(i for i in range(x.ndim) if i != axis % x.ndim)
-    bshape = [1] * x.ndim
-    bshape[axis % x.ndim] = x.shape[axis % x.ndim]
+
+    def per_channel(v):
+        # (C,) -> x's rank with C on `axis`: a broadcast, never a reshape
+        return lax.expand_dims(v, axes)
+
     if fix_gamma:
         gamma = jnp.ones_like(gamma)
     if training and not use_global_stats:
@@ -338,8 +354,7 @@ def _batch_norm(x, gamma, beta, moving_mean, moving_var, eps=1e-3, momentum=0.9,
         # (output std ≤ 1) instead of rsqrt(garbage) (the round-2 advisor
         # measured output std 158 at mean=1e4 on zero-init stats).
         c = lax.stop_gradient(moving_mean.astype(jnp.float32))
-        cb = c.reshape(bshape)
-        xc = x.astype(jnp.float32) - cb
+        xc = x.astype(jnp.float32) - per_channel(c)
         mean_c = jnp.mean(xc, axis=axes)
         e2 = jnp.mean(jnp.square(xc), axis=axes)
         var_raw = jnp.maximum(e2 - jnp.square(mean_c), 0.0)
@@ -362,16 +377,15 @@ def _batch_norm(x, gamma, beta, moving_mean, moving_var, eps=1e-3, momentum=0.9,
     scale = inv * gamma.astype(jnp.float32)
     offset = beta.astype(jnp.float32) - mean * scale
     if act_type is None:
-        out = x * scale.astype(x.dtype).reshape(bshape) \
-            + offset.astype(x.dtype).reshape(bshape)
+        out = x * per_channel(scale.astype(x.dtype)) \
+            + per_channel(offset.astype(x.dtype))
     else:
-        # BN+activation epilogue through the guarded kernel tier: one
-        # VMEM pass on TPU, the numerics-contract XLA reference (same
-        # fp32 fold, journaled fallback) everywhere else
-        from ..pallas import fused_conv_epilogue
-        out = fused_conv_epilogue(
-            x, scale=scale.astype(x.dtype), bias=offset.astype(x.dtype),
-            channel_axis=axis, act_type=act_type)
+        # BN+activation: the multiply-add stays in fp32 up to the
+        # activation and is cast back once, on x's own layout, so XLA
+        # fuses it into one elementwise pass next to the convolutions
+        out = _epilogue_act(
+            x.astype(jnp.float32) * per_channel(scale)
+            + per_channel(offset), act_type, x.dtype)
     return out, mean.astype(moving_mean.dtype), var.astype(moving_var.dtype)
 
 

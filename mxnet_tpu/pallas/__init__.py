@@ -17,14 +17,13 @@ time).
 from __future__ import annotations
 
 from . import kernels as _kernels          # noqa: F401  (registration)
-from .kernels import (EPILOGUE_ACTS, dropout_bits, fused_conv_epilogue,
-                      fused_matmul_epilogue, keep_threshold)
+from .kernels import (EPILOGUE_ACTS, dropout_bits, fused_matmul_epilogue,
+                      keep_threshold)
 from .registry import (MODES, KernelSpec, dispatch, get_kernel, kernels,
                        mode, register_kernel, reset_provenance, set_mode,
                        tier_provenance)
 
 __all__ = ["KernelSpec", "MODES", "EPILOGUE_ACTS", "dispatch",
-           "dropout_bits", "fused_conv_epilogue", "fused_matmul_epilogue",
-           "get_kernel", "keep_threshold", "kernels", "mode",
-           "register_kernel", "reset_provenance", "set_mode",
-           "tier_provenance"]
+           "dropout_bits", "fused_matmul_epilogue", "get_kernel",
+           "keep_threshold", "kernels", "mode", "register_kernel",
+           "reset_provenance", "set_mode", "tier_provenance"]

@@ -2,11 +2,12 @@
 
 Three kernels target the two profiled ceilings docs/perf_notes.md ends on:
 
-- ``conv_epilogue`` — the RN50 lever (conv fusions at ~76% of HBM
-  bandwidth): scale·y + bias + residual + activation in ONE VMEM pass,
-  promoted from ``benchmarks/conv_epilogue_probe.py``'s staged probe into
-  the library, wired behind ``ops/nn.py``'s BatchNorm ``act_type`` path,
-  the resnet-v1 residual epilogue, and ``nd.contrib.conv_epilogue``.
+- ``conv_epilogue`` — scale·y + bias + residual + activation over 2-D
+  rows in ONE VMEM pass. No op dispatches it: BatchNorm's ``act_type``
+  and ``nd.contrib.conv_epilogue`` compute the same formula as plain
+  jax.numpy on the N-D array (a 2-D view of a tiled NCHW activation is a
+  physical re-layout on the chip, which cost 5.5x the step; PERF.md §6,
+  PR 26). It stays registered as the tier's worked example.
 - ``matmul_epilogue`` — the BERT lever (~56% MFU inside XLA's matmul
   fusions, dropout-mask traffic measured 24% of a step pre-rbg): bias +
   activation + inverted dropout applied in one pass over the matmul
@@ -35,8 +36,8 @@ from ..base import MXNetError
 from .registry import (block_ok, default_block, dispatch,
                        register_kernel)
 
-__all__ = ["fused_conv_epilogue", "fused_matmul_epilogue", "dropout_bits",
-           "keep_threshold", "EPILOGUE_ACTS"]
+__all__ = ["fused_matmul_epilogue", "dropout_bits", "keep_threshold",
+           "EPILOGUE_ACTS"]
 
 
 def _block_pair(r, c, block):
@@ -273,10 +274,10 @@ def _conv_epilogue_example():
     "conv_epilogue", xla_reference=_conv_epilogue_ref, tolerance=1e-5,
     backends=("tpu",), supports=_conv_epilogue_supports,
     example=_conv_epilogue_example,
-    doc="act(scale*y + bias [+ res]) over 2D rows in one VMEM pass — the "
-        "RN50 conv-fusion bandwidth lever (docs/perf_notes.md; promoted "
-        "from benchmarks/conv_epilogue_probe.py). scale/bias broadcast "
-        "as (1, C) columns or (R, 1) rows. block=(br, bc) overrides the "
+    doc="act(scale*y + bias [+ res]) over 2D rows in one VMEM pass "
+        "(registered, dispatched by no op: docs/pallas.md). scale/bias "
+        "broadcast as (1, C) columns or (R, 1) rows. block=(br, bc) "
+        "overrides the "
         "default tiling (tuned tables; every tiling is bit-identical, a "
         "block the chip's compiler would refuse clamps to the default).",
     tune_key=_epilogue_tune_key)
@@ -523,82 +524,8 @@ def _blockwise_pallas(q, k, v, interpret=False, block_size=512, causal=False,
 
 
 # ---------------------------------------------------------------------------
-# N-D wrappers — the surface ops/ and gluon/ wire against
+# N-D wrapper — the surface ops/ and gluon/ wire against
 # ---------------------------------------------------------------------------
-def fused_conv_epilogue(x, scale=None, bias=None, res=None, channel_axis=-1,
-                        act_type="relu", interpret=False):
-    """N-D entry: normalize to the 2D kernel form and dispatch.
-
-    ``scale``/``bias`` are per-channel vectors along ``channel_axis``
-    (or None for a pure residual-add epilogue). Channel-last inputs map
-    to (1, C) column broadcasts; ``channel_axis=1`` (NCHW) maps to
-    (R, 1) row broadcasts over a (N*C, spatial) view — no transpose on
-    either layout. Other axes are moved to the minor position first.
-    """
-    shape = x.shape
-    if x.ndim < 2:
-        # nothing to tile: the reference IS the op
-        s = jnp.ones((1,), x.dtype) if scale is None else scale
-        b = jnp.zeros((1,), x.dtype) if bias is None else bias
-        return _conv_epilogue_ref(x.reshape(1, -1), s.reshape(1, -1),
-                                  b.reshape(1, -1),
-                                  None if res is None
-                                  else res.reshape(1, -1),
-                                  act_type=act_type).reshape(shape)
-    ax = channel_axis % x.ndim
-    moved = False
-    if scale is None and bias is None:
-        # no per-channel vectors: any 2D view works — pick the one with
-        # the widest well-aligned minor dim for lane utilization
-        y2 = _flatten2d(x)
-        r2 = None if res is None else res.reshape(y2.shape)
-        c = y2.shape[1]
-        s2 = jnp.ones((1, c), x.dtype)
-        b2 = jnp.zeros((1, c), x.dtype)
-    elif ax == x.ndim - 1:
-        c = shape[ax]
-        y2 = x.reshape(-1, c)
-        r2 = None if res is None else res.reshape(-1, c)
-        s2 = (jnp.ones((1, c), x.dtype) if scale is None
-              else scale.reshape(1, c))
-        b2 = (jnp.zeros((1, c), x.dtype) if bias is None
-              else bias.reshape(1, c))
-    else:
-        if ax != 1:
-            x = jnp.moveaxis(x, ax, 1)
-            res = None if res is None else jnp.moveaxis(res, ax, 1)
-            shape = x.shape
-            moved = True
-        n, c = shape[0], shape[1]
-        y2 = x.reshape(n * c, -1)
-        r2 = None if res is None else res.reshape(n * c, -1)
-
-        def _rowvec(v, fill):
-            if v is None:
-                return jnp.full((n * c, 1), fill, x.dtype)
-            return jnp.tile(v.reshape(c), n).reshape(n * c, 1)
-
-        s2 = _rowvec(scale, 1)
-        b2 = _rowvec(bias, 0)
-    out = dispatch("conv_epilogue", y2, s2, b2, r2, act_type=act_type,
-                   interpret=interpret)
-    out = out.reshape(shape)
-    if moved:
-        out = jnp.moveaxis(out, 1, ax)
-    return out
-
-
-def _flatten2d(x):
-    """2D view of x maximizing a lane-aligned minor dim: the largest
-    divisor of x.size that is <= 4096 and a multiple of 128, else the
-    natural (…, last) flatten."""
-    total = int(x.size)
-    for c in range(4096, 127, -128):
-        if total % c == 0:
-            return x.reshape(total // c, c)
-    return x.reshape(-1, x.shape[-1])
-
-
 def fused_matmul_epilogue(y, bias, act_type=None, p=0.0, rng=None,
                           training=False, layer=0, tick=0, shard=0,
                           interpret=False):

@@ -3,9 +3,11 @@ interpret-mode parity for EVERY registered kernel vs its XLA reference
 (the registration-time numerics gate), fallback selection (non-TPU
 backend, unsupported shape, env kill-switch — each journaled with a
 reason), gradient parity through the custom_vjp paths, dropout-key
-independence under the PR-1 (layer, tick, shard) fold discipline, and
-the gluon/ops wiring (Dense epilogue, BatchNorm act_type, resnet
-residual epilogue, blockwise-attention routing, bench A/B flag)."""
+independence under the PR-1 (layer, tick, shard) fold discipline, the
+gluon/ops wiring (Dense epilogue, blockwise-attention routing, bench A/B
+flag), and what is deliberately NOT wired: BatchNorm act_type and the
+resnet residual epilogue are plain jax.numpy equal to the conv_epilogue
+reference, with no reshape and no kernel in ResNet-50's step."""
 import json
 import os
 
@@ -387,6 +389,221 @@ def test_contrib_conv_epilogue_matches_add_relu(clean_tier):
     got = nd.contrib.conv_epilogue(x, r).asnumpy()
     want = np.maximum(x.asnumpy() + r.asnumpy(), 0.0)
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+def _bf16_ulps(a, b):
+    """Distance between two bfloat16 arrays in units in the last place."""
+    def ordered(v):
+        bits = np.asarray(v).view(np.uint16).astype(np.int32)
+        mag = bits & 0x7FFF
+        return np.where(bits & 0x8000, -mag, mag)
+    return np.abs(ordered(a) - ordered(b))
+
+
+def _rows_view(layout, x, vecs):
+    """The 2D view the conv_epilogue kernel was fed: NCHW as (N*C, H*W)
+    rows with (R, 1) vectors, channel-last as (rows, C) with (1, C)."""
+    import jax.numpy as jnp
+    if layout == "NCHW":
+        n, c = x.shape[:2]
+        return (lambda a: a.reshape(n * c, -1),
+                [jnp.tile(v, n).reshape(n * c, 1) for v in vecs])
+    c = x.shape[-1]
+    return lambda a: a.reshape(-1, c), [v.reshape(1, c) for v in vecs]
+
+
+@pytest.mark.parametrize("act", ["relu", "gelu"])
+@pytest.mark.parametrize("layout,shape,axis", [("NCHW", (4, 8, 6, 6), 1),
+                                               ("NHWC", (4, 6, 6, 8), -1)])
+def test_batchnorm_act_bf16_is_the_reference_fold(clean_tier, layout, shape,
+                                                  axis, act):
+    """BatchNorm(act_type=) on the N-D array, in bf16, is
+    ``_conv_epilogue_ref`` on the 2D view the kernel used to get: the same
+    fp32 fold and one cast back, forward and gradients, to one bf16 ulp.
+    The parity is with the reference fed the float32 scale and offset, as
+    ISSUE 26 wrote the formula, not with what the tree before PR 26
+    computed: that rounded both vectors to bf16 before the fold and sits a
+    few ulps away where ``x*scale`` and ``offset`` cancel."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.nn import _batch_norm
+    from mxnet_tpu.pallas.kernels import _conv_epilogue_ref
+    rng = np.random.RandomState(11)
+    c, eps = shape[axis], 1e-5
+    x = jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+    cot = jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+    gamma = jnp.asarray(rng.rand(c) + 0.5, jnp.float32)
+    beta = jnp.asarray(rng.randn(c) * 0.3, jnp.float32)
+    mean = jnp.asarray(rng.randn(c) * 0.2, jnp.float32)
+    var = jnp.asarray(rng.rand(c) + 0.5, jnp.float32)
+
+    def op(x, gamma, beta):
+        return _batch_norm(x, gamma, beta, mean, var, eps=eps,
+                           fix_gamma=False, axis=axis, act_type=act)[0]
+
+    def ref(x, gamma, beta):
+        scale = jax.lax.rsqrt(var + eps) * gamma
+        view, (s2, b2) = _rows_view(layout, x,
+                                    [scale, beta - mean * scale])
+        return _conv_epilogue_ref(view(x), s2, b2,
+                                  act_type=act).reshape(x.shape)
+
+    got, want = op(x, gamma, beta), ref(x, gamma, beta)
+    assert got.dtype == jnp.bfloat16
+    assert _bf16_ulps(got, want).max() <= 1
+    grads = [jax.grad(lambda *a: (f(*a).astype(jnp.float32)
+                                  * cot.astype(jnp.float32)).sum(),
+                      argnums=(0, 1, 2))(x, gamma, beta) for f in (op, ref)]
+    assert _bf16_ulps(grads[0][0], grads[1][0]).max() <= 1
+    for g, w in zip(grads[0][1:], grads[1][1:]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-4)
+    # and with batch statistics: the fold of the mean and var it reports
+    out, bmean, bvar = _batch_norm(x, gamma, beta, mean, var, eps=eps,
+                                   fix_gamma=False, axis=axis, act_type=act,
+                                   training=True)
+    scale = jax.lax.rsqrt(bvar + eps) * gamma
+    view, (s2, b2) = _rows_view(layout, x, [scale, beta - bmean * scale])
+    want = _conv_epilogue_ref(view(x), s2, b2, act_type=act)
+    assert _bf16_ulps(out, want.reshape(x.shape)).max() <= 1
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("act", ["relu", "gelu"])
+@pytest.mark.parametrize("layout,shape,axis", [("NCHW", (4, 8, 6, 6), 1),
+                                               ("NHWC", (4, 6, 6, 8), -1)])
+def test_batchnorm_act_train_mode_gradients(clean_tier, layout, shape, axis,
+                                            act, dtype):
+    """With batch statistics (``training=True``) the gradients of
+    BatchNorm(act_type=) for x, gamma and beta, the paths through the mean
+    and the variance included, are those of the plain formula: two-pass
+    float32 statistics folded by ``_conv_epilogue_ref`` on the 2D view.
+    In bf16 the x-gradient agrees to one ulp; again the reference is fed
+    float32 vectors (the tree before PR 26 rounded them to bf16)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.nn import _batch_norm
+    from mxnet_tpu.pallas.kernels import _conv_epilogue_ref
+    rng = np.random.RandomState(13)
+    c, eps, f32 = shape[axis], 1e-5, jnp.float32
+    axes = tuple(i for i in range(len(shape)) if i != axis % len(shape))
+    x = jnp.asarray(rng.randn(*shape) * 1.7 + 0.4, dtype)
+    cot = jnp.asarray(rng.randn(*shape), dtype)
+    gamma = jnp.asarray(rng.rand(c) + 0.5, f32)
+    beta = jnp.asarray(rng.randn(c) * 0.3, f32)
+    moving_mean = jnp.asarray(rng.randn(c) * 0.2, f32)
+    moving_var = jnp.asarray(rng.rand(c) + 0.5, f32)
+
+    def op(x, gamma, beta):
+        return _batch_norm(x, gamma, beta, moving_mean, moving_var, eps=eps,
+                           fix_gamma=False, axis=axis, act_type=act,
+                           training=True)[0]
+
+    def ref(x, gamma, beta):
+        xf = x.astype(f32)
+        mean = xf.mean(axes)
+        var = jnp.square(xf - jax.lax.expand_dims(mean, axes)).mean(axes)
+        scale = jax.lax.rsqrt(var + eps) * gamma
+        view, (s2, b2) = _rows_view(layout, x, [scale, beta - mean * scale])
+        return _conv_epilogue_ref(view(x), s2, b2,
+                                  act_type=act).reshape(x.shape)
+
+    got, want = (jax.grad(lambda *a: (f(*a).astype(f32)
+                                      * cot.astype(f32)).sum(),
+                          argnums=(0, 1, 2))(x, gamma, beta)
+                 for f in (op, ref))
+    assert got[0].dtype == x.dtype
+    if dtype == "bfloat16":
+        assert _bf16_ulps(got[0], want[0]).max() <= 1
+        got, want = got[1:], want[1:]
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert np.abs(g - w).max() <= 1e-5 * np.abs(w).max()
+
+
+@pytest.mark.parametrize("layout,shape", [("NCHW", (4, 8, 6, 6)),
+                                          ("NHWC", (4, 6, 6, 8))])
+def test_contrib_conv_epilogue_bf16_is_the_reference_fold(clean_tier,
+                                                          layout, shape):
+    """contrib.conv_epilogue on the N-D arrays, in bf16, is
+    ``_conv_epilogue_ref`` with unit scale and a residual on the 2D view,
+    forward and both gradients, to one bf16 ulp."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.contrib import _conv_epilogue_contrib
+    from mxnet_tpu.pallas.kernels import _conv_epilogue_ref
+    rng = np.random.RandomState(12)
+    x, res, cot = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                   for _ in range(3))
+    c = shape[1] if layout == "NCHW" else shape[-1]
+
+    def ref(x, res):
+        view, (s2, b2) = _rows_view(layout, x, [jnp.ones(c), jnp.zeros(c)])
+        return _conv_epilogue_ref(view(x), s2, b2, view(res),
+                                  act_type="relu").reshape(x.shape)
+
+    got = _conv_epilogue_contrib(x, res)
+    assert got.dtype == jnp.bfloat16
+    assert _bf16_ulps(got, ref(x, res)).max() <= 1
+    grads = [jax.grad(lambda *a: (f(*a).astype(jnp.float32)
+                                  * cot.astype(jnp.float32)).sum(),
+                      argnums=(0, 1))(x, res)
+             for f in (_conv_epilogue_contrib, ref)]
+    for g, w in zip(*grads):
+        assert _bf16_ulps(g, w).max() <= 1
+
+
+@pytest.mark.parametrize("training", [False, True], ids=["eval", "train"])
+def test_batchnorm_act_never_reshapes(clean_tier, training):
+    """BatchNorm(act_type=) works on the array as it is: on the chip a 2D
+    view of a tiled NCHW activation is a physical re-layout (PERF.md §6,
+    PR 26), so its jaxpr holds no reshape at all. The train case is why
+    the shifted-moment statistics broadcast with ``lax.expand_dims`` too;
+    the ``act_type=None`` branch shares that helper and keeps the bf16
+    arithmetic it had."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.nn import _batch_norm
+    x = jnp.ones((2, 8, 4, 4), jnp.bfloat16)
+    vec = jnp.ones((8,), jnp.float32)
+    text = str(jax.make_jaxpr(
+        lambda x, g, b, m, v: _batch_norm(x, g, b, m, v, act_type="relu",
+                                          fix_gamma=False,
+                                          training=training))(
+        x, vec, vec, vec, vec))
+    assert "reshape" not in text and "mul" in text
+
+
+def test_resnet50_step_holds_no_custom_kernel(clean_tier, monkeypatch):
+    """ResNet-50's forward and backward, traced under jit with a TPU as the
+    default backend and lowered for one, dispatch nothing through the
+    tier: no conv_epilogue in the provenance, no Mosaic call in the text."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.gluon.block import functional_apply
+    from mxnet_tpu.gluon.model_zoo import vision
+    from mxnet_tpu.pallas import registry
+    net = vision.resnet50_v1(classes=10)
+    net.initialize()
+    x = np.random.RandomState(5).randn(2, 3, 32, 32).astype(np.float32)
+    net(nd.array(x))                        # deferred shapes
+    trainable, aux = net._param_split()
+    monkeypatch.setattr(registry, "_backend", lambda: "tpu")
+    pallas.reset_provenance()
+
+    def loss(tr, ax, x):
+        out, _, new_aux = functional_apply(net, jax.random.PRNGKey(0), tr,
+                                           ax, [x], training=True)
+        return out[0].astype(jnp.float32).sum(), new_aux
+
+    traced = jax.jit(jax.value_and_grad(loss, has_aux=True)).trace(
+        [p.data()._data.astype(jnp.bfloat16) for p in trainable],
+        [p.data()._data for p in aux], jnp.asarray(x, jnp.bfloat16))
+    assert "conv_epilogue" not in pallas.tier_provenance()
+    assert "pallas_call" not in str(traced.jaxpr)
+    text = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert "stablehlo.convolution" in text
+    assert "tpu_custom_call" not in text
 
 
 def test_positionwise_ffn_fused_parity_eval(clean_tier):
