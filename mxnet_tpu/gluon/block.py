@@ -360,6 +360,41 @@ class HybridBlock(Block):
         self._cached_fns = {}
         self._flags = {}
         self._out_treedef = None
+        self._recompute = False
+
+    def recompute(self, active=True):
+        """Keep only this block's inputs for the backward pass and compute
+        its activations again there (``jax.checkpoint`` around the block's
+        forward): the memory of a deep stack becomes one block's internals
+        plus every block's input. It takes effect where the block runs in
+        training mode inside a compiled program (a hybridized parent, a
+        trainer's ``step`` / ``run_steps``) and is a no-op in predict mode
+        and on the eager tape. The reference's analog is
+        MXNET_BACKWARD_DO_MIRROR (src/executor/graph_executor.cc), which is
+        a process-wide switch; this is one block's."""
+        self._recompute = bool(active)
+        return self
+
+    def _call_recomputed(self, *args):
+        trainable, aux = self._param_split()
+        ctx = args[0].ctx
+        treedefs = []
+
+        def forward(key, tr_datas, aux_datas, *in_datas):
+            outs, treedef, aux_new = functional_apply(
+                self, key, tr_datas, aux_datas, in_datas, training=True,
+                ctx=ctx)
+            treedefs.append(treedef)
+            return tuple(outs), tuple(aux_new)
+
+        outs, aux_new = jax.checkpoint(forward)(
+            _rng.next_key(), [p._data[0]._data for p in trainable],
+            [p._data[0]._data for p in aux], *[a._data for a in args])
+        for param, new in zip(aux, aux_new):
+            param._data[0]._rebind(new)
+        return jax.tree_util.tree_unflatten(
+            treedefs[0], [nd.NDArray(o, ctx=ctx, _skip_device_put=True)
+                          for o in outs])
 
     def hybridize(self, active=True, static_alloc=False, static_shape=False,
                   inline_limit=2, forward_bulk_size=None,
@@ -429,6 +464,10 @@ class HybridBlock(Block):
             self._num_inputs = len(args)
         if self._active and not _rng.in_trace():
             return self._call_cached(*args)
+        if self._recompute and not kwargs and _rng.in_trace() and \
+                autograd.is_training() and args and \
+                all(isinstance(a, nd.NDArray) for a in args):
+            return self._call_recomputed(*args)
         return super().__call__(*args, **kwargs)
 
     def _ensure_ready(self, args):

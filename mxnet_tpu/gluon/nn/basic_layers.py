@@ -12,7 +12,8 @@ from ...base import MXNetError
 from ..block import Block, HybridBlock
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "BatchNorm",
-           "LayerNorm", "GroupNorm", "InstanceNorm", "Embedding", "Flatten",
+           "LayerNorm", "RMSNorm", "GroupNorm", "InstanceNorm", "Embedding",
+           "Flatten",
            "Lambda", "HybridLambda", "Activation", "LeakyReLU", "PReLU",
            "ELU", "SELU", "GELU", "Swish", "SyncBatchNorm"]
 
@@ -316,6 +317,27 @@ class LayerNorm(HybridBlock):
 
     def hybrid_forward(self, F, x, gamma, beta):
         return F.LayerNorm(x, gamma, beta, axis=self._axis, eps=self._epsilon)
+
+
+class RMSNorm(HybridBlock):
+    """x / rms(x) * gamma over ``axis`` (no reference analog; the op
+    computes in float32 and returns x's dtype)."""
+
+    def __init__(self, axis=-1, epsilon=1e-6, gamma_initializer="ones",
+                 in_channels=0, **kwargs):
+        super().__init__(**kwargs)
+        self._axis = axis
+        self._epsilon = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get(
+                "gamma", shape=(in_channels,), init=gamma_initializer,
+                allow_deferred_init=True)
+
+    def infer_shape(self, x):
+        self.gamma._set_shape((x.shape[self._axis],))
+
+    def hybrid_forward(self, F, x, gamma):
+        return F.RMSNorm(x, gamma, axis=self._axis, eps=self._epsilon)
 
 
 class GroupNorm(HybridBlock):

@@ -18,6 +18,9 @@ One substrate every subsystem records into:
   summaries.
 - :mod:`.instrument` — the shared step-phase / compile-span helpers the
   four trainers, serving and checkpointing use.
+- :mod:`.scopes` — ``device_scopes()``: which HLO instruction of a live
+  trainer's programs belongs to which ``jax.named_scope``, for joining
+  with a profiler trace.
 
 Every journal record written inside a span carries ``trace_id``/
 ``span_id`` (the provider hook in diagnostics.journal), so the
@@ -29,7 +32,8 @@ wedged.
 """
 from __future__ import annotations
 
-from . import aggregate, export, flight, instrument, metrics, report, trace
+from . import (aggregate, export, flight, instrument, metrics, report,
+               scopes, trace)
 from .aggregate import (aggregate_chrome, critical_path, scan_run_dir,
                         timeline_report)
 from .export import (chrome_trace_from_journal, export_chrome,
@@ -38,6 +42,7 @@ from .flight import FlightRecorder, install_from_env
 from .metrics import (Counter, Gauge, LatencySummary, MetricsRegistry,
                       Summary, default_registry, prometheus_text,
                       reset_metrics)
+from .scopes import device_scopes
 from .trace import (SpanContext, Tracer, adopt_trace, annotate, configure,
                     current_context, current_ids, current_span, enabled,
                     event, get_tracer, identity, reset_tracer, span,
@@ -49,10 +54,12 @@ __all__ = [
     "aggregate", "aggregate_chrome", "annotate",
     "chrome_trace_from_journal", "compile_stats", "configure",
     "critical_path", "current_context", "current_ids", "current_span",
-    "default_registry", "enabled", "event", "export", "export_chrome",
+    "default_registry", "device_scopes", "enabled", "event", "export",
+    "export_chrome",
     "flight", "get_tracer", "identity", "install_from_env", "instrument",
     "metrics", "prometheus_text", "report", "reset_metrics",
-    "reset_tracer", "scan_run_dir", "serve_metrics", "snapshot", "span",
+    "reset_tracer", "scan_run_dir", "scopes", "serve_metrics", "snapshot",
+    "span",
     "start_span", "timeline_report", "to_chrome_trace", "trace",
 ]
 

@@ -31,7 +31,7 @@ import time
 from . import trace
 from .metrics import default_registry
 
-__all__ = ["aot_load_span", "call_span", "compile_span",
+__all__ = ["aot_load_span", "call_span", "compile_span", "device_scope",
            "maybe_compile_span", "step_phase", "ANNOTATION_PREFIX",
            "PHASE_METRIC", "COMPILE_COUNT_METRIC",
            "COMPILE_MS_METRIC", "AOT_LOAD_COUNT_METRIC",
@@ -57,6 +57,16 @@ def _annotation(name, **attrs):
         from jax.profiler import TraceAnnotation
         _TraceAnnotation = TraceAnnotation
     return _TraceAnnotation(ANNOTATION_PREFIX + name, **attrs)
+
+
+def device_scope(name):
+    """``jax.named_scope(ANNOTATION_PREFIX + name)``: names the device
+    operations traced inside it. The name lands in the ``op_name`` metadata
+    of every HLO instruction of the compiled program (backward and
+    recomputed ones too), which :func:`..scopes.device_scopes` joins
+    with a profiler trace; it costs nothing when the program runs."""
+    import jax
+    return jax.named_scope(ANNOTATION_PREFIX + name)
 
 
 _phase_cache = None

@@ -22,3 +22,4 @@ from . import optimizer_op    # ref: src/operator/optimizer_op.cc
 from . import contrib         # ref: src/operator/contrib/
 from . import quantization    # ref: src/operator/quantization/
 from . import sequence        # ref: src/operator/sequence_*.cc
+from . import ssm             # state-space scan (Mamba-2): no reference analog

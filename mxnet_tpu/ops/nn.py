@@ -465,8 +465,12 @@ def _l2_normalization(x, eps=1e-10, mode="instance"):
           params=[OpParam("axis", int, -1), OpParam("eps", float, 1e-6)],
           doc="RMSNorm (new op — modern LLM parity; no reference analog)")
 def _rms_norm(x, gamma, axis=-1, eps=1e-6):
-    ms = jnp.mean(jnp.square(x), axis=axis, keepdims=True)
-    return x * lax.rsqrt(ms + eps) * gamma
+    # the mean of squares and the scaling in float32 whatever x's dtype (a
+    # bfloat16 sum over thousands of channels loses the norm), one cast back
+    x32 = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(x32), axis=axis, keepdims=True)
+    return (x32 * lax.rsqrt(ms + eps)
+            * gamma.astype(jnp.float32)).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -43,6 +43,7 @@ from ..guardrails import fused as _guard
 from ..guardrails.monitor import AnomalyMonitor, GuardConfig
 from ..guardrails.trainer_mixin import GuardedTrainerMixin
 from ..observability import instrument as _obs
+from ..observability import scopes as _scopes
 from ..ops import optimizer_op as _ops
 from . import _ckpt
 from .mesh import current_mesh
@@ -95,6 +96,11 @@ def _lr_sequence(optimizer, t, num_steps):
 
 def _zeros_like(w):
     return jnp.zeros(w.shape, w.dtype)
+
+
+def _as_shapes(arrays):
+    return [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+            for a in arrays]
 
 
 def _state_spec(weight_spec, entry):
@@ -355,6 +361,10 @@ class ShardedTrainer(GuardedTrainerMixin):
         self._step_fn = None
         self._eval_fn = None
         self._out_treedef = None
+        # the batch each program was first called with, as shapes, by its
+        # steps per call (0: step()): what program_texts() lowers it with
+        self._program_batches = {}
+        _scopes.watch(self)
         # anomaly guardrails (docs/guardrails.md): the fused flag/norm is
         # computed in-program on EVERY step (the reduction is ~free and
         # keeps the program signature stable); the config only decides
@@ -517,10 +527,11 @@ class ShardedTrainer(GuardedTrainerMixin):
                             o.dtype, jnp.floating) else o,
                         _skip_device_put=True) for o in outs]
                 label_nd = nd.NDArray(label, _skip_device_put=True)
-                with autograd.pause(train_mode=True):
+                with autograd.pause(train_mode=True), \
+                        _obs.device_scope("loss"):
                     loss_nd = loss_block(out_nds[0] if len(out_nds) == 1
                                          else out_nds, label_nd)
-                loss_val = jnp.mean(loss_nd._data.astype(jnp.float32))
+                    loss_val = jnp.mean(loss_nd._data.astype(jnp.float32))
                 aux_pen = _collect_aux_losses(block)
                 if aux_pen is not None:     # MoE load-balancing term
                     loss_val = loss_val + jnp.asarray(aux_pen,
@@ -545,7 +556,8 @@ class ShardedTrainer(GuardedTrainerMixin):
             # GSPMD, so the flag is globally agreed — no rank can branch
             # out of a collective (the skip below is data flow).
             inv = jnp.float32(1.0) / lscale
-            finite, gnorm_scaled = _guard.guard_stats(grads, loss_val)
+            with _obs.device_scope("guard"):
+                finite, gnorm_scaled = _guard.guard_stats(grads, loss_val)
             gnorm = gnorm_scaled * inv
             rescale_all = rescale * inv
             if guard_clip is not None:
@@ -554,11 +566,12 @@ class ShardedTrainer(GuardedTrainerMixin):
                 rescale_all = rescale_all * _guard.clip_scale(
                     gnorm * rescale, jnp.float32(guard_clip))
             new_tr, new_states = [], []
-            for i, (w, g, s) in enumerate(zip(tr, grads, states)):
-                w2, s2 = _opt_apply(opt, w, g, s, lr * lr_mults[i], t,
-                                    wds[i], rescale_all, clip)
-                new_tr.append(w2)
-                new_states.append(s2)
+            with _obs.device_scope("optimizer"):
+                for i, (w, g, s) in enumerate(zip(tr, grads, states)):
+                    w2, s2 = _opt_apply(opt, w, g, s, lr * lr_mults[i], t,
+                                        wds[i], rescale_all, clip)
+                    new_tr.append(w2)
+                    new_states.append(s2)
             # skip-step semantics: a non-finite step is a bitwise no-op
             # for params, optimizer state AND aux state (BatchNorm
             # running stats) — jnp.where, so it works under jit/pjit/scan
@@ -619,6 +632,8 @@ class ShardedTrainer(GuardedTrainerMixin):
         with _obs.call_span("sharded_trainer", "step", step=t):
             with _obs.step_phase("sharded_trainer", "data_wait"):
                 batch_datas = [self._shard_batch_arg(b) for b in batch]
+            if 0 not in self._program_batches:
+                self._program_batches[0] = _as_shapes(batch_datas)
             # host_args: every small device program step() starts beside
             # the step (the key split, the scalar conversions) starts here
             with _obs.step_phase("sharded_trainer", "host_args"):
@@ -656,6 +671,16 @@ class ShardedTrainer(GuardedTrainerMixin):
                 self._after_step(t, loss_val, finite, gnorm)
         return nd.NDArray(loss_val, _skip_device_put=True)
 
+    def _lower(self, fn, scalars, batch):
+        """``fn`` (the step or a multi-step program) lowered with the
+        trainer's arrays as they are now, the given scalars and batch."""
+        from .mesh import use_mesh
+        with use_mesh(self.mesh):
+            return fn.lower(
+                [p._data[0]._data for p in self._trainable],
+                [p._data[0]._data for p in self._aux],
+                self._states, self._guard_state, *scalars, *batch)
+
     def step_program_text(self, *batch) -> str:
         """Optimized HLO of the compiled :meth:`step` for this batch — where
         a caller reads which collectives (``all-reduce``) and custom kernels
@@ -666,15 +691,33 @@ class ShardedTrainer(GuardedTrainerMixin):
         if self._step_fn is None:
             self._step_fn = self._build_step(len(batch) - 1)
         one = jnp.float32(1.0)
-        from .mesh import use_mesh
-        with use_mesh(self.mesh):
-            lowered = self._step_fn.lower(
-                [p._data[0]._data for p in self._trainable],
-                [p._data[0]._data for p in self._aux],
-                self._states, self._guard_state, _rng.next_key(),
-                one, one, one, one,
-                *[self._shard_batch_arg(b) for b in batch])
-        return lowered.compile().as_text()
+        return self._lower(
+            self._step_fn, (_rng.next_key(), one, one, one, one),
+            [self._shard_batch_arg(b) for b in batch]).compile().as_text()
+
+    def program_texts(self) -> dict:
+        """``{"step": text, "run_steps(<k>)": text}``: optimized HLO of every
+        program this trainer has run, each lowered and compiled again as
+        :meth:`step_program_text` does, with the shapes of the batch it was
+        first called with. Every instruction's ``op_name`` metadata holds
+        the ``jax.named_scope`` names it was traced under
+        (``observability.device_scopes``). Takes no step, draws no key and
+        costs nothing until it is called."""
+        key = jax.eval_shape(lambda: jax.random.key(  # graftlint: disable=G2 shape only
+            0, impl=_rng._default_impl()))
+        f32 = jax.ShapeDtypeStruct((), jnp.float32)
+        texts = {}
+        for num_steps, batch in self._program_batches.items():
+            if num_steps:
+                name = f"run_steps({num_steps})"
+                fn = getattr(self, "_multi_fns", {}).get(f"multi{num_steps}")
+                lr = jax.ShapeDtypeStruct((num_steps,), jnp.float32)
+            else:
+                name, fn, lr = "step", self._step_fn, f32
+            if fn is not None:      # dropped by an AMP change or a new mesh
+                texts[name] = self._lower(
+                    fn, (key, lr, f32, f32, f32), batch).compile().as_text()
+        return texts
 
     # -- guard bookkeeping: GuardedTrainerMixin (docs/guardrails.md) ----------
     def _reinit_guard_state(self):
@@ -690,6 +733,7 @@ class ShardedTrainer(GuardedTrainerMixin):
             self._step_fn = None
             self._eval_fn = None
             self._multi_fns = {}
+            self._program_batches = {}
             self._amp_epoch = _dispatch.amp_epoch()
             # the retraced program's dtype may have changed with it —
             # BEFORE the rebuild reads _compute_dtype/_scaler
@@ -743,6 +787,8 @@ class ShardedTrainer(GuardedTrainerMixin):
                             num_steps=num_steps):
             with _obs.step_phase("sharded_trainer", "data_wait"):
                 batch_datas = [self._shard_batch_arg(b) for b in batch]
+            if compiling:
+                self._program_batches[num_steps] = _as_shapes(batch_datas)
             with _obs.step_phase("sharded_trainer", "host_args"):
                 self._optimizer.num_update = self._num_update
                 lrs = _lr_sequence(self._optimizer, t, num_steps)
@@ -1043,6 +1089,7 @@ class ShardedTrainer(GuardedTrainerMixin):
         self._step_fn = None
         self._eval_fn = None
         self._multi_fns = {}
+        self._program_batches = {}
         get_journal().event("elastic_retrace", reason="mesh_rebuild",
                             consumer=self._guard_consumer,
                             old_devices=int(old_n),
