@@ -308,6 +308,20 @@ def _interleaved_valatt(qkv, att, heads=None):
     return out.reshape(t, n, e)
 
 
+FLASH_COUNT_METRIC = "mxnet_tpu_flash_attention_traced_total"
+
+
+def _count_traced_attention(branch, block_q="", block_k=""):
+    """One attention call traced into a program, by the branch it took
+    (``dense``, ``tpu_kernel``, ``portable``) and that branch's query and
+    key tiles: trace-time only, so a compiled step never counts."""
+    from ..observability.metrics import default_registry
+    default_registry().counter(
+        FLASH_COUNT_METRIC, "flash-attention calls traced into a program",
+        ("branch", "block_q", "block_k")).labels(
+            branch=branch, block_q=str(block_q), block_k=str(block_k)).inc()
+
+
 def _tpu_flash_attention(q, k, v, causal, scale):
     """JAX's library flash-attention kernel for TPU on [B, H, S, D] (or
     [B, S, D], which rides as H=1 — e.g. FuseAttention pattern-1
@@ -329,10 +343,46 @@ def _flash_precision(q):
     return jax.default_matmul_precision("default")
 
 
+def _largest_tile(length, cap, step=128):
+    """The largest multiple of ``step`` up to ``cap`` that divides
+    ``length`` (itself a multiple of ``step``, so ``step`` always does)."""
+    return next(t for t in range(min(cap, length) // step * step, 0, -step)
+                if length % t == 0)
+
+
+def _flash_tiles(s_q, s_kv, d, dtype):
+    """The library kernel's eleven tiles from what the call can see. Its own
+    default is 128 for all of them whatever the shape, and a grid step on a
+    v5e costs about a third of a microsecond: at S=4096 the kernel then
+    waits for its grid, not for its products. The caps are the sweep's on
+    the chip (PERF.md sec. 6, PR 28). Minor tiles, one product's: 512.
+    Major tiles, a grid step's: 1024 for two-byte heads of up to 128, else
+    512 (float32's multi-pass products on the score tile run out of fast
+    memory at 1024, and 512 is faster there anyway); a minor of 512 under
+    a major of 1024 costs nothing measurable and keeps the score tile well
+    inside fast memory. The dq kernel's key tiles: the minor, because it
+    is handed each query's float32 row sum broadcast to its major key
+    tile. A minor tile is the largest multiple of 128 under its cap that
+    divides the sequence length, a major the largest multiple of its
+    minor that does."""
+    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
+    cap = 1024 if jnp.dtype(dtype).itemsize == 2 and d <= 128 else 512
+    q_minor, k_minor = _largest_tile(s_q, 512), _largest_tile(s_kv, 512)
+    q = _largest_tile(s_q, cap, q_minor)
+    k = _largest_tile(s_kv, cap, k_minor)
+    return BlockSizes(
+        block_q=q, block_k_major=k, block_k=k_minor, block_b=1,
+        block_q_major_dkv=q, block_q_dkv=q_minor,
+        block_k_major_dkv=k, block_k_dkv=k_minor,
+        block_q_dq=q, block_k_major_dq=k_minor, block_k_dq=k_minor)
+
+
 def _library_flash_call(q, k, v, causal, scale):
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         flash_attention)
-    return flash_attention(q, k, v, causal=causal, sm_scale=scale)
+    tiles = _flash_tiles(q.shape[-2], k.shape[-2], q.shape[-1], q.dtype)
+    return flash_attention(q, k, v, causal=causal, sm_scale=scale,
+                           block_sizes=tiles)
 
 
 # custom_vjp only to hold the precision scope over the backward's trace
@@ -377,16 +427,21 @@ def _flash_attention(q, k, v, block_size=512, causal=False, sm_scale=None):
         # mask convention, fp32-accumulated row sums). The s_q x s_kv
         # score tensor is small here, and a single batched matmul pair
         # beats any streaming kernel (measured: the Pallas kernels cost
-        # ~20x at S=128 — see docs/perf_notes.md).
+        # ~20x at S=128, at the library's default tiles — see
+        # docs/perf_notes.md).
         from ..parallel.ring_attention import attention_reference
+        _count_traced_attention("dense")
         return attention_reference(q, k, v, causal=causal, scale=scale)
-    # on TPU hardware route to the hand-tiled Pallas kernel (MXU-tiled
-    # blocks, VMEM-resident online softmax); the jnp blockwise kernel is
-    # the portable path and the CPU-test oracle. What the library kernel
-    # raises is raised: a quiet second path would hide a slower run on
-    # the chip (tests/test_chip_compile.py compiles this call for a v5e).
-    # Inside jit the platform is only known at lowering (pallas.runs_on),
-    # so there both paths are staged and the lowering keeps one.
+    # on TPU hardware route to the library's hand-tiled Pallas kernel
+    # (MXU-tiled blocks, VMEM-resident online softmax), called with the
+    # tiles _flash_tiles reads from (S_q, S_kv, D, dtype) and not the
+    # library's 128 everywhere; the jnp blockwise kernel is the portable
+    # path and the CPU-test oracle, and ``block_size`` is its key/value
+    # block and nothing else's. What the library kernel raises is raised:
+    # a quiet second path would hide a slower run on the chip
+    # (tests/test_chip_compile.py compiles this call for a v5e). Inside
+    # jit the platform is only known at lowering (pallas.runs_on), so
+    # there both paths are staged and the lowering keeps one.
     from ..pallas import mode as _pallas_mode
     from ..pallas.registry import runs_on
 
@@ -400,10 +455,14 @@ def _flash_attention(q, k, v, block_size=512, causal=False, sm_scale=None):
             q.shape[-1] >= 64 and q.dtype in (jnp.bfloat16, jnp.float32):
         def on_tpu(q, k, v):
             return _tpu_flash_attention(q, k, v, causal, scale)
+        tiles = _flash_tiles(q.shape[-2], k.shape[-2], q.shape[-1], q.dtype)
+        _count_traced_attention("tpu_kernel", tiles.block_q,
+                                tiles.block_k_major)
         if staged:
             return lax.platform_dependent(q, k, v, tpu=on_tpu,
                                           default=portable)
         return on_tpu(q, k, v)
+    _count_traced_attention("portable", block_k=block_size)
     return portable(q, k, v)
 
 

@@ -65,6 +65,66 @@ def test_flash_attention_op_via_nd():
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s", [1152, 1280, 2048, 4096, 8192, 32768])
+def test_flash_tiles_are_legal_for_the_library_kernel(s, d, dtype):
+    """Every tile the rule reads from the shapes is a multiple of 128 that
+    divides its sequence length, each minor tile divides its major, and the
+    library's own checks accepted the eleven together (``BlockSizes``
+    raises from ``__post_init__`` where they do not)."""
+    import dataclasses
+    from mxnet_tpu.ops.contrib import _flash_tiles
+    for s_q, s_kv in ((s, s), (s, 2048), (1152, s)):
+        tiles = _flash_tiles(s_q, s_kv, d, dtype)
+        assert tiles.has_backward_blocks and tiles.block_b == 1
+        fields = dataclasses.asdict(tiles)
+        for name, tile in fields.items():
+            if name == "block_b":
+                continue
+            length = s_q if "block_q" in name else s_kv
+            assert tile % 128 == 0 and length % tile == 0, (name, tile)
+        for major, minor in (("block_k_major", "block_k"),
+                             ("block_q_major_dkv", "block_q_dkv"),
+                             ("block_k_major_dkv", "block_k_dkv"),
+                             ("block_k_major_dq", "block_k_dq")):
+            assert fields[major] % fields[minor] == 0, (major, minor)
+
+
+def test_flash_tiles_read_the_shape():
+    """Past the default: a long sequence gets tiles above the library's
+    128, and a length only 128 and 384 divide gets one of those."""
+    from mxnet_tpu.ops.contrib import _flash_tiles
+    tiles = _flash_tiles(4096, 4096, 64, jnp.bfloat16)
+    assert tiles.block_q > 128 and tiles.block_k_major > 128
+    assert tiles.block_q_dkv > 128 and tiles.block_q_dq > 128
+    odd = _flash_tiles(1152, 1152, 64, jnp.bfloat16)
+    assert odd.block_q in (128, 384) and odd.block_k_major in (128, 384)
+
+
+@pytest.mark.parametrize("s, branch, block_k",
+                         [(128, "dense", ""), (2048, "portable", "512")])
+def test_flash_attention_counts_the_branch_it_traced(s, branch, block_k):
+    from mxnet_tpu import observability
+    from mxnet_tpu.ops.contrib import FLASH_COUNT_METRIC
+    key = f"branch={branch},block_q=,block_k={block_k}"
+
+    def count():
+        return observability.snapshot()["metrics"].get(
+            FLASH_COUNT_METRIC, {}).get("values", {}).get(key, 0)
+
+    before = count()
+    q, k, v = _qkv(B=1, H=1, S=s, D=64)
+    out = mx.nd.contrib.flash_attention(
+        mx.nd.array(np.asarray(q)), mx.nd.array(np.asarray(k)),
+        mx.nd.array(np.asarray(v)), causal=True)
+    assert count() == before + 1
+    ref = attention_reference(q, k, v, causal=True)
+    np.testing.assert_allclose(out.asnumpy(), np.asarray(ref),
+                               rtol=1e-4, atol=1e-4)
+
+
 def _tiny_bert(**kw):
     cfg = dict(num_layers=2, units=32, hidden_size=64, num_heads=4,
                max_length=64, vocab_size=100, dropout=0.1)

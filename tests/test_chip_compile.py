@@ -150,18 +150,32 @@ def test_training_step_through_a_kernel_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# (B, H, S, D), dtype: BERT-base heads at S=2048; Granite's attention layer
+# (32 heads of 64, one 4096-token sequence); a length that only 128 and 384
+# divide; float32, whose products take several passes over the score tile
+# (at Granite's shape the compiler refused its dkv kernel at tiles of 1024)
+FLASH_SHAPES = {
+    "bert_s2048_d64": ((1, 12, 2048, 64), jnp.bfloat16),
+    "bert_s2048_d128": ((1, 12, 2048, 128), jnp.bfloat16),
+    "granite_s4096_d64": ((1, 32, 4096, 64), jnp.bfloat16),
+    "s1152_d64": ((1, 12, 1152, 64), jnp.bfloat16),
+    "f32_s2048_d128": ((1, 12, 2048, 128), jnp.float32),
+    "f32_s4096_d64": ((1, 32, 4096, 64), jnp.float32),
+}
+
+
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
-@pytest.mark.parametrize("head_dim", [64, 128])
-def test_flash_attention_call_compiles_forward_and_backward(one_chip,
-                                                            head_dim,
+@pytest.mark.parametrize("case", list(FLASH_SHAPES))
+def test_flash_attention_call_compiles_forward_and_backward(one_chip, case,
                                                             causal):
     """The library flash-attention call ``_contrib_flash_attention`` takes
-    on a TPU for long sequences (BERT-base heads at S=2048), in bf16 under
-    the package's process-wide HIGHEST matmul precision."""
+    on a TPU for long sequences, at the tiles ``_flash_tiles`` reads from
+    the shapes, under the package's process-wide HIGHEST matmul precision:
+    forward and ``jax.grad``, each through the kernel."""
     from mxnet_tpu.ops.contrib import _tpu_flash_attention
-    qkv = jax.ShapeDtypeStruct((1, 12, 2048, head_dim), jnp.bfloat16,
-                               sharding=one_chip)
-    scale = head_dim ** -0.5
+    shape, dtype = FLASH_SHAPES[case]
+    qkv = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    scale = shape[-1] ** -0.5
 
     def fwd(q, k, v):
         return _tpu_flash_attention(q, k, v, causal, scale)
@@ -170,7 +184,8 @@ def test_flash_attention_call_compiles_forward_and_backward(one_chip,
         return fwd(q, k, v).astype(jnp.float32).sum()
 
     assert "tpu_custom_call" in _compile(fwd, qkv, qkv, qkv).as_text()
-    _compile(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+    grad = _compile(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+    assert grad.as_text().count("tpu_custom_call") >= 3
 
 
 def test_tuned_blocks_the_compiler_refuses_are_refused_first(one_chip):
