@@ -58,22 +58,14 @@ def cmd_trial(args) -> int:
     spec = registry.get_kernel(args.kernel)
     r, c = _parse_rc(args.shape)
     rng = np.random.RandomState(0)
-    params = {}
-    if args.kernel == "conv_epilogue":
-        call_args = (jnp.asarray(rng.randn(r, c), jnp.float32),
-                     jnp.asarray(rng.rand(1, c) + 0.5, jnp.float32),
-                     jnp.asarray(rng.randn(1, c) * 0.1, jnp.float32),
-                     None)
-        params["act_type"] = "relu"
-    elif args.kernel == "matmul_epilogue":
-        call_args = (jnp.asarray(rng.randn(r, c), jnp.float32),
-                     jnp.asarray(rng.randn(1, c) * 0.1, jnp.float32),
-                     None)
-        params["act_type"] = "gelu"
-    else:
+    if args.kernel != "matmul_epilogue":
         _emit({"metric": "autotune_kernel_elems_per_sec", "value": None,
                "error": "unknown_kernel", "detail": args.kernel})
         return 1
+    call_args = (jnp.asarray(rng.randn(r, c), jnp.float32),
+                 jnp.asarray(rng.randn(1, c) * 0.1, jnp.float32),
+                 None)
+    params = {"act_type": "gelu"}
     block = None
     if args.block:
         block = _parse_rc(args.block)
@@ -299,7 +291,7 @@ def main(argv=None) -> int:
     s.add_argument("--families", default="kernel,serving",
                    help="comma list of knob families to search "
                         "(kernel, serving)")
-    s.add_argument("--kernel", default="conv_epilogue",
+    s.add_argument("--kernel", default="matmul_epilogue",
                    help="registered Pallas kernel to tune")
     s.add_argument("--kernel-shape", default="256x128",
                    help="RxC shape class to tune the kernel at")

@@ -135,7 +135,7 @@ class KernelObjective(_Objective):
 
     name = "kernel"
 
-    def __init__(self, kernel: str = "conv_epilogue", r: int = 256,
+    def __init__(self, kernel: str = "matmul_epilogue", r: int = 256,
                  c: int = 128, iters: int = 30, deadline_s: float = 120.0,
                  interpret: bool = True):
         super().__init__(deadline_s)

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 
 from ...base import MXNetError
+from ...ops.contrib import MATMUL_EPILOGUE_ACTS
 from .. import nn
 from ..block import HybridBlock
 
@@ -97,8 +98,8 @@ class PositionwiseFFN(HybridBlock):
     def __init__(self, units, hidden_size, dropout=0.0, activation="gelu",
                  **kwargs):
         super().__init__(**kwargs)
-        from ..nn.basic_layers import _EPILOGUE_ACTS
-        fused_act = activation if activation in _EPILOGUE_ACTS else None
+        fused_act = activation if activation in MATMUL_EPILOGUE_ACTS \
+            else None
         with self.name_scope():
             self.ffn_1 = nn.Dense(hidden_size, flatten=False, prefix="ffn1_",
                                   activation=fused_act)

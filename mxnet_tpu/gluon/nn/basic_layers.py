@@ -9,6 +9,7 @@ from __future__ import annotations
 import numpy as np
 
 from ...base import MXNetError
+from ...ops.contrib import MATMUL_EPILOGUE_ACTS
 from ..block import Block, HybridBlock
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "BatchNorm",
@@ -86,13 +87,6 @@ class HybridSequential(HybridBlock):
         return iter(self._children.values())
 
 
-# activations the fused matmul-epilogue kernel handles (docs/pallas.md):
-# Dense routes these through ONE bias+act(+dropout) pass over the matmul
-# output instead of separate FullyConnected-bias / Activation ops. gelu
-# is epilogue-only (the plain Activation op has no gelu mode).
-_EPILOGUE_ACTS = ("relu", "tanh", "sigmoid", "gelu")
-
-
 class Dense(HybridBlock):
     """y = act(x W^T + b) (ref: nn.Dense → FullyConnected op).
 
@@ -129,8 +123,11 @@ class Dense(HybridBlock):
         self.weight._set_shape((self._units, in_units))
 
     def hybrid_forward(self, F, x, weight, bias=None):
+        # bias + activation (+ dropout) ride the matmul output in ONE pass
+        # (docs/pallas.md) instead of FullyConnected's bias and a separate
+        # Activation; gelu is epilogue-only (Activation has no gelu mode)
         fuse = bias is not None and (
-            self._activation in _EPILOGUE_ACTS
+            self._activation in MATMUL_EPILOGUE_ACTS
             or (self._activation is None and self._epilogue_dropout > 0))
         if fuse:
             out = F.FullyConnected(x, weight, num_hidden=self._units,

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..base import MXNetError
+from ..pallas.kernels import EPILOGUE_ACTS
 from .nn import _epilogue_act
 from .registry import OpParam, register
 
@@ -475,6 +476,11 @@ def _flash_attention(q, k, v, block_size=512, causal=False, sm_scale=None):
 def _conv_epilogue_contrib(x, res, act_type="relu"):
     return _epilogue_act(x.astype(jnp.float32) + res.astype(jnp.float32),
                          act_type, x.dtype)
+
+
+# the activations Gluon folds into ``_contrib_matmul_epilogue`` (Dense,
+# PositionwiseFFN): the kernel tier's set; identity is what a bare bias is
+MATMUL_EPILOGUE_ACTS = tuple(a for a in EPILOGUE_ACTS if a != "identity")
 
 
 @register("_contrib_matmul_epilogue", num_inputs=2, needs_rng=True,

@@ -255,8 +255,9 @@ def _epilogue_act(out, act_type, dtype):
     elif act_type in ("relu", "tanh", "sigmoid"):
         out = _activation(out, act_type=act_type)
     elif act_type not in (None, "identity"):
+        from ..pallas import EPILOGUE_ACTS
         raise MXNetError(f"epilogue: unknown act_type {act_type!r}; one of "
-                         "identity, relu, gelu, tanh, sigmoid")
+                         f"{', '.join(EPILOGUE_ACTS)}")
     return out.astype(dtype)
 
 

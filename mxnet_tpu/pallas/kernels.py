@@ -1,13 +1,7 @@
 """Seed kernels of the guarded Pallas tier (docs/pallas.md).
 
-Three kernels target the two profiled ceilings docs/perf_notes.md ends on:
+Two kernels, each dispatched by a benchmark cell:
 
-- ``conv_epilogue`` — scale·y + bias + residual + activation over 2-D
-  rows in ONE VMEM pass. No op dispatches it: BatchNorm's ``act_type``
-  and ``nd.contrib.conv_epilogue`` compute the same formula as plain
-  jax.numpy on the N-D array (a 2-D view of a tiled NCHW activation is a
-  physical re-layout on the chip, which cost 5.5x the step; PERF.md §6,
-  PR 26). It stays registered as the tier's worked example.
 - ``matmul_epilogue`` — the BERT lever (~56% MFU inside XLA's matmul
   fusions, dropout-mask traffic measured 24% of a step pre-rbg): bias +
   activation + inverted dropout applied in one pass over the matmul
@@ -18,6 +12,10 @@ Three kernels target the two profiled ceilings docs/perf_notes.md ends on:
 - ``blockwise_attention`` — the existing long-context online-softmax
   kernel (parallel/ring_attention.py), routed through the same registry
   so every custom kernel shares one kill-switch / parity / journal story.
+
+(A third, ``conv_epilogue``, was the ResNet lever until it lost on the
+chip — a 2-D view of a tiled NCHW activation is a physical re-layout,
+5.5x the step; PERF.md §6, PR 26 — and was deleted in PR 29.)
 
 Every kernel registers with its XLA reference and tolerance; gradients of
 the Pallas paths are ``custom_vjp`` with the reference's VJP as the
@@ -137,156 +135,6 @@ def _epilogue_tune_key(y, *rest, **params):
     if getattr(y, "ndim", 0) != 2:
         return None
     return f"{y.shape[0]}x{y.shape[1]}"
-
-
-# ---------------------------------------------------------------------------
-# conv epilogue: act(scale * y + bias [+ res]) in one VMEM pass
-# ---------------------------------------------------------------------------
-def _conv_epilogue_ref(y, scale, bias, res=None, act_type="relu",
-                       block=None):
-    """The XLA reference (the semantic contract): fp32 accumulation, cast
-    back to y's dtype — matching the kernel's internal math. ``block`` is
-    the Pallas tier's tiling knob; tiling doesn't change semantics, so
-    the reference accepts and ignores it (fallback keeps one signature)."""
-    out = (y.astype(jnp.float32) * scale.astype(jnp.float32)
-           + bias.astype(jnp.float32))
-    if res is not None:
-        out = out + res.astype(jnp.float32)
-    return _act_fn(act_type)(out).astype(y.dtype)
-
-
-def _conv_epilogue_call(y, scale, bias, res, act_type, interpret, block):
-    from jax.experimental import pallas as pl
-    r, c = y.shape
-    br, bc = _block_pair(r, c, block)
-    act = _act_fn(act_type, in_kernel=True)
-    data = pl.BlockSpec((br, bc), lambda i, j: (i, j))
-
-    def kernel(y_ref, s_ref, b_ref, *rest):
-        o_ref = rest[-1]
-        out = (y_ref[...].astype(jnp.float32)
-               * s_ref[...].astype(jnp.float32)
-               + b_ref[...].astype(jnp.float32))
-        if len(rest) == 2:
-            out = out + rest[0][...].astype(jnp.float32)
-        o_ref[...] = act(out).astype(o_ref.dtype)
-
-    in_specs = [data, _vec_spec(scale.shape, br, bc),
-                _vec_spec(bias.shape, br, bc)]
-    args = [y, scale, bias]
-    if res is not None:
-        in_specs.append(data)
-        args.append(res)
-    return pl.pallas_call(
-        kernel, grid=(pl.cdiv(r, br), pl.cdiv(c, bc)), in_specs=in_specs,
-        out_specs=data,
-        out_shape=_out_struct(y, args),
-        interpret=interpret)(*args)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _ce_res(act_type, interpret, block, y, scale, bias, res):
-    return _conv_epilogue_call(y, scale, bias, res, act_type, interpret,
-                               block)
-
-
-def _ce_res_fwd(act_type, interpret, block, y, scale, bias, res):
-    return (_ce_res(act_type, interpret, block, y, scale, bias, res),
-            (y, scale, bias, res))
-
-
-def _ce_res_bwd(act_type, interpret, block, saved, g):
-    y, scale, bias, res = saved
-    _, vjp = jax.vjp(
-        lambda a, s, b, r: _conv_epilogue_ref(a, s, b, r,
-                                              act_type=act_type),
-        y, scale, bias, res)
-    return vjp(g)
-
-
-_ce_res.defvjp(_ce_res_fwd, _ce_res_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _ce_nores(act_type, interpret, block, y, scale, bias):
-    return _conv_epilogue_call(y, scale, bias, None, act_type, interpret,
-                               block)
-
-
-def _ce_nores_fwd(act_type, interpret, block, y, scale, bias):
-    return (_ce_nores(act_type, interpret, block, y, scale, bias),
-            (y, scale, bias))
-
-
-def _ce_nores_bwd(act_type, interpret, block, saved, g):
-    y, scale, bias = saved
-    _, vjp = jax.vjp(
-        lambda a, s, b: _conv_epilogue_ref(a, s, b, act_type=act_type),
-        y, scale, bias)
-    return vjp(g)
-
-
-_ce_nores.defvjp(_ce_nores_fwd, _ce_nores_bwd)
-
-
-def _conv_epilogue_supports(y, scale, bias, res=None, act_type="relu",
-                            block=None):
-    if y.ndim != 2:
-        return f"not_2d:{y.shape}"
-    if y.size == 0:
-        return "empty"
-    if y.shape[1] < 8:
-        return f"minor_dim_tiny:{y.shape[1]}"
-    bad = _check_dtype("y", y)
-    for name, v in (("scale", scale), ("bias", bias)):
-        bad = bad or _check_vec(name, v, y)
-    if bad:
-        return bad
-    if scale.shape != bias.shape:
-        return f"shape:scale{scale.shape}_vs_bias{bias.shape}"
-    if res is not None:
-        if res.shape != y.shape:
-            return f"shape:res{res.shape}_vs_y{y.shape}"
-        bad = _check_dtype("res", res)
-        if bad:
-            return bad
-    if act_type not in (None,) + EPILOGUE_ACTS:
-        return f"act:{act_type}"
-    return None
-
-
-def _conv_epilogue_example():
-    rng = np.random.RandomState(0)
-    y = jnp.asarray(rng.randn(16, 128), jnp.float32)
-    res = jnp.asarray(rng.randn(16, 128), jnp.float32)
-    col = (jnp.asarray(rng.rand(1, 128) + 0.5, jnp.float32),
-           jnp.asarray(rng.randn(1, 128) * 0.1, jnp.float32))
-    row = (jnp.asarray(rng.rand(16, 1) + 0.5, jnp.float32),
-           jnp.asarray(rng.randn(16, 1) * 0.1, jnp.float32))
-    return [
-        ((y, col[0], col[1], res), {"act_type": "relu"}),
-        ((y, row[0], row[1], None), {"act_type": "relu"}),
-        ((y, col[0], col[1], None), {"act_type": "gelu"}),
-    ]
-
-
-@register_kernel(
-    "conv_epilogue", xla_reference=_conv_epilogue_ref, tolerance=1e-5,
-    backends=("tpu",), supports=_conv_epilogue_supports,
-    example=_conv_epilogue_example,
-    doc="act(scale*y + bias [+ res]) over 2D rows in one VMEM pass "
-        "(registered, dispatched by no op: docs/pallas.md). scale/bias "
-        "broadcast as (1, C) columns or (R, 1) rows. block=(br, bc) "
-        "overrides the "
-        "default tiling (tuned tables; every tiling is bit-identical, a "
-        "block the chip's compiler would refuse clamps to the default).",
-    tune_key=_epilogue_tune_key)
-def _conv_epilogue_pallas(y, scale, bias, res=None, interpret=False,
-                          act_type="relu", block=None):
-    block = None if block is None else (int(block[0]), int(block[1]))
-    if res is None:
-        return _ce_nores(act_type, bool(interpret), block, y, scale, bias)
-    return _ce_res(act_type, bool(interpret), block, y, scale, bias, res)
 
 
 # ---------------------------------------------------------------------------

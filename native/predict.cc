@@ -5,10 +5,13 @@
 // self-contained CPU graph interpreter covers the deployment path the
 // reference's amalgamation/mobile builds serve).
 //
-// Supported ops: Convolution, FullyConnected, BatchNorm (inference),
-// Activation, Pooling, Flatten, Reshape, elemwise/broadcast
-// add/mul/sub/div, scalar ops, Concat, softmax, log_softmax, Dropout
-// (identity), LeakyReLU (leaky/elu/gelu), Embedding, LayerNorm,
+// Supported ops: Convolution, FullyConnected, BatchNorm (inference, with
+// its fused act_type), Activation, Pooling, Flatten, Reshape,
+// elemwise/broadcast add/mul/sub/div, scalar ops, Concat, softmax,
+// log_softmax, Dropout (identity), LeakyReLU (leaky/elu/gelu), Embedding,
+// LayerNorm, the fused epilogues Gluon's Dense and the resnet-v1 blocks
+// export (_contrib_matmul_epilogue = act(y + bias), _contrib_conv_epilogue
+// = act(x + res); contrib/onnx/mx2onnx.py lowers them the same way),
 // fused self/cross attention, transpose, batch_dot, slice/slice_like,
 // expand_dims, squeeze — the exported-model op sets of the model zoo's
 // image classifiers (LeNet/MLP/ResNet/VGG) AND the transformer family
@@ -619,6 +622,31 @@ static void broadcast_binary(const Tensor& a, const Tensor& b, int op,
   }
 }
 
+// One activation by its MXNet name, in place: the act_types of Activation,
+// of LeakyReLU (`slope`) and of the fused epilogue ops, whose absent or
+// "None" act_type is the identity.
+static void apply_activation(Tensor& t, const std::string& act,
+                             float slope = 0.25f) {
+  if (act.empty() || act == "None" || act == "identity") return;
+  static const char* const names[] = {"relu", "sigmoid", "tanh", "softrelu",
+                                      "gelu", "leaky", "elu"};
+  size_t kind = 0;
+  while (kind < 7 && act != names[kind]) ++kind;
+  if (kind == 7) throw std::runtime_error("predict: unknown act_type " + act);
+  for (float& v : t.data) {
+    switch (kind) {
+      case 0: v = std::max(v, 0.f); break;
+      case 1: v = 1.f / (1.f + std::exp(-v)); break;
+      case 2: v = std::tanh(v); break;
+      case 3: v = std::log1p(std::exp(v)); break;
+      case 4:   // exact erf form, like jax.nn.gelu
+        v = 0.5f * v * (1.f + std::erf(v * 0.70710678f)); break;
+      case 5: v = v > 0 ? v : slope * v; break;
+      default: v = v > 0 ? v : slope * std::expm1(v);   // elu
+    }
+  }
+}
+
 // tuple parser that keeps None entries as LONG_MIN sentinels (for slice)
 static const long kNone = LONG_MIN;
 static std::vector<long> parse_tuple_opt(const std::string& s) {
@@ -768,33 +796,27 @@ struct Predictor {
         batchnorm(in(n, 0), in(n, 1), in(n, 2), in(n, 3), in(n, 4),
                   parse_float(a("eps"), 1e-3),
                   parse_bool(a("fix_gamma"), true), out);
+        apply_activation(out, a("act_type"));   // nn.BatchNorm(activation=)
         values[id] = {out, in(n, 3), in(n, 4)};
         continue;
       } else if (n.op == "Activation") {
         out = in(n, 0);
-        std::string act = a("act_type");
-        for (float& v : out.data) {
-          if (act == "relu") v = std::max(v, 0.f);
-          else if (act == "sigmoid") v = 1.f / (1.f + std::exp(-v));
-          else if (act == "tanh") v = std::tanh(v);
-          else if (act == "softrelu") v = std::log1p(std::exp(v));
-          else throw std::runtime_error("activation " + act);
-        }
+        apply_activation(out, a("act_type"));
       } else if (n.op == "relu") {
         out = in(n, 0);
-        for (float& v : out.data) v = std::max(v, 0.f);
+        apply_activation(out, "relu");
       } else if (n.op == "LeakyReLU") {
         out = in(n, 0);
-        float slope = (float)parse_float(a("slope"), 0.25);
-        std::string act = a("act_type");
-        if (act.empty()) act = "leaky";
-        for (float& v : out.data) {
-          if (act == "leaky") v = v > 0 ? v : slope * v;
-          else if (act == "elu") v = v > 0 ? v : slope * std::expm1(v);
-          else if (act == "gelu")   // exact erf form, like jax.nn.gelu
-            v = 0.5f * v * (1.f + std::erf(v * 0.70710678f));
-          else throw std::runtime_error("LeakyReLU act_type " + act);
-        }
+        apply_activation(out, a("act_type").empty() ? "leaky" : a("act_type"),
+                         (float)parse_float(a("slope"), 0.25));
+      } else if (n.op == "_contrib_matmul_epilogue" ||
+                 n.op == "_contrib_conv_epilogue") {
+        // act(in0 + in1): the bias broadcasts over the last axis, the
+        // residual is elementwise; `p` is training-only, as Dropout's
+        broadcast_binary(in(n, 0), in(n, 1), 0, out);
+        bool conv = n.op == "_contrib_conv_epilogue";
+        apply_activation(out, conv && a("act_type").empty() ? "relu"
+                                                            : a("act_type"));
       } else if (n.op == "Pooling") {
         auto kernel = a("kernel").empty() ? std::vector<long>{1, 1}
                                           : parse_tuple(a("kernel"));

@@ -38,5 +38,5 @@ def disabled_twin(x):
 def registry_path_is_clean(x):
     # the sanctioned route: registered kernel + guarded dispatch
     from mxnet_tpu.pallas import dispatch
-    return dispatch("conv_epilogue", x, jnp.ones((1, x.shape[1])),
-                    jnp.zeros((1, x.shape[1])), None, act_type="relu")
+    return dispatch("matmul_epilogue", x, jnp.zeros((1, x.shape[1])),
+                    None, act_type="relu")

@@ -99,7 +99,7 @@ def _mlp(dim=8):
 # ---------------------------------------------------------------------------
 class TestTableRoundtrip:
     def test_build_commit_read_smoke(self, tmp_path, journal_file):
-        doc = _table_doc(pallas={"conv_epilogue":
+        doc = _table_doc(pallas={"matmul_epilogue":
                                  {"64x32": {"block": [16, 16]}}},
                          serving={"window_ms": 2.0})
         path = str(tmp_path / "t.json")
@@ -108,16 +108,16 @@ class TestTableRoundtrip:
             path, envelope=attable.current_envelope())
         assert reason is None
         assert got == doc
-        assert attable.pallas_entry(got, "conv_epilogue",
+        assert attable.pallas_entry(got, "matmul_epilogue",
                                     "64x32")["block"] == [16, 16]
         assert attable.knob(got, "serving", "window_ms") == 2.0
         kinds = [r["kind"] for r in _records(journal_file)]
         assert "tuned_commit" in kinds
 
     def test_wildcard_shape_class(self):
-        doc = _table_doc(pallas={"conv_epilogue":
+        doc = _table_doc(pallas={"matmul_epilogue":
                                  {"*": {"block": [8, 8]}}})
-        assert attable.pallas_entry(doc, "conv_epilogue",
+        assert attable.pallas_entry(doc, "matmul_epilogue",
                                     "999x999")["block"] == [8, 8]
         assert attable.pallas_entry(doc, "other_kernel", "8x8") is None
 
@@ -298,7 +298,7 @@ class TestSpacesAndSearch:
     def test_pallas_space_only_valid_tilings_smoke(self):
         # valid = what the chip's compiler takes: rows in multiples of 8
         # and lanes in multiples of 128, or the whole dim
-        sp = atspace.pallas_block_space("conv_epilogue", 48, 20)
+        sp = atspace.pallas_block_space("matmul_epilogue", 48, 20)
         rng = random.Random(0)
         for _ in range(50):
             cfg = sp.sample(rng)
@@ -306,11 +306,12 @@ class TestSpacesAndSearch:
         assert sp.reason({"block_r": 7, "block_c": 4}) is not None
         assert sp.reason({"block_r": 16, "block_c": 10}) is not None
         assert sp.reason(dict(sp.default)) is None
-        # a real width whose default does not divide it (3136 = 12.25x256)
-        big = atspace.pallas_block_space("conv_epilogue", 65536, 3136)
+        # a real width whose default does not divide it: BERT's vocabulary
+        # projection (30522 = 119.2x256)
+        big = atspace.pallas_block_space("matmul_epilogue", 16384, 30522)
         assert big.default == {"block_r": 512, "block_c": 256}
         assert big.reason(dict(big.default)) is None
-        assert big.reason({"block_r": 512, "block_c": 224}) is not None
+        assert big.reason({"block_r": 512, "block_c": 96}) is not None
 
     def test_bucket_space_enforces_grid_bound(self):
         sp = atspace.bucket_space(max_batch=8, compile_cap=2)
@@ -445,7 +446,7 @@ class TestRunner:
             self, tmp_path, journal_file):
         """One REAL kernel trial through the subprocess harness: the
         parity gate runs in the child and a fitness comes back."""
-        obj = atrunner.KernelObjective(kernel="conv_epilogue", r=32,
+        obj = atrunner.KernelObjective(kernel="matmul_epilogue", r=32,
                                        c=16, iters=2, deadline_s=120.0)
         res = atrunner.TrialRunner(
             obj, workdir=str(tmp_path)).evaluate(
@@ -509,24 +510,23 @@ class TestConsumers:
         from mxnet_tpu.pallas import registry
         rng = np.random.RandomState(0)
         y = jnp.asarray(rng.randn(64, 32), np.float32)
-        sc = jnp.asarray(rng.rand(1, 32) + 0.5, np.float32)
         b = jnp.asarray(rng.randn(1, 32) * 0.1, np.float32)
-        args = (y, sc, b, None)
-        base = registry.dispatch("conv_epilogue", *args,
+        args = (y, b, None)
+        base = registry.dispatch("matmul_epilogue", *args,
                                  act_type="relu", interpret=True)
         attable.commit_table(
-            _table_doc(pallas={"conv_epilogue":
+            _table_doc(pallas={"matmul_epilogue":
                                {"64x32": {"block": [16, 32]}}}),
             tuned_env)
         attable.reset_cache()
         registry.reset_provenance()
-        tuned = registry.dispatch("conv_epilogue", *args,
+        tuned = registry.dispatch("matmul_epilogue", *args,
                                   act_type="relu", interpret=True)
         assert (np.asarray(base) == np.asarray(tuned)).all()
         loads = [r for r in _records(journal_file, "tuned_load")
                  if r["site"] == "pallas"]
         assert loads and loads[0]["block"] == [16, 32]
-        assert loads[0]["kernel"] == "conv_epilogue"
+        assert loads[0]["kernel"] == "matmul_epilogue"
         assert loads[0]["shape_class"] == "64x32"
 
     def test_dispatch_refuses_invalid_tuned_block(self, tuned_env,
@@ -535,18 +535,17 @@ class TestConsumers:
         from mxnet_tpu.pallas import registry
         rng = np.random.RandomState(1)
         y = jnp.asarray(rng.randn(64, 32), np.float32)
-        sc = jnp.asarray(rng.rand(1, 32) + 0.5, np.float32)
         b = jnp.asarray(rng.randn(1, 32) * 0.1, np.float32)
         # 16 lanes of 32 is neither a multiple of 128 nor the whole dim:
         # the table is schema-valid but the chip's compiler would refuse
         # the block — dispatch must refuse it first, journaled
         attable.commit_table(
-            _table_doc(pallas={"conv_epilogue":
+            _table_doc(pallas={"matmul_epilogue":
                                {"64x32": {"block": [48, 16]}}}),
             tuned_env)
         attable.reset_cache()
         registry.reset_provenance()
-        out = registry.dispatch("conv_epilogue", y, sc, b, None,
+        out = registry.dispatch("matmul_epilogue", y, b, None,
                                 act_type="relu", interpret=True)
         assert out.shape == (64, 32)
         falls = [r for r in _records(journal_file, "tuned_fallback")
@@ -561,17 +560,16 @@ class TestConsumers:
         from mxnet_tpu.pallas import registry
         rng = np.random.RandomState(2)
         y = jnp.asarray(rng.randn(32, 16), np.float32)
-        sc = jnp.asarray(rng.rand(1, 16) + 0.5, np.float32)
         b = jnp.asarray(rng.randn(1, 16) * 0.1, np.float32)
-        base = registry.dispatch("conv_epilogue", y, sc, b, None,
+        base = registry.dispatch("matmul_epilogue", y, b, None,
                                  act_type="relu", interpret=True)
         for blk in ((8, 16), (32, 16), (24, 16), (7, 3)):  # last clamps
-            out = registry.dispatch("conv_epilogue", y, sc, b, None,
+            out = registry.dispatch("matmul_epilogue", y, b, None,
                                     act_type="relu", interpret=True,
                                     block=blk)
             assert (np.asarray(base) == np.asarray(out)).all(), blk
         g = jax.grad(lambda a: registry.dispatch(
-            "conv_epilogue", a, sc, b, None, act_type="relu",
+            "matmul_epilogue", a, b, None, act_type="relu",
             interpret=True, block=(8, 8)).sum())(y)
         assert g.shape == y.shape
 
@@ -648,9 +646,8 @@ class TestSearchCLI:
             "s = Server(net)\n"
             "rng = np.random.RandomState(0)\n"
             "y = jnp.asarray(rng.randn(64, 32), np.float32)\n"
-            "sc = jnp.asarray(rng.rand(1, 32) + 0.5, np.float32)\n"
             "b = jnp.asarray(rng.randn(1, 32) * 0.1, np.float32)\n"
-            "registry.dispatch('conv_epilogue', y, sc, b, None,\n"
+            "registry.dispatch('matmul_epilogue', y, b, None,\n"
             "                  act_type='relu', interpret=True)\n"
             "print(json.dumps({'window_ms': s.config.window_ms,\n"
             "                  'max_queue': s.config.max_queue}))\n")

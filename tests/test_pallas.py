@@ -6,8 +6,9 @@ reason), gradient parity through the custom_vjp paths, dropout-key
 independence under the PR-1 (layer, tick, shard) fold discipline, the
 gluon/ops wiring (Dense epilogue, blockwise-attention routing, bench A/B
 flag), and what is deliberately NOT wired: BatchNorm act_type and the
-resnet residual epilogue are plain jax.numpy equal to the conv_epilogue
-reference, with no reshape and no kernel in ResNet-50's step."""
+resnet residual epilogue are plain jax.numpy equal to the float32 fold
+the deleted conv_epilogue kernel had as its reference, with no reshape and
+no kernel in ResNet-50's step."""
 import json
 import os
 
@@ -69,31 +70,28 @@ def test_parity_gate_covers_shape_and_dtype(clean_tier):
 
 def test_grads_match_reference_smoke(clean_tier):
     """The custom_vjp paths (pallas forward, reference VJP backward)
-    agree with differentiating the reference end-to-end — scale/bias
-    vectors included, so BN's gamma/beta gradients are covered."""
+    agree with differentiating the reference end-to-end — the bias
+    vector included, so Dense's bias gradient is covered."""
     import jax
     import jax.numpy as jnp
     rng = np.random.RandomState(3)
     y = jnp.asarray(rng.randn(16, 128), jnp.float32)
-    s = jnp.asarray(rng.rand(1, 128) + 0.5, jnp.float32)
     b = jnp.asarray(rng.randn(1, 128) * 0.1, jnp.float32)
-    res = jnp.asarray(rng.randn(16, 128), jnp.float32)
-    spec = pallas.get_kernel("conv_epilogue")
+    mspec = pallas.get_kernel("matmul_epilogue")
 
-    def loss_p(y, s, b, res):
-        return (spec.pallas_impl(y, s, b, res, interpret=True,
-                                 act_type="relu") ** 2).sum()
+    def loss_p(y, b):
+        return (mspec.pallas_impl(y, b, None, interpret=True,
+                                  act_type="relu") ** 2).sum()
 
-    def loss_r(y, s, b, res):
-        return (spec.xla_reference(y, s, b, res, act_type="relu") ** 2).sum()
+    def loss_r(y, b):
+        return (mspec.xla_reference(y, b, None, act_type="relu") ** 2).sum()
 
-    gp = jax.grad(loss_p, argnums=(0, 1, 2, 3))(y, s, b, res)
-    gr = jax.grad(loss_r, argnums=(0, 1, 2, 3))(y, s, b, res)
+    gp = jax.grad(loss_p, argnums=(0, 1))(y, b)
+    gr = jax.grad(loss_r, argnums=(0, 1))(y, b)
     for a, bb in zip(gp, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
                                    rtol=1e-4, atol=1e-4)
-    # matmul epilogue with dropout folded in
-    mspec = pallas.get_kernel("matmul_epilogue")
+    # and with dropout folded in
     bits = pallas.dropout_bits(jax.random.key(5), (16, 128))
     gp = jax.grad(lambda v: (mspec.pallas_impl(
         v, b, bits, interpret=True, act_type="gelu", p=0.3) ** 2).sum())(y)
@@ -118,31 +116,29 @@ def test_fallback_non_tpu_backend_is_journaled_smoke(clean_tier, tmp_path):
     jpath = str(tmp_path / "journal.jsonl")
     reset_journal(jpath)
     try:
-        y = jnp.ones((16, 128))
-        s, b = jnp.ones((1, 128)), jnp.zeros((1, 128))
-        out = pallas.dispatch("conv_epilogue", y, s, b, None,
+        y, b = jnp.ones((16, 128)), jnp.zeros((1, 128))
+        out = pallas.dispatch("matmul_epilogue", y, b, None,
                               act_type="relu")
         assert out.shape == (16, 128)
     finally:
         reset_journal(None)
-    prov = pallas.tier_provenance()["conv_epilogue"]
+    prov = pallas.tier_provenance()["matmul_epilogue"]
     assert prov["pallas"] == 0 and prov["xla"] == 1
     assert prov["fallback_reasons"] == {"backend:cpu": 1}
     recs = [r for r in _journal_records(jpath)
             if r["kind"] == "pallas_fallback"]
     assert len(recs) == 1
-    assert recs[0]["kernel"] == "conv_epilogue"
+    assert recs[0]["kernel"] == "matmul_epilogue"
     assert recs[0]["reason"] == "backend:cpu"
     # dedupe: a second identical fallback journals nothing new but counts
-    pallas.dispatch("conv_epilogue", y, s, b, None, act_type="relu")
-    assert pallas.tier_provenance()["conv_epilogue"]["xla"] == 2
+    pallas.dispatch("matmul_epilogue", y, b, None, act_type="relu")
+    assert pallas.tier_provenance()["matmul_epilogue"]["xla"] == 2
 
 
 def _epilogue_args():
     import jax.numpy as jnp
     rng = np.random.RandomState(3)
     return (jnp.asarray(rng.randn(16, 128), jnp.float32),
-            jnp.asarray(rng.rand(1, 128) + 0.5, jnp.float32),
             jnp.asarray(rng.randn(1, 128) * 0.1, jnp.float32))
 
 
@@ -153,13 +149,13 @@ def test_concrete_operands_decide_by_where_they_live(clean_tier,
     the gate reads the operands, not the process."""
     from mxnet_tpu.pallas import registry
     monkeypatch.setattr(registry, "_backend", lambda: "tpu")
-    y, s, b = _epilogue_args()
-    assert registry.runs_on((y, s, None)) == ("cpu", False)
-    out = pallas.dispatch("conv_epilogue", y, s, b, None, act_type="relu")
-    ref = pallas.get_kernel("conv_epilogue").xla_reference(
-        y, s, b, None, act_type="relu")
+    y, b = _epilogue_args()
+    assert registry.runs_on((y, b, None)) == ("cpu", False)
+    out = pallas.dispatch("matmul_epilogue", y, b, None, act_type="relu")
+    ref = pallas.get_kernel("matmul_epilogue").xla_reference(
+        y, b, None, act_type="relu")
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-    prov = pallas.tier_provenance()["conv_epilogue"]
+    prov = pallas.tier_provenance()["matmul_epilogue"]
     assert prov["fallback_reasons"] == {"backend:cpu": 1}
 
 
@@ -172,22 +168,22 @@ def test_traced_dispatch_stages_kernel_and_reference(clean_tier,
     import jax
     from mxnet_tpu.pallas import registry
     monkeypatch.setattr(registry, "_backend", lambda: "tpu")
-    y, s, b = _epilogue_args()
+    y, b = _epilogue_args()
 
-    def f(y, s, b):
-        return pallas.dispatch("conv_epilogue", y, s, b, None,
+    def f(y, b):
+        return pallas.dispatch("matmul_epilogue", y, b, None,
                                act_type="relu")
 
-    text = str(jax.make_jaxpr(f)(y, s, b))
+    text = str(jax.make_jaxpr(f)(y, b))
     assert "pallas_call" in text and "platform_index" in text
-    out = jax.jit(f)(y, s, b)               # lowered for the CPU here
-    ref = pallas.get_kernel("conv_epilogue").xla_reference(
-        y, s, b, None, act_type="relu")
+    out = jax.jit(f)(y, b)                  # lowered for the CPU here
+    ref = pallas.get_kernel("matmul_epilogue").xla_reference(
+        y, b, None, act_type="relu")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-6, atol=1e-6)
-    g = jax.jit(jax.grad(lambda y: f(y, s, b).sum()))(y)
+    g = jax.jit(jax.grad(lambda y: f(y, b).sum()))(y)
     assert g.shape == y.shape and np.isfinite(np.asarray(g)).all()
-    prov = pallas.tier_provenance()["conv_epilogue"]
+    prov = pallas.tier_provenance()["matmul_epilogue"]
     assert prov["pallas"] >= 2 and prov["xla"] == 0
 
 
@@ -202,26 +198,26 @@ def test_kernel_is_not_staged_into_a_program_the_compiler_partitions(
     from mxnet_tpu.pallas import registry
     from mxnet_tpu.parallel import PartitionSpec as P
     monkeypatch.setattr(registry, "_backend", lambda: "tpu")
-    y, s, b = _epilogue_args()
+    y, b = _epilogue_args()
     mesh = parallel.make_mesh({"data": 4}, devices=jax.devices()[:4])
 
     def fresh():        # a new function each time: traces are cached
-        return lambda y, s, b: pallas.dispatch(
-            "conv_epilogue", y, s, b, None, act_type="relu")
+        return lambda y, b: pallas.dispatch(
+            "matmul_epilogue", y, b, None, act_type="relu")
 
     with parallel.use_mesh(mesh):
-        assert "pallas_call" not in str(jax.make_jaxpr(fresh())(y, s, b))
-    prov = pallas.tier_provenance()["conv_epilogue"]
+        assert "pallas_call" not in str(jax.make_jaxpr(fresh())(y, b))
+    prov = pallas.tier_provenance()["matmul_epilogue"]
     assert prov["fallback_reasons"] == {"auto_partition:4dev": 1}
 
-    by_hand = shard_map(fresh(), mesh=mesh, in_specs=(P("data"), P(), P()),
+    by_hand = shard_map(fresh(), mesh=mesh, in_specs=(P("data"), P()),
                         out_specs=P("data"))
     with parallel.use_mesh(mesh):
-        assert "pallas_call" in str(jax.make_jaxpr(by_hand)(y, s, b))
+        assert "pallas_call" in str(jax.make_jaxpr(by_hand)(y, b))
     # and on the mesh of one device a one-chip trainer uses, it stays too
     with parallel.use_mesh(parallel.make_mesh({"data": 1},
                                               devices=jax.devices()[:1])):
-        assert "pallas_call" in str(jax.make_jaxpr(fresh())(y, s, b))
+        assert "pallas_call" in str(jax.make_jaxpr(fresh())(y, b))
 
 
 def test_fallback_unsupported_shape(clean_tier):
@@ -229,18 +225,17 @@ def test_fallback_unsupported_shape(clean_tier):
     when interpret would otherwise force the custom path."""
     import jax.numpy as jnp
     y = jnp.ones((4, 2))          # minor dim below the tier's floor
-    s, b = jnp.ones((1, 2)), jnp.zeros((1, 2))
-    out = pallas.dispatch("conv_epilogue", y, s, b, None,
+    b = jnp.zeros((1, 2))
+    out = pallas.dispatch("matmul_epilogue", y, b, None,
                           act_type="relu", interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.ones((4, 2)))
-    reasons = pallas.tier_provenance()["conv_epilogue"]["fallback_reasons"]
+    reasons = pallas.tier_provenance()["matmul_epilogue"]["fallback_reasons"]
     assert any(r.startswith("minor_dim_tiny") for r in reasons)
     # int input: dtype gate
-    pallas.dispatch("conv_epilogue", jnp.ones((16, 128), jnp.int32),
-                    jnp.ones((1, 128), jnp.int32),
+    pallas.dispatch("matmul_epilogue", jnp.ones((16, 128), jnp.int32),
                     jnp.zeros((1, 128), jnp.int32), None, act_type="relu",
                     interpret=True)
-    reasons = pallas.tier_provenance()["conv_epilogue"]["fallback_reasons"]
+    reasons = pallas.tier_provenance()["matmul_epilogue"]["fallback_reasons"]
     assert any(r.startswith("dtype") for r in reasons)
 
 
@@ -249,11 +244,10 @@ def test_kill_switch_env_beats_interpret(clean_tier, monkeypatch):
     gets the reference."""
     import jax.numpy as jnp
     monkeypatch.setenv("MXNET_TPU_PALLAS", "off")
-    y = jnp.ones((16, 128))
-    s, b = jnp.ones((1, 128)), jnp.zeros((1, 128))
-    pallas.dispatch("conv_epilogue", y, s, b, None, act_type="relu",
+    y, b = jnp.ones((16, 128)), jnp.zeros((1, 128))
+    pallas.dispatch("matmul_epilogue", y, b, None, act_type="relu",
                     interpret=True)
-    prov = pallas.tier_provenance()["conv_epilogue"]
+    prov = pallas.tier_provenance()["matmul_epilogue"]
     assert prov["pallas"] == 0
     assert prov["fallback_reasons"] == {"mode_off": 1}
 
@@ -268,17 +262,16 @@ def test_malformed_mode_degrades_to_auto(clean_tier, monkeypatch):
 def test_mode_on_makes_fallback_loud(clean_tier):
     import jax.numpy as jnp
     pallas.set_mode("on")
-    y = jnp.ones((16, 128))
-    s, b = jnp.ones((1, 128)), jnp.zeros((1, 128))
+    y, b = jnp.ones((16, 128)), jnp.zeros((1, 128))
     with pytest.warns(RuntimeWarning, match="fell back"):
-        pallas.dispatch("conv_epilogue", y, s, b, None, act_type="relu")
+        pallas.dispatch("matmul_epilogue", y, b, None, act_type="relu")
 
 
 def test_duplicate_registration_rejected(clean_tier):
-    spec = pallas.get_kernel("conv_epilogue")
+    spec = pallas.get_kernel("matmul_epilogue")
     with pytest.raises(MXNetError, match="duplicate"):
         pallas.register_kernel(
-            "conv_epilogue", xla_reference=spec.xla_reference,
+            "matmul_epilogue", xla_reference=spec.xla_reference,
             tolerance=1.0)(spec.pallas_impl)
 
 
@@ -400,6 +393,23 @@ def _bf16_ulps(a, b):
     return np.abs(ordered(a) - ordered(b))
 
 
+def _conv_epilogue_ref(y, scale, bias, res=None, act_type="relu"):
+    """The float32 fold BatchNorm ``act_type=`` and ``contrib.conv_epilogue``
+    are held to: act(scale * y + bias [+ res]) accumulated in float32 and
+    cast back to y's dtype. It was the XLA reference of the ``conv_epilogue``
+    kernel (deleted in PR 29); the plain jax.numpy ops still have to equal
+    it."""
+    import jax
+    import jax.numpy as jnp
+    act = {"relu": lambda x: jnp.maximum(x, 0.0),
+           "gelu": lambda x: jax.nn.gelu(x, approximate=False)}[act_type]
+    out = (y.astype(jnp.float32) * scale.astype(jnp.float32)
+           + bias.astype(jnp.float32))
+    if res is not None:
+        out = out + res.astype(jnp.float32)
+    return act(out).astype(y.dtype)
+
+
 def _rows_view(layout, x, vecs):
     """The 2D view the conv_epilogue kernel was fed: NCHW as (N*C, H*W)
     rows with (R, 1) vectors, channel-last as (rows, C) with (1, C)."""
@@ -427,7 +437,6 @@ def test_batchnorm_act_bf16_is_the_reference_fold(clean_tier, layout, shape,
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops.nn import _batch_norm
-    from mxnet_tpu.pallas.kernels import _conv_epilogue_ref
     rng = np.random.RandomState(11)
     c, eps = shape[axis], 1e-5
     x = jnp.asarray(rng.randn(*shape), jnp.bfloat16)
@@ -483,7 +492,6 @@ def test_batchnorm_act_train_mode_gradients(clean_tier, layout, shape, axis,
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops.nn import _batch_norm
-    from mxnet_tpu.pallas.kernels import _conv_epilogue_ref
     rng = np.random.RandomState(13)
     c, eps, f32 = shape[axis], 1e-5, jnp.float32
     axes = tuple(i for i in range(len(shape)) if i != axis % len(shape))
@@ -531,7 +539,6 @@ def test_contrib_conv_epilogue_bf16_is_the_reference_fold(clean_tier,
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops.contrib import _conv_epilogue_contrib
-    from mxnet_tpu.pallas.kernels import _conv_epilogue_ref
     rng = np.random.RandomState(12)
     x, res, cot = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
                    for _ in range(3))
@@ -577,7 +584,7 @@ def test_batchnorm_act_never_reshapes(clean_tier, training):
 def test_resnet50_step_holds_no_custom_kernel(clean_tier, monkeypatch):
     """ResNet-50's forward and backward, traced under jit with a TPU as the
     default backend and lowered for one, dispatch nothing through the
-    tier: no conv_epilogue in the provenance, no Mosaic call in the text."""
+    tier: the provenance stays empty, no Mosaic call in the text."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.gluon.block import functional_apply
@@ -599,7 +606,7 @@ def test_resnet50_step_holds_no_custom_kernel(clean_tier, monkeypatch):
     traced = jax.jit(jax.value_and_grad(loss, has_aux=True)).trace(
         [p.data()._data.astype(jnp.bfloat16) for p in trainable],
         [p.data()._data for p in aux], jnp.asarray(x, jnp.bfloat16))
-    assert "conv_epilogue" not in pallas.tier_provenance()
+    assert pallas.tier_provenance() == {}
     assert "pallas_call" not in str(traced.jaxpr)
     text = traced.lower(lowering_platforms=("tpu",)).as_text()
     assert "stablehlo.convolution" in text

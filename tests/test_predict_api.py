@@ -75,6 +75,26 @@ def test_lenet_matches_python(native_lib, tmp_path):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("act", ["relu", "tanh", "sigmoid", "gelu"])
+def test_dense_activation_matches_python(native_lib, tmp_path, act):
+    """``Dense(activation=)`` exports ``_contrib_matmul_epilogue``: the
+    native predictor computes act(y + bias) for every activation the
+    epilogue takes."""
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(16, activation=act), gluon.nn.Dense(4))
+    net.initialize(mx.init.Xavier())
+    net.hybridize()
+    x = np.random.RandomState(0).randn(3, 8).astype(np.float32)
+    want = net(nd.array(x)).asnumpy()
+    prefix = str(tmp_path / f"dense_{act}")
+    net.export(prefix)
+    sym = open(f"{prefix}-symbol.json").read()
+    assert "_contrib_matmul_epilogue" in sym and f'"{act}"' in sym
+    got = _predict_native(native_lib, f"{prefix}-symbol.json",
+                          f"{prefix}-0000.params", x)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+
+
 def test_resnet18_matches_python(native_lib, tmp_path):
     from mxnet_tpu.gluon.model_zoo import vision
     net = vision.resnet18_v1(classes=10)
