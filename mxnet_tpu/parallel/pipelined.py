@@ -467,8 +467,9 @@ class PipelinedTrainer(GuardedTrainerMixin):
                 yd = y._data if isinstance(y, nd.NDArray) \
                     else jnp.asarray(y)
             self._optimizer.num_update = self._num_update
-            from .sharded import _lr_sequence
-            lrs = _lr_sequence(self._optimizer, t, num_steps)
+            # each inner step sees the lr a separate step() call would
+            lrs = jnp.asarray([self._lr_at(t + i) for i in range(num_steps)],
+                              jnp.float32)
             lscale = (self._scaler.loss_scale
                       if self._scaler is not None else 1.0)
             e_tr = [p._data[0]._data for p in self._e_params]

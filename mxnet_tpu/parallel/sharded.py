@@ -86,12 +86,16 @@ def _lr_at(optimizer, t):
     return float(optimizer.learning_rate)
 
 
-def _lr_sequence(optimizer, t, num_steps):
-    """Host-evaluated per-step lr array for a scanned multi-step program:
-    each inner step must see the SAME lr a separate step() call would
-    (a frozen first-step lr silently changes warmup/decay math)."""
-    return jnp.asarray([_lr_at(optimizer, t + i) for i in range(num_steps)],
-                       jnp.float32)
+def _scalar_args(lr, t, rescale, lscale):
+    """``t``, ``rescale``, ``lscale`` and ``lr`` (one value, or
+    ``run_steps``' per-step sequence) as every compiled program of the
+    trainer takes them: ONE host float32 array ``[t, rescale, lscale,
+    *lr]`` that the compiled call uploads with its other arguments (one
+    small upload, not four or five). Traced values, so a new lr or loss
+    scale is a new argument and never a new program; not device arrays,
+    whose conversion is a device program each."""
+    return np.concatenate([(t, rescale, lscale), np.atleast_1d(lr)],
+                          dtype=np.float32)
 
 
 def _zeros_like(w):
@@ -496,8 +500,8 @@ class ShardedTrainer(GuardedTrainerMixin):
         # training invisibly, which is worse than the NaN surfacing
         guarded = self._scaler is not None or self._guard_cfg is not None
 
-        def step(tr, aux, states, gstate, key, lr, t, rescale, lscale,
-                 *batch):
+        def raw_step(tr, aux, states, gstate, key, lr, t, rescale, lscale,
+                     *batch):
             inputs, label = batch[:-1], batch[-1]
 
             def loss_of(tr_):
@@ -586,6 +590,14 @@ class ShardedTrainer(GuardedTrainerMixin):
             return (new_tr, aux_new, new_states, gstate2, loss_val,
                     (finite, gnorm), tuple(outs))
 
+        def step(tr, aux, states, gstate, root, scalars, *batch):
+            # the draw of _rng.split_in_program: the split an eager
+            # next_key() makes, inside the program that consumes the subkey
+            root, key = jax.random.split(root)
+            t, rescale, lscale, lr = scalars
+            return raw_step(tr, aux, states, gstate, key, lr, t, rescale,
+                            lscale, *batch) + (jax.random.key_data(root),)
+
         mesh = self.mesh
         ns = lambda spec: NamedSharding(mesh, spec)
         rep = ns(PartitionSpec())
@@ -595,7 +607,7 @@ class ShardedTrainer(GuardedTrainerMixin):
             [tuple(ns(_state_spec(s, e)) for e in st)
              for s, st in zip(self._tr_specs, self._states)],
             (rep, rep),                       # guard state
-            rep, rep, rep, rep, rep,
+            rep, rep,                         # root key, _scalar_args
         ) + tuple(jax.tree_util.tree_map(
             lambda _: None, tuple(range(n_inputs + 1))))  # batch: auto
         out_shardings = (
@@ -605,16 +617,24 @@ class ShardedTrainer(GuardedTrainerMixin):
              for s, st in zip(self._tr_specs, self._states)],
             (rep, rep),                       # guard state
             rep, (rep, rep), None,
+            rep,                              # the new root key's bits
         )
         donate = (0, 2) if self._donate else ()
-        self._raw_step = step
+        self._raw_step = raw_step
         self._shardings = (in_shardings, out_shardings, donate)
         return jax.jit(step, in_shardings=in_shardings,
                        out_shardings=out_shardings, donate_argnums=donate)
 
     def step(self, *batch):
         """Run one fused train step; last positional arg is the label.
-        Returns the (replicated) scalar loss as an NDArray."""
+        Returns the (replicated) scalar loss as an NDArray.
+
+        The call starts one device program, the compiled step: ``t``,
+        ``rescale_grad``, the loss scale and ``lr`` go in as one host
+        float32 array (``_scalar_args``), and the dropout key is split off
+        ``_rng``'s root inside the program, which returns the new root's
+        bits for ``_rng`` to keep (the stream is the one
+        ``_rng.next_key()`` would give, draw for draw)."""
         args = batch[:-1]
         self._prepare(args)
         self._maybe_invalidate_amp()
@@ -634,31 +654,28 @@ class ShardedTrainer(GuardedTrainerMixin):
                 batch_datas = [self._shard_batch_arg(b) for b in batch]
             if 0 not in self._program_batches:
                 self._program_batches[0] = _as_shapes(batch_datas)
-            # host_args: every small device program step() starts beside
-            # the step (the key split, the scalar conversions) starts here
+            # host_args: host work only (the schedule's lr, the parameter
+            # lists, four float32 scalars); it starts no device program
             with _obs.step_phase("sharded_trainer", "host_args"):
                 self._optimizer.num_update = t
-                lr = _lr_at(self._optimizer, t)
-                rescale = self._optimizer.rescale_grad
-                lscale = (self._scaler.loss_scale
-                          if self._scaler is not None else 1.0)
                 tr = [p._data[0]._data for p in self._trainable]
                 aux = [p._data[0]._data for p in self._aux]
                 cshapes = ([list(map(int, np.shape(b))) for b in batch]
                            if compiling else None)
-                scalars = (_rng.next_key(), jnp.float32(lr), jnp.float32(t),
-                           jnp.float32(rescale), jnp.float32(lscale))
+                scalars = _scalar_args(
+                    _lr_at(self._optimizer, t), t,
+                    self._optimizer.rescale_grad, self._loss_scale())
             from .mesh import use_mesh
             # mesh-aware ops (ring attention) trace under use_mesh
             with _obs.step_phase("sharded_trainer", "compiled_step"), \
                     _obs.maybe_compile_span(compiling,
                                             "sharded_trainer.step",
                                             shapes=cshapes), \
-                    use_mesh(self.mesh):
+                    use_mesh(self.mesh), _rng.split_in_program() as draw:
                 (new_tr, aux_new, new_states, gstate, loss_val,
-                 (finite, gnorm), outs) = self._step_fn(
-                    tr, aux, self._states, self._guard_state, *scalars,
-                    *batch_datas)
+                 (finite, gnorm), outs, draw.new_root_data) = self._step_fn(
+                    tr, aux, self._states, self._guard_state, draw.root,
+                    scalars, *batch_datas)
             for p, w in zip(self._trainable, new_tr):
                 p._data[0]._rebind(w)
             for p, a in zip(self._aux, aux_new):
@@ -671,28 +688,37 @@ class ShardedTrainer(GuardedTrainerMixin):
                 self._after_step(t, loss_val, finite, gnorm)
         return nd.NDArray(loss_val, _skip_device_put=True)
 
-    def _lower(self, fn, scalars, batch):
-        """``fn`` (the step or a multi-step program) lowered with the
-        trainer's arrays as they are now, the given scalars and batch."""
+    def _loss_scale(self):
+        return self._scaler.loss_scale if self._scaler is not None else 1.0
+
+    def _lower(self, fn, num_steps, batch):
+        """``fn`` (the step, or the program of ``num_steps`` steps) lowered
+        with the trainer's arrays as they are now, ``batch`` and arguments
+        of the shapes a call passes: a key by its shape only (no draw) and
+        ``_scalar_args`` of ones."""
         from .mesh import use_mesh
+        key = jax.eval_shape(lambda: jax.random.key(  # graftlint: disable=G2 shape only
+            0, impl=_rng._default_impl()))
+        lr = np.ones(num_steps) if num_steps else 1.0
         with use_mesh(self.mesh):
             return fn.lower(
                 [p._data[0]._data for p in self._trainable],
                 [p._data[0]._data for p in self._aux],
-                self._states, self._guard_state, *scalars, *batch)
+                self._states, self._guard_state, key,
+                _scalar_args(lr, 1.0, 1.0, 1.0), *batch)
 
     def step_program_text(self, *batch) -> str:
         """Optimized HLO of the compiled :meth:`step` for this batch — where
         a caller reads which collectives (``all-reduce``) and custom kernels
         (``tpu_custom_call``) the compiler put into the program. Lowers and
         compiles the step again (a persistent compile cache makes that a
-        reload) with the arguments :meth:`step` passes; takes no step."""
+        reload) with arguments of the shapes :meth:`step` passes; takes no
+        step and draws no key."""
         self._prepare(batch[:-1])
         if self._step_fn is None:
             self._step_fn = self._build_step(len(batch) - 1)
-        one = jnp.float32(1.0)
         return self._lower(
-            self._step_fn, (_rng.next_key(), one, one, one, one),
+            self._step_fn, 0,
             [self._shard_batch_arg(b) for b in batch]).compile().as_text()
 
     def program_texts(self) -> dict:
@@ -703,20 +729,16 @@ class ShardedTrainer(GuardedTrainerMixin):
         the ``jax.named_scope`` names it was traced under
         (``observability.device_scopes``). Takes no step, draws no key and
         costs nothing until it is called."""
-        key = jax.eval_shape(lambda: jax.random.key(  # graftlint: disable=G2 shape only
-            0, impl=_rng._default_impl()))
-        f32 = jax.ShapeDtypeStruct((), jnp.float32)
         texts = {}
         for num_steps, batch in self._program_batches.items():
             if num_steps:
                 name = f"run_steps({num_steps})"
                 fn = getattr(self, "_multi_fns", {}).get(f"multi{num_steps}")
-                lr = jax.ShapeDtypeStruct((num_steps,), jnp.float32)
             else:
-                name, fn, lr = "step", self._step_fn, f32
+                name, fn = "step", self._step_fn
             if fn is not None:      # dropped by an AMP change or a new mesh
                 texts[name] = self._lower(
-                    fn, (key, lr, f32, f32, f32), batch).compile().as_text()
+                    fn, num_steps, batch).compile().as_text()
         return texts
 
     # -- guard bookkeeping: GuardedTrainerMixin (docs/guardrails.md) ----------
@@ -745,7 +767,13 @@ class ShardedTrainer(GuardedTrainerMixin):
         — the TPU analog of the reference's engine keeping a deep async
         queue ahead of the Python loop (SURVEY §3.2: "the loop
         synchronizes only at metric.update"). The batch is reused each
-        inner step; returns the last step's loss."""
+        inner step; returns the last step's loss.
+
+        Like :meth:`step`, the call starts one device program: the scalars
+        and the per-step lr sequence are one host float32 array, the
+        program splits ``_rng``'s root once (inner step ``i`` folds ``i``
+        into the subkey) and returns the new root's bits and the last loss
+        itself."""
         args = batch[:-1]
         self._prepare(args)
         self._maybe_invalidate_amp()
@@ -760,8 +788,10 @@ class ShardedTrainer(GuardedTrainerMixin):
             in_sh, out_sh, donate = self._shardings
             rep_sh = out_sh[4]
 
-            def multi(tr, aux, states, gstate, rng, lrs, t, rescale,
-                      lscale, *b):
+            def multi(tr, aux, states, gstate, root, scalars, *b):
+                root, rng = jax.random.split(root)  # as the step's program
+                (t, rescale, lscale), lrs = scalars[:3], scalars[3:]
+
                 # lrs: (num_steps,) host-evaluated schedule — each inner
                 # step sees the SAME lr a separate step() call would
                 def body(carry, i):
@@ -775,11 +805,12 @@ class ShardedTrainer(GuardedTrainerMixin):
                 (tr, aux, states, gstate, _), (losses, fins, gns) = \
                     jax.lax.scan(body, (tr, aux, states, gstate, t),
                                  jnp.arange(num_steps))
-                return tr, aux, states, gstate, losses, fins, gns
+                return (tr, aux, states, gstate, losses, fins, gns,
+                        losses[-1], jax.random.key_data(root))
 
             self._multi_fns[key] = jax.jit(
                 multi, in_shardings=in_sh,
-                out_shardings=out_sh[:4] + (rep_sh, rep_sh, rep_sh),
+                out_shardings=out_sh[:4] + (rep_sh,) * 5,
                 donate_argnums=donate)
         t = self._num_update + 1
         self._num_update += num_steps
@@ -791,32 +822,32 @@ class ShardedTrainer(GuardedTrainerMixin):
                 self._program_batches[num_steps] = _as_shapes(batch_datas)
             with _obs.step_phase("sharded_trainer", "host_args"):
                 self._optimizer.num_update = self._num_update
-                lrs = _lr_sequence(self._optimizer, t, num_steps)
-                # fp16 note (docs/guardrails.md): the loss scale is one
-                # traced input for the WHOLE window — overflow inside a
-                # scanned window skips those steps in-program, and the
-                # scaler adjusts once per window from the per-step flags
-                # below
-                lscale = (self._scaler.loss_scale
-                          if self._scaler is not None else 1.0)
                 tr = [p._data[0]._data for p in self._trainable]
                 aux = [p._data[0]._data for p in self._aux]
                 cshapes = ([list(map(int, np.shape(b))) for b in batch]
                            if compiling else None)
-                scalars = (_rng.next_key(), lrs, jnp.float32(t),
-                           jnp.float32(self._optimizer.rescale_grad),
-                           jnp.float32(lscale))
+                # each inner step sees the SAME lr a separate step() call
+                # would (a frozen first-step lr silently changes
+                # warmup/decay math). fp16 note (docs/guardrails.md): the
+                # loss scale is one traced input for the WHOLE window —
+                # overflow inside a scanned window skips those steps
+                # in-program, and the scaler adjusts once per window from
+                # the per-step flags below
+                scalars = _scalar_args(
+                    [_lr_at(self._optimizer, t + i)
+                     for i in range(num_steps)], t,
+                    self._optimizer.rescale_grad, self._loss_scale())
             from .mesh import use_mesh
             with _obs.step_phase("sharded_trainer", "compiled_step"), \
                     _obs.maybe_compile_span(compiling,
                                             "sharded_trainer.run_steps",
                                             num_steps=num_steps,
                                             shapes=cshapes), \
-                    use_mesh(self.mesh):
-                (new_tr, aux_new, new_states, gstate, losses, fins,
-                 gns) = self._multi_fns[key](
-                    tr, aux, self._states, self._guard_state, *scalars,
-                    *batch_datas)
+                    use_mesh(self.mesh), _rng.split_in_program() as draw:
+                (new_tr, aux_new, new_states, gstate, losses, fins, gns,
+                 last_loss, draw.new_root_data) = self._multi_fns[key](
+                    tr, aux, self._states, self._guard_state, draw.root,
+                    scalars, *batch_datas)
             for p, w in zip(self._trainable, new_tr):
                 p._data[0]._rebind(w)
             for p, a in zip(self._aux, aux_new):
@@ -825,7 +856,7 @@ class ShardedTrainer(GuardedTrainerMixin):
             self._guard_state = gstate
             with _obs.step_phase("sharded_trainer", "guard_fetch"):
                 self._after_run_steps(t, losses, fins, gns)
-        return nd.NDArray(losses[-1], _skip_device_put=True)
+        return nd.NDArray(last_loss, _skip_device_put=True)
 
     def evaluate(self, *batch):
         """Forward + loss under one compiled program (no update)."""

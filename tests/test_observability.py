@@ -622,19 +622,18 @@ def test_profiler_session_holds_one_span_tree_per_trainer_call(
     for _, start, end, _ in phases:         # in that order, inside the call
         assert reach <= start <= end <= outer[2]
         reach = end
-    started = {}
-    for name, start, end, _ in events:
-        if name.startswith("PjitFunction(") and outer[1] <= start \
-                and end <= outer[2]:
-            # every program the call starts is started inside one phase:
-            # none in the trainer's self time
-            (phase,) = [p[0][len(prefix):] for p in phases
-                        if p[1] <= start and end <= p[2]]
-            started.setdefault(phase, set()).add(name)
-    # the step's own in compiled_step and alone there, the small ones before
-    assert started.pop("compiled_step") == {f"PjitFunction({program})"}
-    assert set(started) == {"host_args"}
-    assert "PjitFunction(convert_element_type)" in started["host_args"]
+    # the call starts one device program, the compiled step, in
+    # compiled_step: the scalars are host values and the key is split inside
+    # the program, so host_args starts none; and the loss read that follows
+    # the call starts none either (run_steps returns its last loss from the
+    # program, no eager ``losses[-1]``). A set: the profiler holds one call
+    # as two nested events
+    programs = {(name, next((p[0][len(prefix):] for p in phases
+                             if p[1] <= start and end <= p[2]), None))
+                for name, start, end, _ in events
+                if name.startswith("PjitFunction(")
+                and outer[1] <= start <= outer[2] + 5_000_000}
+    assert programs == {(f"PjitFunction({program})", "compiled_step")}
 
 
 def test_no_profiler_session_no_ring_span_no_device_read():
@@ -677,6 +676,114 @@ def test_fixed_seed_losses_are_those_of_the_parent_commit():
                    "0x1.600cc00000000p+0", "0x1.56dac80000000p+0"]
     tr2, _, _ = _dropout_sharded(4321)      # the key does reach the loss
     assert float(tr2.step(x, y).asscalar()).hex() != got[0]
+
+
+# -- the key is split inside the program; the scalars are host values ---------
+
+def _trainer_call(tr, call, x, y):
+    return tr.step(x, y) if call == "step" else tr.run_steps(x, y,
+                                                             num_steps=2)
+
+
+@pytest.mark.parametrize("call", ["step", "run_steps"])
+def test_program_split_leaves_the_eager_stream_where_next_key_would(call):
+    """On a mesh of 8 devices the program returns the new root replicated
+    and committed; ``_rng`` keeps a one-device uncommitted view of it, so
+    the state is the parent's after the same number of splits and eager
+    samplers still run, beside arrays on any device."""
+    import jax
+    from mxnet_tpu import _rng, autograd
+    tr, x, y = _dropout_sharded(99)
+    assert tr.mesh.devices.size == 8
+    tr.prepare(x)
+    for _ in range(2):                      # the compiling call and a warm one
+        data, impl = _rng.get_state()
+        root, _ = jax.random.split(jax.random.wrap_key_data(data, impl=impl))
+        _trainer_call(tr, call, x, y)
+        got, got_impl = _rng.get_state()
+        assert got_impl == impl
+        np.testing.assert_array_equal(got, jax.random.key_data(root))
+    key = _rng._ensure_key_locked()
+    assert len(key.devices()) == 1 and not key.committed
+    root, sub = jax.random.split(root)      # the draw an eager sampler makes
+    np.testing.assert_array_equal(
+        mx.nd.random.uniform(shape=(2,)).asnumpy(),
+        jax.random.uniform(sub, (2,)))
+    for ctx in (mx.cpu(0), mx.cpu(3)):
+        ones = mx.nd.ones((64,), ctx=ctx)
+        with autograd.train_mode():
+            dropped = mx.nd.Dropout(ones, p=0.5).asnumpy()
+        assert set(np.unique(dropped)) == {0.0, 2.0}
+        root, _ = jax.random.split(root)
+    np.testing.assert_array_equal(_rng.get_state()[0],
+                                  jax.random.key_data(root))
+
+
+@pytest.mark.parametrize("call", ["step", "run_steps"])
+def test_checkpoint_resumes_the_dropout_stream_bit_for_bit(tmp_path, call):
+    tr, x, y = _dropout_sharded(5)
+    _trainer_call(tr, call, x, y)
+    tr.save_checkpoint(str(tmp_path / "ck"))
+
+    def three():
+        return [float(_trainer_call(tr, call, x, y).asscalar()).hex()
+                for _ in range(3)]
+
+    first = three()
+    mx.random.seed(77)                      # not the ambient seed's doing
+    tr.load_checkpoint(str(tmp_path / "ck"))
+    assert three() == first
+    assert len(set(first)) == 3             # a new mask each call
+
+
+def _sgd_weights(tr):
+    return [p._data[0].asnumpy() for p in tr._trainable]
+
+
+@pytest.mark.parametrize("what", ["learning_rate", "scheduler", "loss_scale"])
+def test_new_scalar_values_reach_the_update_and_compile_nothing(what):
+    """lr, the scheduler's value and the fp16 loss scale are traced float32
+    inputs built on the host: a new value is a new argument, never a new
+    program, for ``step()`` and ``run_steps()`` alike."""
+    net = _mlp()
+    lr_of = {"lr": 0.1}
+    tr = parallel.ShardedTrainer(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+        optimizer_params=dict(
+            {"learning_rate": 0.1},
+            **({"lr_scheduler": lambda t: lr_of["lr"]}
+               if what == "scheduler" else {})),
+        mesh=parallel.make_mesh({"data": -1}),
+        compute_dtype="float16" if what == "loss_scale" else None)
+    rng = np.random.RandomState(0)
+    x, y = rng.randn(16, 8).astype(np.float32), rng.randint(0, 4, (16,))
+    tr.step(x, y)
+    tr.run_steps(x, y, num_steps=2)         # both programs compiled
+    programs = (tr._step_fn, tr._multi_fns["multi2"])
+    assert [f._cache_size() for f in programs] == [1, 1]
+    before = _sgd_weights(tr)
+
+    def set_lr(value):
+        if what == "scheduler":
+            lr_of["lr"] = value
+        else:
+            tr.set_learning_rate(value)
+
+    if what == "loss_scale":                # fp16 gradients overflow: skipped
+        tr._scaler.loss_scale = 2.0 ** 40
+    else:                                   # an update of lr 0 moves nothing
+        set_lr(0.0)
+    tr.step(x, y)
+    tr.run_steps(x, y, num_steps=2)
+    for a, b in zip(before, _sgd_weights(tr)):
+        np.testing.assert_array_equal(a, b)
+    if what == "loss_scale":
+        assert tr.skipped_steps == 3 and tr._scaler.loss_scale < 2.0 ** 40
+    else:                                   # and back: the update moves again
+        set_lr(0.1)
+        tr.step(x, y)
+        assert any((a != b).any() for a, b in zip(before, _sgd_weights(tr)))
+    assert [f._cache_size() for f in programs] == [1, 1]
 
 
 # -- reports + doctor surfaces ------------------------------------------------
