@@ -2,8 +2,9 @@
 
 Parameters, all from the traffic file: ``mode`` (``fused`` | ``loop``),
 ``k`` (optimizer steps per dispatch, ``fused``), ``batch_per_chip``,
-``mesh`` (``{"data": "all"}``), ``sync_every`` and ``ring`` (``loop``),
-``trace_dispatches`` / ``trace_steps`` (length of the traced window).
+``mesh`` (``{"data": "all"}``), ``sync_every``, ``ahead`` and ``ring``
+(``loop``), ``trace_dispatches`` / ``trace_steps`` (length of the traced
+window).
 
 Set-up builds the trainer as the configuration says, resolves its shapes
 (``trainer.prepare``) and warms exactly the program the window uses:
@@ -16,13 +17,19 @@ each ended by reading its loss. Then
   whole dispatches from the first dispatch to the last completion;
 - ``loop`` (host in the loop; what ``Module.fit`` and the Gluon tutorials
   do): every step takes the next of ``ring`` host batches, calls
-  ``trainer.step`` and reads the loss every ``sync_every`` steps; a step is
-  timed from the call to the loss on the host.
+  ``trainer.step`` and reads every ``sync_every``-th loss, ``ahead`` of
+  them late (MXNet's engine runs ahead of the Python loop in the same way);
+  a turn of the loop is timed from the call to the return of its read. When
+  the time is up nothing more is sent, every loss still due is read, and
+  the rate counts all steps over all of that time.
 
 ``correct``: every loss read is finite, the guard skipped no step, nothing
 compiled inside the window, and on every batch the loss read last is below
 the loss read first. A traced run also holds the system's compiled forward
-pass to the configuration's plain float32 reference (``FORWARD_TOLERANCE``).
+pass to the configuration's plain float32 reference (``FORWARD_TOLERANCE``):
+every logit to the reference's (``forward_check``), or, where the
+configuration file names a ``compare`` beside its ``reference``, through
+that comparison and under the same tolerance (``configured_check``).
 """
 from __future__ import annotations
 
@@ -140,14 +147,21 @@ def fused_window(trainer, x, y, k, spans, reads, seconds=None,
     return sent, time.perf_counter() - t_first
 
 
-def loop_window(trainer, ring, start, sync_every, spans, reads, step_ms,
-                seconds=None, steps=None):
-    """``step()`` on the next host batch of ``ring``, the loss read every
-    ``sync_every`` steps. ``start`` is the index of the first batch taken.
-    Returns ``(steps taken, seconds from the first call to the last loss
-    read)``; each step's time, call to return, goes to ``step_ms``."""
+def loop_window(trainer, ring, start, sync_every, ahead, spans, reads,
+                step_ms, seconds=None, steps=None):
+    """``step()`` on the next host batch of ``ring``, every ``sync_every``-th
+    loss read, ``ahead`` such losses late: the host waits for a loss only
+    once ``ahead`` later ones are dispatched, so the device has that many
+    steps queued while the host stands still (``ahead`` 0: each loss is read
+    before the next step is sent). ``start`` is the index of the first batch
+    taken. When the time is up nothing more is sent, every loss still due is
+    read, and the clock is read after that. Returns ``(steps taken, seconds
+    from the first call to the last loss read)``; the time of each turn of
+    the loop, from the call of ``step()`` to the return of the read it
+    makes, goes to ``step_ms``."""
     taken = 0
     loss = None
+    due = collections.deque()
     t_first = time.perf_counter()
     while True:
         with spans("next_batch"):
@@ -158,9 +172,12 @@ def loop_window(trainer, ring, start, sync_every, spans, reads, step_ms,
             loss = trainer.step(x, y)
         taken += 1
         if taken % sync_every == 0:
-            with spans("wait_loss"):
-                reads.append((at, loss.asscalar()))
+            due.append((at, loss))
             loss = None
+        if len(due) > ahead:
+            with spans("wait_loss"):
+                read_at, read = due.popleft()
+                reads.append((read_at, read.asscalar()))
         now = time.perf_counter()
         step_ms.append((now - t0) * 1e3)
         if steps is not None:
@@ -169,8 +186,11 @@ def loop_window(trainer, ring, start, sync_every, spans, reads, step_ms,
         elif now - t_first >= seconds:
             break
     if loss is not None:
+        due.append((at, loss))
+    while due:
         with spans("wait_loss"):
-            reads.append((at, loss.asscalar()))
+            read_at, read = due.popleft()
+            reads.append((read_at, read.asscalar()))
     return taken, time.perf_counter() - t_first
 
 
@@ -187,17 +207,22 @@ def losses_fell(reads) -> bool:
 
 
 def plain_reference(config, net, x):
-    """Logits of the configuration's plain float32 reference on the first
+    """What the configuration's plain float32 reference gives on the first
     ``reference_samples`` of ``x``, with the net's parameters as drawn (the
-    caller has resolved their shapes)."""
+    caller has resolved their shapes): its logits, or, for a configuration
+    that brings its own ``compare``, whatever that needs kept from before
+    the cast (the float32 parameters)."""
     return manifest.resolve(config["reference"])(
         net, x[:config["reference_samples"]])
 
 
-def system_logits(trainer, args, x, y, n):
-    """The first ``n`` rows of the system's own compiled forward pass over
-    the whole batch (``trainer.evaluate``: its mesh, dtype and kernel tier;
-    in predict mode samples are independent, so a slice compares exactly).
+def system_outputs(trainer, args, x, y, rows=None):
+    """Every output of the system's own compiled forward pass over the whole
+    batch (``trainer.evaluate``: its mesh, dtype and kernel tier), as host
+    arrays in their own dtypes, cut to the first ``rows`` rows where given
+    (in predict mode samples are independent, so a slice compares exactly).
+    One call is one run of one compiled program: a ``compare`` that reads
+    logits and routes calls it once, and both come from that run.
     Two things ``evaluate()`` leaves undone that ``step()`` does, both
     listed in PERF.md for the program to repair: it does not cast its inputs
     (float32 images fail against bfloat16 master weights), so it gets the
@@ -211,19 +236,89 @@ def system_logits(trainer, args, x, y, n):
         x = x.astype(jnp.dtype(args["compute_dtype"]))
     with parallel.use_mesh(trainer.mesh):
         trainer.evaluate(x, y)
-    return trainer.last_outputs[0][:n].asnumpy().astype(np.float32)
+    return [(out if rows is None else out[:rows]).asnumpy()
+            for out in trainer.last_outputs]
+
+
+def system_logits(trainer, args, x, y, n):
+    """The first ``n`` rows of the system's logits, its first output, in
+    float32."""
+    return system_outputs(trainer, args, x, y, rows=n)[0].astype(np.float32)
+
+
+def bounded(samples, error, scale, sound) -> dict:
+    """The runner's bound on a forward comparison, the same for every
+    configuration: the largest error over the compared logits is within
+    ``FORWARD_TOLERANCE`` of the largest reference logit, and the comparison
+    is ``sound`` (finite, and whatever else its caller holds it to)."""
+    return {"samples": samples, "max_abs_error": error,
+            "max_abs_reference": scale,
+            "share": error / scale if scale else None,
+            "tolerance": FORWARD_TOLERANCE,
+            "ok": bool(sound and error <= FORWARD_TOLERANCE * scale)}
 
 
 def forward_check(system, reference) -> dict:
     """The system's logits against the plain float32 reference's."""
-    scale = float(np.max(np.abs(reference)))
-    error = float(np.max(np.abs(system - reference)))
-    return {"samples": len(reference), "max_abs_error": error,
-            "max_abs_reference": scale,
-            "share": error / scale if scale else None,
-            "tolerance": FORWARD_TOLERANCE,
-            "ok": bool(np.isfinite(system).all()
-                       and error <= FORWARD_TOLERANCE * scale)}
+    return bounded(len(reference), float(np.max(np.abs(system - reference))),
+                   float(np.max(np.abs(reference))),
+                   np.isfinite(system).all())
+
+
+COMPARE_KEYS = ("samples", "compared", "max_abs_error", "max_abs_reference",
+                "conditions")
+CONDITION_KEYS = ("value", "limit", "ok", "why")
+
+
+def configured_check(config, kept, trainer, args, x, y) -> dict:
+    """The forward check of a configuration that brings its own comparison.
+
+    Where the forward pass is not continuous in its inputs (a top-k router:
+    two scores closer than bfloat16 rounding send a token to another expert
+    in the system than in the float32 reference, and both are right), every
+    logit cannot be held to the plain reference. Such a configuration names
+    ``compare(kept, trainer, args, x, y)``: it runs the system's forward
+    once (``system_outputs``), evaluates its reference **at the system's own
+    choices**, and says how far the logits of the first ``reference_samples``
+    samples lie apart (``max_abs_error``, ``max_abs_reference``), how many
+    it compared (``compared``), and what it holds the choices to
+    (``conditions``: name -> ``value``, ``limit``, ``ok``, ``why``).
+
+    The bound stays here. ``share``, the tolerance and ``ok`` are worked out
+    by ``bounded`` from those numbers, and anything else in the dict is
+    ignored: a configuration can add conditions, it cannot widen the 3%,
+    compare fewer logits or leave samples out. A dict that lacks a key, or
+    counts other than all the logits of ``reference_samples`` samples, is an
+    error of the run, not a verdict."""
+    said = manifest.resolve(config["compare"])(kept, trainer, args, x, y)
+    missing = [key for key in COMPARE_KEYS if key not in said]
+    if missing:
+        raise ValueError(f"{config['compare']} left out {missing}")
+    samples = config["reference_samples"]
+    # the compare's one evaluate() left the system's logits with the trainer
+    per_sample = int(np.prod(trainer.last_outputs[0].shape[1:]))
+    if (said["samples"], said["compared"]) != (samples, samples * per_sample):
+        raise ValueError(
+            f"{config['compare']} compared {said['compared']} logits of "
+            f"{said['samples']} samples: all {samples * per_sample} of the "
+            f"first {samples} are due")
+    conditions = {}
+    for name, condition in said["conditions"].items():
+        missing = [key for key in CONDITION_KEYS if key not in condition]
+        if missing:
+            raise ValueError(f"{config['compare']}: condition {name!r} "
+                             f"left out {missing}")
+        conditions[name] = {"value": float(condition["value"]),
+                            "limit": float(condition["limit"]),
+                            "ok": bool(condition["ok"]),
+                            "why": str(condition["why"])}
+    error = float(said["max_abs_error"])
+    scale = float(said["max_abs_reference"])
+    check = bounded(samples, error, scale,
+                    math.isfinite(error) and math.isfinite(scale)
+                    and all(c["ok"] for c in conditions.values()))
+    check.update(compared=int(said["compared"]), conditions=conditions)
+    return check
 
 
 def run(config, traffic, devices, seed, seconds, trace_dir=None) -> dict:
@@ -251,7 +346,8 @@ def run(config, traffic, devices, seed, seconds, trace_dir=None) -> dict:
     x0, y0 = ring[0]
 
     # A traced run first holds the system's compiled forward pass to the
-    # plain reference. The reference comes before prepare(), which casts the
+    # plain reference. The reference (or what a configuration's own compare
+    # needs of it) comes before prepare(), which casts the
     # parameters to the master dtype and moves them onto the mesh. The
     # check's programs are not the cell's set-up: they are taken out of the
     # compile counters.
@@ -275,8 +371,13 @@ def run(config, traffic, devices, seed, seconds, trace_dir=None) -> dict:
         - fallback_count(before_prepare)
     if trace_dir:
         mark = log.snapshot()
-        checks["forward"] = forward_check(
-            system_logits(trainer, args, x0, y0, len(reference)), reference)
+        if "compare" in config:
+            checks["forward"] = configured_check(config, reference, trainer,
+                                                 args, x0, y0)
+        else:
+            checks["forward"] = forward_check(
+                system_logits(trainer, args, x0, y0, len(reference)),
+                reference)
         set_aside(mark)
     before_warm = pallas.tier_provenance()
 
@@ -308,8 +409,9 @@ def run(config, traffic, devices, seed, seconds, trace_dir=None) -> dict:
     def window(**length):
         if mode == "fused":
             return fused_window(trainer, x, y, k, spans, reads, **length)
-        return loop_window(trainer, ring, 3, sync_every, spans, reads,
-                           step_ms, **length)
+        return loop_window(trainer, ring, 3, sync_every,
+                           traffic.get("ahead", 0), spans, reads, step_ms,
+                           **length)
 
     if trace_dir:
         options = jax.profiler.ProfileOptions()

@@ -75,6 +75,8 @@ def test_cell_files_exist_and_import(cell):
     assert callable(runner.run)
     for key in ("build", "make_batch", "flops_per_sample", "reference"):
         assert callable(manifest.resolve(config[key])), key
+    if "compare" in config:         # optional: chipbench/README.md
+        assert callable(manifest.resolve(config["compare"]))
     assert config["flops_per_sample"].startswith("chipbench.models.")
     reports = {n for n, m in END_TO_END.items() if cell["name"] in cells_of(m)}
     assert "setup_s" in reports and len(reports) >= 2

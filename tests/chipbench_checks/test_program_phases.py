@@ -190,7 +190,7 @@ def test_the_metric_files_read_a_cpu_trace_of_the_toy(
     assert phases[outer]["mean"] < facts["spans"]["dispatch"]["mean"]
     started = r["programs_started_per_call"]
     assert started["compiled_step"] == {program: 1.0}
-    assert started["host_args"]["convert_element_type"] >= 3
+    assert "host_args" not in started     # host work only (since PR 30)
     assert "self" not in started and "guard_fetch" not in started
     steps = facts["values"]["steps_traced"]
     assert (got["idle_in_trainer_ms_per_step"]

@@ -44,7 +44,8 @@ def fake_device_plane(monkeypatch):
     monkeypatch.setattr(reduce_trace, "read_planes", read)
 
 
-@pytest.mark.parametrize("mix", ["tiny_fused", "tiny_loop"])
+@pytest.mark.parametrize("mix", ["tiny_fused", "tiny_loop",
+                                 "tiny_loop_ahead"])
 def test_timed_run_hands_back_the_facts(config, mix):
     traffic = load(mix)
     facts = train.run(config, traffic, jax.devices()[:1], 2 ** 31 + 11, 0.3)
@@ -62,7 +63,8 @@ def test_timed_run_hands_back_the_facts(config, mix):
     assert facts["trace"] is None
 
 
-@pytest.mark.parametrize("mix", ["tiny_fused", "tiny_loop"])
+@pytest.mark.parametrize("mix", ["tiny_fused", "tiny_loop",
+                                 "tiny_loop_ahead"])
 def test_traced_run_gives_the_contract_line(config, mix, tmp_path,
                                             monkeypatch):
     fake_device_plane(monkeypatch)
