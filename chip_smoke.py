@@ -347,11 +347,18 @@ def real_width_cases(chip):
     b = (jax.random.normal(k[1], (1, c)) * 0.1).astype(jnp.bfloat16)
     bits = dropout_bits(k[2], (r, c), layer=1, tick=2)
     q = jax.random.normal(k[3], (1, 12, s, 64), jnp.float32)
+    # the routed-expert layer's first product: 16 experts of 2688 -> 1856,
+    # a buffer of 12288 rows of which the groups own half
+    rows, u, f, groups = (12288, 2688, 1856, 16) if chip else (64, 128, 256, 4)
+    lhs = jax.random.normal(k[0], (rows, u), jnp.bfloat16)
+    rhs = (jax.random.normal(k[1], (groups, u, f)) * 0.02).astype(jnp.bfloat16)
+    sizes = jnp.full((groups,), rows // (2 * groups), jnp.int32)
     return {
         "matmul_epilogue": ((y, b, bits), {"act_type": "gelu", "p": 0.1}),
         "blockwise_attention": ((q, q * 0.5, q + 1.0),
                                 {"block_size": 512 if chip else 32,
                                  "causal": True}),
+        "grouped_matmul": ((lhs, rhs, sizes), {}),
     }
 
 

@@ -81,3 +81,11 @@ def getenv(name: str, default=None, typ=str):
     if typ is bool:
         return val not in ("0", "false", "False", "")
     return typ(val)
+
+
+# A value named so with ``jax.ad_checkpoint.checkpoint_name`` is kept for the
+# backward pass by ``HybridBlock.recompute()`` and not computed again there.
+# A router's choice is the case: the compiler may round a recomputed layer
+# elsewhere than it rounded the forward's, and a score at the cut then sends
+# a token to another expert in the backward pass than in the forward pass.
+RECOMPUTE_KEEP = "mxnet_tpu.keep"

@@ -30,7 +30,7 @@ import numpy as np
 
 from .. import _rng, autograd
 from .. import ndarray as nd
-from ..base import MXNetError, _as_np_dtype
+from ..base import MXNetError, RECOMPUTE_KEEP, _as_np_dtype
 from ..context import Context, current_context
 from .parameter import (DeferredInitializationError, Parameter, ParameterDict)
 
@@ -387,7 +387,11 @@ class HybridBlock(Block):
             treedefs.append(treedef)
             return tuple(outs), tuple(aux_new)
 
-        outs, aux_new = jax.checkpoint(forward)(
+        # nothing is kept but what a block names RECOMPUTE_KEEP (a router's
+        # choice: ops/moe.py); without such a name this is the default policy
+        outs, aux_new = jax.checkpoint(
+            forward, policy=jax.checkpoint_policies.save_only_these_names(
+                RECOMPUTE_KEEP))(
             _rng.next_key(), [p._data[0]._data for p in trainable],
             [p._data[0]._data for p in aux], *[a._data for a in args])
         for param, new in zip(aux, aux_new):

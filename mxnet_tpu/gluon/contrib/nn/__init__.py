@@ -1,14 +1,16 @@
 """gluon.contrib.nn (ref: python/mxnet/gluon/contrib/nn/basic_layers.py)."""
 from __future__ import annotations
 
+import weakref
+
 import numpy as np
 
 from ....base import MXNetError
 from ...block import HybridBlock
 from ...nn import HybridSequential, Sequential, SyncBatchNorm
 
-__all__ = ["Concurrent", "HybridConcurrent", "Identity", "MoEFFN",
-           "SyncBatchNorm"]
+__all__ = ["Concurrent", "FeedForward", "HybridConcurrent", "Identity",
+           "MoEFFN", "RoutedExperts", "SyncBatchNorm"]
 
 
 class HybridConcurrent(HybridSequential):
@@ -68,10 +70,15 @@ class MoEFFN(HybridBlock):
     Under a mesh whose ``expert`` axis matches ``num_experts`` the forward
     dispatches tokens with two ``all_to_all``s and runs ONLY the local
     expert per device at ``capacity_factor`` buffer size
-    (parallel.moe_apply_topk — per-device compute O(k·tokens/E)); on any
+    (parallel.moe_apply_topk — per-device compute O(k·tokens/E); every
+    (token, expert) pair past an expert's capacity is DROPPED, its token
+    gets nothing from that expert); on any
     other mesh (or eagerly on one device) it falls back to the dense
     formulation: every expert over every token, gate-weighted — same
     math except no capacity dropping, so tiny-scale runs are exact.
+
+    For experts that outnumber the chips (each chip holds several, routes
+    over all of them and drops nothing) use :class:`RoutedExperts`.
 
     Inside a ShardedTrainer step the Switch load-balancing loss is added
     to the training objective automatically (``aux_loss_weight`` times
@@ -204,3 +211,171 @@ class MoEFFN(HybridBlock):
             self._last_aux_loss = aux
         y = y.astype(xd.dtype).reshape(shape[:-1] + (self._units,))
         return nd_mod.NDArray(y, _skip_device_put=True)
+
+
+class FeedForward(HybridBlock):
+    """``W2 relu(W1 x)^2`` without biases: the form of
+    :class:`RoutedExperts`' experts, and its shared expert."""
+
+    def __init__(self, units, hidden_size, **kwargs):
+        super().__init__(**kwargs)
+        from ...nn import Dense
+        with self.name_scope():
+            self.w_in = Dense(hidden_size, flatten=False, use_bias=False,
+                              in_units=units, prefix="in_")
+            self.w_out = Dense(units, flatten=False, use_bias=False,
+                               in_units=hidden_size, prefix="out_")
+
+    def hybrid_forward(self, F, x):
+        return self.w_out(F.square(F.relu(self.w_in(x))))
+
+
+class RoutedExperts(HybridBlock):
+    """A routed-expert feed-forward layer that holds a share of the experts:
+    one chip's layer under expert parallelism, where the experts outnumber
+    the chips (net-new TPU capability; ops in ``ops/moe.py``).
+
+    The router scores every token over **all** ``num_experts`` in float32
+    (sigmoid of ``x W_r^T``), takes the ``k`` largest of ``score +
+    bias`` (``router_bias``: a buffer no gradient reaches, the
+    correction of auxiliary-loss-free balancing; it chooses and does not
+    weigh), and weighs the chosen by their scores (divided by their sum if
+    ``norm_topk_prob``, times ``scaling_factor``). The layer holds the
+    ``experts_held`` experts from ``first_expert`` on (all of them by
+    default) and computes, for the pairs whose expert it holds, ``W2_e
+    relu(W1_e x)^2`` as two grouped products over rows ordered by expert;
+    pairs whose expert lives on another chip add nothing here, and the
+    partial result is what the block returns (the exchange that would add
+    the other chips' parts is not this block's). No pair is dropped. A
+    shared expert of width ``shared_hidden_size`` (0: none), the same form,
+    runs on every token and is added.
+
+    ``x``: (B, S, units) -> (B, S, units); with ``return_routes`` the
+    block returns ``(y, routes, rows, scores)``: the chosen experts (B, S,
+    k), int32, ids among all ``num_experts``; the rows of each held expert
+    that it computed (experts_held,), int32; and the router's float32
+    scores (B, S, num_experts), against which a comparison can hold the
+    choice.
+
+    In training mode the block adds the rows each held expert received to
+    ``expert_rows`` and one to ``steps`` (int32 auxiliary state, carried
+    through a compiled step as BatchNorm's running statistics are: no host
+    callback); ``mxnet_tpu.observability`` exports them as
+    ``mxnet_tpu_moe_expert_rows{layer,expert}`` and
+    ``mxnet_tpu_moe_steps{layer}`` whenever a snapshot is taken.
+    """
+
+    def __init__(self, units, hidden_size, num_experts, k=2, first_expert=0,
+                 experts_held=None, norm_topk_prob=True, scaling_factor=1.0,
+                 shared_hidden_size=0, return_routes=False, **kwargs):
+        super().__init__(**kwargs)
+        held = num_experts - first_expert if experts_held is None \
+            else experts_held
+        if not (0 <= first_expert and 0 < held
+                and first_expert + held <= num_experts):
+            raise MXNetError(
+                f"RoutedExperts: experts {first_expert}..{first_expert + held - 1} "
+                f"are not among {num_experts}")
+        if not 0 < k <= num_experts:
+            raise MXNetError(f"RoutedExperts: k {k} of {num_experts} experts")
+        self._experts, self._k = int(num_experts), int(k)
+        self._first, self._held = int(first_expert), int(held)
+        self._route = dict(top_k=self._k,
+                           norm_topk_prob=bool(norm_topk_prob),
+                           scaling_factor=float(scaling_factor))
+        self._return_routes = bool(return_routes)
+        with self.name_scope():
+            self.router_weight = self.params.get(
+                "router_weight", shape=(num_experts, units))
+            self.router_bias = self.params.get(
+                "router_bias", shape=(num_experts,), init="zeros",
+                differentiable=False)
+            self.expert_w1 = self.params.get(
+                "expert_w1", shape=(held, units, hidden_size))
+            self.expert_w2 = self.params.get(
+                "expert_w2", shape=(held, hidden_size, units))
+            self.expert_rows = self.params.get(
+                "expert_rows", shape=(held,), init="zeros", dtype="int32",
+                differentiable=False)
+            self.steps = self.params.get(
+                "steps", shape=(1,), init="zeros", dtype="int32",
+                differentiable=False)
+            self.shared = FeedForward(
+                units, shared_hidden_size,
+                prefix="shared_") if shared_hidden_size else None
+        _live_routed.add(self)
+
+    @property
+    def experts_held(self):
+        """``(first, count)`` of the experts this block computes."""
+        return self._first, self._held
+
+    def hybrid_forward(self, F, x, router_weight, router_bias, expert_w1,
+                       expert_w2, expert_rows, steps):
+        from .... import autograd
+        from ....observability.instrument import device_scope
+        with device_scope("moe.router"):
+            weights, routes, scores = F.contrib.moe_route(
+                x, router_weight, router_bias, **self._route)
+        # the op opens moe.dispatch, moe.experts and moe.combine itself
+        y, rows = F.contrib.moe_experts(
+            x, weights, routes, expert_w1, expert_w2,
+            first_expert=self._first, num_experts=self._experts)
+        if autograd.is_training():
+            expert_rows._rebind(expert_rows._data + rows._data)
+            steps._rebind(steps._data + 1)
+        if self.shared is not None:
+            with device_scope("moe.shared"):
+                y = y + self.shared(x)
+        return (y, routes, rows, scores) if self._return_routes else y
+
+
+# -- the expert load, for observability ---------------------------------------
+_live_routed = weakref.WeakSet()
+
+EXPERT_ROWS_METRIC = "mxnet_tpu_moe_expert_rows"
+EXPERT_STEPS_METRIC = "mxnet_tpu_moe_steps"
+
+
+def expert_load():
+    """``{layer: {"first_expert", "rows": [...], "steps"}}`` of the live
+    :class:`RoutedExperts` blocks whose counters hold concrete arrays: the
+    rows each held expert has received, summed over the training steps, and
+    the count of those steps. Reads the device."""
+    import jax
+    out = {}
+    for block in list(_live_routed):
+        rows, steps = block.expert_rows._data, block.steps._data
+        if not rows or not steps:
+            continue
+        rows, steps = rows[0]._data, steps[0]._data
+        if isinstance(rows, jax.core.Tracer) \
+                or isinstance(steps, jax.core.Tracer):
+            continue
+        out[block.prefix.rstrip("_")] = {
+            "first_expert": block._first,
+            "rows": [int(n) for n in np.asarray(rows)],
+            "steps": int(np.asarray(steps)[0])}
+    return out
+
+
+def _export_expert_load(registry):
+    load = expert_load()
+    if not load:
+        return
+    rows = registry.gauge(
+        EXPERT_ROWS_METRIC, "rows a held expert has received, summed over "
+        "the training steps", ("layer", "expert"))
+    steps = registry.gauge(
+        EXPERT_STEPS_METRIC, "training steps a routed-expert layer has "
+        "counted its rows over", ("layer",))
+    for layer, said in load.items():
+        steps.labels(layer=layer).set(said["steps"])
+        for i, n in enumerate(said["rows"]):
+            rows.labels(layer=layer,
+                        expert=str(said["first_expert"] + i)).set(n)
+
+
+from ....observability import metrics as _metrics     # noqa: E402
+
+_metrics.register_collector(_export_expert_load)

@@ -133,7 +133,9 @@ class Mamba2Mixer(HybridBlock):
     """Mamba-2 mixer: ``[z | xBC | dt] = W_in x``; ``xBC`` through a
     depthwise causal convolution and SiLU; the selective scan over ``x``
     (``num_heads`` x ``head_dim``) with ``B`` and ``C`` (``n_groups`` x
-    ``state_size``); ``RMSNorm(y * silu(z))``; ``W_out``."""
+    ``state_size``: a group serves ``num_heads // n_groups`` consecutive
+    heads); ``RMSNorm(y * silu(z))``, the mean square taken over each of
+    the ``n_groups`` runs of channels apart; ``W_out``."""
 
     def __init__(self, units, num_heads, head_dim, state_size, n_groups=1,
                  conv_kernel=4, chunk_size=256, epsilon=1e-5, **kwargs):
@@ -187,7 +189,7 @@ class Mamba2Mixer(HybridBlock):
         with device_scope("mamba2.gate_norm"):
             y = F.contrib.gated_rms_norm(
                 F.reshape(y, (0, 0, -1)), cut(zxbcdt, 0, inner), norm_gamma,
-                eps=self._epsilon)
+                eps=self._epsilon, groups=self._groups)
         with device_scope("mamba2.out_proj"):
             return self.out_proj(y)
 

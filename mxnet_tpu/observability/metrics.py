@@ -322,6 +322,7 @@ class MetricsRegistry:
         """JSON-able state of every family: scalar values for counters/
         gauges, the LatencySummary dict for summaries.  Label values key
         a nested dict as ``"k=v,k2=v2"`` (or ``""`` for label-less)."""
+        _run_collectors(self)
         out = {}
         for name, fam in self.families().items():
             values = {}
@@ -338,6 +339,7 @@ class MetricsRegistry:
 
     def prometheus_text(self) -> str:
         """Prometheus text exposition format, version 0.0.4."""
+        _run_collectors(self)
         lines = []
         for name, fam in self.families().items():
             if fam.help:
@@ -388,6 +390,25 @@ def _num(v) -> str:
     if f == int(f) and abs(f) < 1e15:
         return str(int(f))
     return repr(f)
+
+
+# Collectors fill gauges that are worth computing only when somebody reads
+# them (state that lives on the device): ``fn(registry)`` runs before every
+# read-out, of whichever registry is read.
+_collectors: list = []
+
+
+def register_collector(fn):
+    """Run ``fn(registry)`` before every ``snapshot()`` and
+    ``prometheus_text()``; registering the same function twice is once."""
+    if fn not in _collectors:
+        _collectors.append(fn)
+    return fn
+
+
+def _run_collectors(registry):
+    for fn in list(_collectors):
+        fn(registry)
 
 
 _default_lock = threading.Lock()

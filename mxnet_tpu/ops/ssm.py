@@ -9,7 +9,8 @@ op; these are new TPU-side capability (ROADMAP R6).
   computed in chunks (Dao & Gu, "Transformers are SSMs", arXiv:2405.21060,
   sec. 6): inside a chunk as masked products, between chunks as a
   recurrence over the chunk states;
-- ``_contrib_gated_rms_norm``: ``RMSNorm(y * silu(z))`` behind the scan;
+- ``_contrib_gated_rms_norm``: ``RMSNorm(y * silu(z))`` behind the scan, over
+  all channels or over each of ``groups`` runs of them;
 - ``_contrib_swiglu``: ``silu(g) * u`` over the two halves of the last axis.
 
 All four are plain ``jax.numpy`` / ``lax`` that XLA compiles, and their
@@ -141,13 +142,24 @@ def _mamba2_ssd(x, dt, a_log, b, c, d, dt_bias, chunk_size=256):
 
 
 @register("_contrib_gated_rms_norm", num_inputs=3,
-          params=[OpParam("eps", float, 1e-5)],
+          params=[OpParam("eps", float, 1e-5), OpParam("groups", int, 1)],
           doc="RMSNorm(y * silu(z)) * gamma over the last axis (gate before "
-              "norm, one group), computed in float32, returned in y's dtype.")
-def _gated_rms_norm(y, z, gamma, eps=1e-5):
+              "norm), the mean square taken over each of `groups` equal "
+              "runs of consecutive channels apart (1: over all of them), "
+              "computed in float32, returned in y's dtype.")
+def _gated_rms_norm(y, z, gamma, eps=1e-5, groups=1):
     g = y.astype(_F32) * jax.nn.silu(z.astype(_F32))
-    ms = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
-    return (g * lax.rsqrt(ms + eps) * gamma.astype(_F32)).astype(y.dtype)
+    if groups == 1:
+        ms = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+        normed = g * lax.rsqrt(ms + eps)
+    else:
+        if g.shape[-1] % groups:
+            raise MXNetError(f"gated_rms_norm: {g.shape[-1]} channels are no "
+                             f"multiple of groups {groups}")
+        by_group = g.reshape(g.shape[:-1] + (groups, -1))
+        ms = jnp.mean(jnp.square(by_group), axis=-1, keepdims=True)
+        normed = (by_group * lax.rsqrt(ms + eps)).reshape(g.shape)
+    return (normed * gamma.astype(_F32)).astype(y.dtype)
 
 
 @register("_contrib_swiglu", num_inputs=1,

@@ -1,6 +1,6 @@
 """Seed kernels of the guarded Pallas tier (docs/pallas.md).
 
-Two kernels, each dispatched by a benchmark cell:
+Three kernels, each dispatched by a benchmark cell:
 
 - ``matmul_epilogue`` — the BERT lever (~56% MFU inside XLA's matmul
   fusions, dropout-mask traffic measured 24% of a step pre-rbg): bias +
@@ -12,8 +12,11 @@ Two kernels, each dispatched by a benchmark cell:
 - ``blockwise_attention`` — the existing long-context online-softmax
   kernel (parallel/ring_attention.py), routed through the same registry
   so every custom kernel shares one kill-switch / parity / journal story.
+- ``grouped_matmul`` — rows ordered by group, one weight matrix a group:
+  the routed-expert layer's two products (``ops/moe.py``), on the
+  library's megablox kernels with ``lax.ragged_dot`` as the reference.
 
-(A third, ``conv_epilogue``, was the ResNet lever until it lost on the
+(Another, ``conv_epilogue``, was the ResNet lever until it lost on the
 chip — a 2-D view of a tiled NCHW activation is a physical re-layout,
 5.5x the step; PERF.md §6, PR 26 — and was deleted in PR 29.)
 
@@ -369,6 +372,170 @@ def _blockwise_pallas(q, k, v, interpret=False, block_size=512, causal=False,
     from ..parallel.ring_attention import _blockwise_impl
     return _blockwise_impl(q, k, v, block_size=block_size, causal=causal,
                            scale=scale)
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul: rows ordered by group, one weight matrix a group
+# ---------------------------------------------------------------------------
+def _grouped_ref(lhs, rhs, group_sizes):
+    """``out[r] = lhs[r] @ rhs[g]`` for the rows ``r`` of group ``g`` (the
+    first ``group_sizes[0]`` rows are group 0's, and so on); rows past the
+    groups' end are zero. Accumulated in float32, returned in lhs's dtype.
+    Two-byte operands say DEFAULT precision themselves, so that the products
+    autodiff derives say it too: one pass of bf16 x bf16 into float32 is
+    exact, and the chip's compiler refuses the package's process-wide
+    HIGHEST on them ("Bad lhs type")."""
+    return jax.lax.ragged_dot(
+        lhs, rhs, group_sizes,
+        precision=None if lhs.dtype == jnp.float32
+        else jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32).astype(lhs.dtype)
+
+
+def _dividing_tile(n, cap):
+    """The whole of ``n`` where it is under ``cap``, else the largest
+    multiple of 128 up to ``cap`` that divides it, else ``cap`` (the
+    library kernels mask a last partial tile)."""
+    if n <= cap:
+        return n
+    return next((t for t in range(cap // 128 * 128, 0, -128) if n % t == 0),
+                cap)
+
+
+def _row_tile(m, cap):
+    return next(t for t in (512, 256, 128, 64, 32, 16, 8)
+                if t <= cap and m % t == 0)
+
+
+def grouped_tiles(m, k, n, itemsize=2):
+    """Row, contraction and column tiles of the library's ``gmm`` for an
+    (m, k) x (G, k, n) product, from the shapes. The sweep on a v5e
+    (PERF.md sec. 6, PR 32; 12288 rows of which 16 groups own 6144, bf16):
+    rows of 256 beat 512 (a group of 384 rows fills whole tiles of 512 at
+    best by half) and 128; a contraction or column dimension taken whole is
+    best where it fits (1856), else its largest divisor that is a multiple
+    of 128 (2688: 896); one weight tile of up to 4 MB, two in flight, stays
+    inside the kernel's 16 MB of fast memory with the row tiles and the
+    float32 accumulator. Float32 operands take half the elements."""
+    scale = max(1, itemsize // 2)
+    whole, cap, budget = 2048 // scale, 1024 // scale, 2 ** 21 // scale
+    tk = _dividing_tile(k, whole if k <= whole else cap)
+    tn = _dividing_tile(n, whole if n <= whole else cap)
+    if tk * tn > budget:
+        tn = _dividing_tile(n, cap)
+    if tk * tn > budget:
+        tk = _dividing_tile(k, cap)
+    return _row_tile(m, 256), tk, tn
+
+
+def grouped_weight_tiles(m, k, n, itemsize=2):
+    """The tiles of the library's ``tgmm`` (the weights' gradient: (k, m) x
+    (m, n) -> (G, k, n)): its float32 accumulator is a whole (tk, tn) output
+    tile, so both stay under 1024 (512 for float32 operands), and the rows
+    it contracts over go 512 at a time."""
+    cap = 1024 // max(1, itemsize // 2)
+    return _row_tile(m, 512), _dividing_tile(k, cap), _dividing_tile(n, cap)
+
+
+def _megablox():
+    # the package's own ``gmm`` attribute is a function that hides the
+    # module of the same name
+    import importlib
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+def _grouped_precision(operand):
+    """The package asks for HIGHEST precision on every float32 matmul,
+    process-wide, and the compiler refuses that on a kernel's bfloat16
+    product ("Bad lhs type"): one pass of bf16 x bf16 into float32 is
+    already exact, so the default changes no result there. Held over the
+    backward's trace too, which runs outside any scope the forward
+    opened."""
+    import contextlib
+    if operand.dtype == jnp.float32:
+        return contextlib.nullcontext()
+    return jax.default_matmul_precision("default")
+
+
+def _past_the_end(out, group_sizes):
+    """Zero the rows no group owns: the library kernel skips their tiles
+    and leaves what was in memory."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (out.shape[0], 1), 0)
+    return jnp.where(rows < jnp.sum(group_sizes), out, jnp.zeros_like(out))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped_library(lhs, rhs, group_sizes, interpret):
+    megablox = _megablox()
+    m, k = lhs.shape
+    with _grouped_precision(lhs):
+        out = megablox.gmm(lhs, rhs, group_sizes, lhs.dtype,
+                           grouped_tiles(m, k, rhs.shape[2],
+                                         lhs.dtype.itemsize),
+                           interpret=interpret)
+    return _past_the_end(out, group_sizes)
+
+
+def _grouped_library_fwd(lhs, rhs, group_sizes, interpret):
+    return (_grouped_library(lhs, rhs, group_sizes, interpret),
+            (lhs, rhs, group_sizes))
+
+
+def _grouped_library_bwd(interpret, kept, g):
+    megablox = _megablox()
+    lhs, rhs, group_sizes = kept
+    m, k = lhs.shape
+    n, size = rhs.shape[2], lhs.dtype.itemsize
+    with _grouped_precision(lhs):
+        d_lhs = megablox.gmm(g, rhs, group_sizes, lhs.dtype,
+                             grouped_tiles(m, n, k, size), transpose_rhs=True,
+                             interpret=interpret)
+        d_rhs = megablox.tgmm(lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
+                              grouped_weight_tiles(m, k, n, size),
+                              num_actual_groups=rhs.shape[0],
+                              interpret=interpret)
+    return _past_the_end(d_lhs, group_sizes), d_rhs, None
+
+
+_grouped_library.defvjp(_grouped_library_fwd, _grouped_library_bwd)
+
+
+def _grouped_supports(lhs, rhs, group_sizes):
+    if lhs.ndim != 2 or rhs.ndim != 3 or lhs.shape[1] != rhs.shape[1] \
+            or group_sizes.shape != (rhs.shape[0],):
+        return f"shape:lhs{lhs.shape}_rhs{rhs.shape}_sizes{group_sizes.shape}"
+    if lhs.dtype != rhs.dtype or lhs.dtype not in (jnp.bfloat16,
+                                                    jnp.float32):
+        return f"dtype:{lhs.dtype}_{rhs.dtype}"
+    if lhs.shape[0] % 8 or lhs.shape[0] == 0:
+        return f"rows:{lhs.shape[0]}"
+    return None
+
+
+def _grouped_example():
+    rng = np.random.RandomState(4)
+    lhs = jnp.asarray(rng.randn(64, 128), jnp.float32)
+    rhs = jnp.asarray(rng.randn(4, 128, 256) * 0.1, jnp.float32)
+    return [
+        # an empty group, and rows past the groups' end
+        ((lhs, rhs, jnp.asarray([20, 0, 17, 11], jnp.int32)), {}),
+        ((lhs, rhs, jnp.asarray([16, 16, 16, 16], jnp.int32)), {}),
+    ]
+
+
+@register_kernel(
+    "grouped_matmul", xla_reference=_grouped_ref, tolerance=1e-4,
+    backends=("tpu",), supports=_grouped_supports, example=_grouped_example,
+    doc="Rows ordered by group times one (k, n) matrix a group: the two "
+        "products of a routed-expert layer (ops/moe.py). The library's "
+        "megablox kernels (custom calls named gmm and tgmm) with tiles "
+        "from the shapes; they visit only the row tiles a group owns, so "
+        "rows past the groups' end cost nothing. lax.ragged_dot is the "
+        "reference: 3.3x the kernel's time forward and backward at 16 "
+        "groups of (2688, 1856) on a v5e (PERF.md sec. 6, PR 32).")
+def _grouped_pallas(lhs, rhs, group_sizes, interpret=False):
+    return _grouped_library(lhs, rhs, group_sizes, bool(interpret))
 
 
 # ---------------------------------------------------------------------------

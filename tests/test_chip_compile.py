@@ -210,3 +210,48 @@ def test_supports_rejects_with_a_named_reason(args, params, want):
     with the reason that ``tier_provenance()`` will carry."""
     got = pallas.get_kernel("matmul_epilogue").supports(*args, **params)
     assert got is not None and got.startswith(want), (got, want)
+
+
+# the routed-expert layer of the nemotron_3_nano_30b_a3b cell: 8192 tokens,
+# 6 experts a token, 16 experts held of 128, 2688 -> 1856 -> 2688, bf16
+MOE_SHAPES = {"tokens": 8192, "k": 6, "held": 16, "units": 2688,
+              "hidden": 1856}
+
+
+def test_routed_experts_compile_forward_and_backward(one_chip, monkeypatch):
+    """``_contrib_moe_experts`` at the cell's shapes, ``jax.grad`` of it: both
+    sizes of the gather buffer under ``lax.cond``, each with its two grouped
+    products on the library's kernels at the tiles ``grouped_tiles`` reads
+    from the shapes (gmm forward, its transposed form and tgmm backward)."""
+    from mxnet_tpu.ops import moe
+    from mxnet_tpu.pallas import registry
+    m = MOE_SHAPES
+    # the process's backend is the CPU, and the tier asks it where abstract
+    # operands run: say what the chip run will say
+    monkeypatch.setattr(registry, "runs_on", lambda args: ("tpu", True))
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    structs = (sds((m["tokens"], m["units"])),
+               sds((m["tokens"], m["k"]), jnp.float32),
+               sds((m["tokens"], m["k"]), jnp.int32),
+               sds((m["held"], m["units"], m["hidden"])),
+               sds((m["held"], m["hidden"], m["units"])))
+
+    def loss(x, w, ids, w1, w2):
+        return moe._moe_experts(x, w, ids, w1, w2, num_experts=128)[
+            0].astype(jnp.float32).sum()
+
+    spec = pallas.get_kernel("grouped_matmul")
+    small, full = moe.buffer_rows(m["tokens"] * m["k"])
+    assert (small, full) == (12288, 49152)
+    _accepted(spec, (sds((small, m["units"])), structs[3],
+                     sds((m["held"],), jnp.int32)), {})
+    before = pallas.tier_provenance().get("grouped_matmul", {}).get(
+        "pallas", 0)
+    grad = _compile(jax.grad(loss, argnums=(0, 1, 3, 4)), *structs)
+    # in each of the two branches: two products forward, and for each of
+    # them two kernels backward
+    assert grad.as_text().count("tpu_custom_call") >= 12
+    assert pallas.tier_provenance()["grouped_matmul"]["pallas"] - before == 4
