@@ -335,8 +335,8 @@ def phase_bert(ctx, S, log, seed, chip):
 # ---------------------------------------------------------------------------
 def real_width_cases(chip):
     """One main-path shape per kernel: BERT-base's FFN hidden at 128x128
-    tokens with dropout, BERT-base heads at S=2048. bf16 where the model
-    runs bf16."""
+    tokens with dropout, BERT-base heads at S=2048, Nemotron's expert
+    product and scan. bf16 where the model runs bf16."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.pallas import dropout_bits
@@ -353,12 +353,23 @@ def real_width_cases(chip):
     lhs = jax.random.normal(k[0], (rows, u), jnp.bfloat16)
     rhs = (jax.random.normal(k[1], (groups, u, f)) * 0.02).astype(jnp.bfloat16)
     sizes = jnp.full((groups,), rows // (2 * groups), jnp.int32)
+    # one Mamba-2 layer's scan of the nemotron cell: 8192 positions, 8 groups
+    # of 8 heads of 64, state 128, chunk 128
+    length, h, g = (8192, 64, 8) if chip else (256, 4, 2)
+    x = jax.random.normal(k[0], (1, length, h, 64), jnp.bfloat16)
+    dt = jax.nn.softplus(jax.random.normal(k[1], (1, length, h)) - 2.0)
+    cs = jnp.cumsum((dt * -jnp.linspace(1.0, 16.0, h)).reshape(
+        1, length // 128, 128, h), axis=2).reshape(dt.shape)
+    bc = (jax.random.normal(k[2], (2, 1, length, g, 128)) * 0.3).astype(
+        jnp.bfloat16)
     return {
         "matmul_epilogue": ((y, b, bits), {"act_type": "gelu", "p": 0.1}),
         "blockwise_attention": ((q, q * 0.5, q + 1.0),
                                 {"block_size": 512 if chip else 32,
                                  "causal": True}),
         "grouped_matmul": ((lhs, rhs, sizes), {}),
+        "mamba2_ssd": ((x, dt, cs, bc[0], bc[1], jnp.ones((h,))),
+                       {"chunk_size": 128}),
     }
 
 

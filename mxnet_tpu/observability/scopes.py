@@ -24,6 +24,7 @@ that a reload). Parsing is stdlib-only.
 """
 from __future__ import annotations
 
+import itertools
 import re
 import weakref
 
@@ -31,7 +32,10 @@ from .instrument import ANNOTATION_PREFIX
 
 __all__ = ["device_scopes", "scope_of", "scopes_of_program", "watch"]
 
-_trainers = weakref.WeakSet()
+# in the order they were registered (a WeakSet has none): callers take the
+# last program listed for the newest trainer's
+_trainers = weakref.WeakValueDictionary()
+_registered = itertools.count()
 
 _SCOPE = re.compile(re.escape(ANNOTATION_PREFIX) + r"([\w.]*\w)")
 _MODULE = re.compile(r"^HloModule\s+([\w.\-]+)")
@@ -44,7 +48,7 @@ _CALLS = re.compile(r"\bfusion\(.*\bcalls=%?([\w.\-]+)")
 def watch(trainer):
     """Register a trainer whose ``program_texts()`` :func:`device_scopes`
     reads while it lives."""
-    _trainers.add(trainer)
+    _trainers[next(_registered)] = trainer
 
 
 def scope_of(op_name):
@@ -95,10 +99,10 @@ def scopes_of_program(text) -> dict:
 def device_scopes() -> dict:
     """``{program: {"module": ..., "scopes": {HLO instruction: innermost
     mxnet_tpu scope}}}`` for every program (``step``, ``run_steps(<k>)``) the
-    live trainers have built. With several trainers alive, a program name
-    is prefixed by the trainer's position (``1:step``)."""
+    live trainers have built, the oldest trainer first. With several trainers
+    alive, a program name is prefixed by the trainer's position (``1:step``)."""
     out = {}
-    for at, trainer in enumerate(list(_trainers)):
+    for at, trainer in enumerate(list(_trainers.values())):
         for program, text in trainer.program_texts().items():
             out[program if at == 0 else f"{at}:{program}"] = \
                 scopes_of_program(text)

@@ -8,15 +8,19 @@ op; these are new TPU-side capability (ROADMAP R6).
   ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t + D x_t``
   computed in chunks (Dao & Gu, "Transformers are SSMs", arXiv:2405.21060,
   sec. 6): inside a chunk as masked products, between chunks as a
-  recurrence over the chunk states;
+  recurrence over the chunk states. The step sizes and the cumulative
+  log-decay are made here; the scan itself is the kernel tier's
+  ``mamba2_ssd`` (``pallas/ssd.py``): one fused pass over the chunks on a
+  TPU, the ``jax.numpy`` scan elsewhere;
 - ``_contrib_gated_rms_norm``: ``RMSNorm(y * silu(z))`` behind the scan, over
   all channels or over each of ``groups`` runs of them;
 - ``_contrib_swiglu``: ``silu(g) * u`` over the two halves of the last axis.
 
-All four are plain ``jax.numpy`` / ``lax`` that XLA compiles, and their
-backward passes are autodiff's. ``dt``, ``A``, the cumulative sums, the
-exponentials and every accumulation are float32 whatever the compute dtype;
-the operands of the four products are in the compute dtype.
+But for that kernel all four are plain ``jax.numpy`` / ``lax`` that XLA
+compiles, and their backward passes are autodiff's. ``dt``, ``A``, the
+cumulative sums, the exponentials and every accumulation are float32
+whatever the compute dtype; the operands of the scan's products are in the
+compute dtype.
 """
 from __future__ import annotations
 
@@ -32,14 +36,16 @@ SCAN_COUNT_METRIC = "mxnet_tpu_ssd_scans_traced_total"
 _F32 = jnp.float32
 
 
-def _count_traced_scan(chunk, length):
-    """One chunked scan traced into a program, by chunk size and (padded)
-    sequence length: trace-time only, so a compiled step never counts."""
+def _count_traced_scan(chunk, length, path):
+    """One chunked scan traced into a program, by chunk size, (padded)
+    sequence length and the path the kernel tier chose (``kernel``: the
+    fused scan where the program is lowered for a TPU; ``xla``: the
+    ``jax.numpy`` scan): trace-time only, so a compiled step never counts."""
     from ..observability.metrics import default_registry
     default_registry().counter(
         SCAN_COUNT_METRIC, "chunked state-space scans traced into a program",
-        ("chunk", "length")).labels(chunk=str(chunk),
-                                    length=str(length)).inc()
+        ("chunk", "length", "path")).labels(
+            chunk=str(chunk), length=str(length), path=path).inc()
 
 
 def _act(x, act_type):
@@ -82,63 +88,31 @@ def _mamba2_ssd(x, dt, a_log, b, c, d, dt_bias, chunk_size=256):
         raise MXNetError(f"mamba2_ssd: x (B, L, H, P) and B, C (B, L, G, N) "
                          f"with H a multiple of G expected, got {x.shape} "
                          f"and {b.shape}")
-    bsz, length, heads, p = x.shape
-    groups, n = b.shape[2], b.shape[3]
-    r = heads // groups                         # heads that share a B and C
+    length, heads = x.shape[1], x.shape[2]
     q = int(chunk_size)
     pad = -length % q
     if pad:
         x, dt, b, c = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
                        for t in (x, dt, b, c))
-    nc = (length + pad) // q
-    if isinstance(x, jax.core.Tracer):
-        _count_traced_scan(q, length + pad)
-    cdt = x.dtype
-
-    def dot(spec, *operands):
-        return jnp.einsum(spec, *(o.astype(cdt) for o in operands),
-                          preferred_element_type=_F32)
-
     dt = jax.nn.softplus(dt.astype(_F32) + dt_bias.astype(_F32))
     a = -jnp.exp(a_log.astype(_F32))
-    xc = x.reshape(bsz, nc, q, groups, r, p)
-    bc = b.reshape(bsz, nc, q, groups, n)
-    cc = c.reshape(bsz, nc, q, groups, n)
-    dtc = dt.reshape(bsz, nc, q, groups, r)
-    # log of the decay from the chunk's start to each position, inclusive
-    cs = jnp.cumsum(dtc * a.reshape(groups, r), axis=2)
-
-    # inside a chunk: y[l] += sum_{s<=l} (C_l . B_s) exp(cs_l - cs_s) dt_s x_s
-    # (the (l, s) matrices head-major, so that their minor axes are whole
-    # tiles of the chip's registers)
-    cs_h = jnp.moveaxis(cs, 2, -1)                      # (B, nc, G, R, Q)
-    seg = cs_h[..., :, None] - cs_h[..., None, :]       # (B, nc, G, R, l, s)
-    decay = jnp.exp(jnp.where(jnp.tril(jnp.ones((q, q), bool)), seg,
-                              -jnp.inf))
-    cb = dot("bclgn,bcsgn->bcgls", cc, bc)
-    scores = cb[:, :, :, None] * decay \
-        * jnp.moveaxis(dtc, 2, -1)[..., None, :]
-    y = dot("bcgrls,bcsgrp->bclgrp", scores, xc)
-
-    # the state each chunk adds: sum_s exp(cs_end - cs_s) dt_s x_s B_s^T
-    to_end = jnp.exp(cs[:, :, -1:] - cs) * dtc
-    added = dot("bcsgn,bcsgrp->bcgrpn", bc, xc * to_end[..., None])
-
-    # between chunks: S_c = exp(cs_end) S_{c-1} + added_c; each chunk reads
-    # the state it starts from
-    def carry_state(state, chunk):
-        keep, new = chunk
-        return keep[..., None, None] * state + new, state
-
-    _, entering = lax.scan(
-        carry_state, jnp.zeros((bsz, groups, r, p, n), _F32),
-        (jnp.moveaxis(jnp.exp(cs[:, :, -1]), 1, 0), jnp.moveaxis(added, 1, 0)))
-    entering = jnp.moveaxis(entering, 0, 1)             # (B, nc, G, R, P, N)
-    y = y + dot("bclgn,bcgrpn->bclgrp", cc, entering) * jnp.exp(cs)[..., None]
-
-    y = y.reshape(bsz, nc * q, heads, p)[:, :length]
-    skip = d.astype(_F32)[:, None] * x[:, :length].astype(_F32)
-    return (y + skip).astype(cdt)
+    # log of the decay from the chunk's start to each position, inclusive:
+    # the cumulative sum of dt * A inside a chunk, as a float32 product with
+    # the lower triangle of ones (exact terms, float32 sums). jnp.cumsum
+    # over (B, nc, q, G, R) is a reduce-window whose minor axes are 8 x 8 at
+    # Nemotron's shape: 1.96 ms for these 2 MB on a v5e, twice a layer a
+    # step, against 0.20 for the product (PERF.md sec. 6, PR 33)
+    cs = jnp.einsum("ls,bcsh->bclh", jnp.tril(jnp.ones((q, q), _F32)),
+                    (dt * a).reshape(x.shape[0], -1, q, heads),
+                    precision=lax.Precision.HIGHEST).reshape(dt.shape)
+    from ..pallas import dispatch, tier_provenance
+    before = tier_provenance().get("mamba2_ssd", {}).get("pallas", 0)
+    y = dispatch("mamba2_ssd", x, dt, cs, b, c, d, chunk_size=q)
+    if isinstance(x, jax.core.Tracer):
+        took_kernel = tier_provenance().get("mamba2_ssd", {}).get(
+            "pallas", 0) > before
+        _count_traced_scan(q, length + pad, "kernel" if took_kernel else "xla")
+    return y[:, :length]
 
 
 @register("_contrib_gated_rms_norm", num_inputs=3,

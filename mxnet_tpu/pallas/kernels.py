@@ -1,6 +1,6 @@
 """Seed kernels of the guarded Pallas tier (docs/pallas.md).
 
-Three kernels, each dispatched by a benchmark cell:
+Four kernels, each dispatched by a benchmark cell:
 
 - ``matmul_epilogue`` — the BERT lever (~56% MFU inside XLA's matmul
   fusions, dropout-mask traffic measured 24% of a step pre-rbg): bias +
@@ -15,15 +15,20 @@ Three kernels, each dispatched by a benchmark cell:
 - ``grouped_matmul`` — rows ordered by group, one weight matrix a group:
   the routed-expert layer's two products (``ops/moe.py``), on the
   library's megablox kernels with ``lax.ragged_dot`` as the reference.
+- ``mamba2_ssd`` — the chunked Mamba-2 scan (``ops/ssm.py``) as one pass
+  over the chunks with the running state in fast memory, forward and
+  backward; kernel and reference live in :mod:`.ssd`.
 
 (Another, ``conv_epilogue``, was the ResNet lever until it lost on the
 chip — a 2-D view of a tiled NCHW activation is a physical re-layout,
 5.5x the step; PERF.md §6, PR 26 — and was deleted in PR 29.)
 
 Every kernel registers with its XLA reference and tolerance; gradients of
-the Pallas paths are ``custom_vjp`` with the reference's VJP as the
-backward (rematerialized — the backward is mathematically the reference's,
-so the parity gate bounds the full training step, not just the forward).
+the epilogue's Pallas paths are ``custom_vjp`` with the reference's VJP as
+the backward (rematerialized — the backward is mathematically the
+reference's, so the parity gate bounds the full training step, not just the
+forward); the grouped product and the scan have backward kernels of their
+own, held to autodiff of their references by tests/test_pallas.py.
 """
 from __future__ import annotations
 
@@ -34,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import MXNetError
+from . import ssd as _ssd      # noqa: F401  (registers mamba2_ssd)
 from .registry import (block_ok, default_block, dispatch,
                        register_kernel)
 

@@ -255,3 +255,53 @@ def test_routed_experts_compile_forward_and_backward(one_chip, monkeypatch):
     # them two kernels backward
     assert grad.as_text().count("tpu_custom_call") >= 12
     assert pallas.tier_provenance()["grouped_matmul"]["pallas"] - before == 4
+
+
+# one layer's scan of the two hybrid cells: (L, H, P, G, N, chunk), bf16
+SSD_SHAPES = {
+    "granite_4_0_h_micro": (4096, 64, 64, 1, 128, 256),
+    "nemotron_3_nano_30b_a3b": (8192, 64, 64, 8, 128, 128),
+}
+
+
+@pytest.mark.parametrize("what", ["forward", "backward"])
+@pytest.mark.parametrize("cell", list(SSD_SHAPES))
+def test_scan_kernel_compiles_at_the_cells_shapes(one_chip, monkeypatch, cell,
+                                                  what):
+    """``_contrib_mamba2_ssd`` as a cell traces it (the step sizes and the
+    cumulative sum in ``jax.numpy``, the scan through the tier's dispatch),
+    forward and ``jax.grad`` over all seven operands: ``supports`` takes
+    both shapes, the forward is one kernel and the backward two (the
+    forward again, writing the chunk states, and the pass in reverse), and
+    no ``while`` is left of the ``lax.scan`` over the chunk states."""
+    from mxnet_tpu.ops import ssm
+    from mxnet_tpu.pallas import registry
+    length, h, p, g, n, chunk = SSD_SHAPES[cell]
+    # the process's backend is the CPU, and the tier asks it where abstract
+    # operands run: say what the chip run will say
+    monkeypatch.setattr(registry, "runs_on", lambda args: ("tpu", True))
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    structs = (sds((1, length, h, p)), sds((1, length, h)),
+               sds((h,), jnp.float32), sds((1, length, g, n)),
+               sds((1, length, g, n)), sds((h,), jnp.float32),
+               sds((h,), jnp.float32))
+
+    def fwd(*a):
+        return ssm._mamba2_ssd(*a, chunk_size=chunk)
+
+    def loss(*a):
+        return fwd(*a).astype(jnp.float32).sum()
+
+    before = pallas.tier_provenance().get("mamba2_ssd", {}).get("pallas", 0)
+    if what == "forward":
+        text, kernels = _compile(fwd, *structs).as_text(), 1
+    else:
+        text = _compile(jax.grad(loss, argnums=tuple(range(7))),
+                        *structs).as_text()
+        kernels = 2
+    assert text.count("tpu_custom_call") == kernels
+    assert " while(" not in text
+    assert pallas.tier_provenance()["mamba2_ssd"]["pallas"] - before == 1

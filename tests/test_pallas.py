@@ -711,3 +711,186 @@ def test_blockwise_reference_chunking_is_exact(clean_tier):
             np.testing.assert_allclose(
                 np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5,
                 err_msg=f"s_q={s_q} s_kv={s_kv} causal={causal}")
+
+
+# -- the fused scan kernel (mamba2_ssd) ---------------------------------------
+
+# (length, heads, head size, groups, state size, chunk): three chunks and more
+# everywhere, so a state and its cotangent are carried from chunk to chunk
+SSD_CASES = {
+    # 16 heads of 64 in one group: blocks of 8 heads in lane tiles of 2,
+    # two blocks a group, whose partial dB and dC are added outside
+    "one_group_many_heads": (24, 16, 64, 1, 16, 8),
+    "eight_groups_of_eight": (32, 64, 8, 8, 16, 8),
+    "a_group_a_head": (24, 4, 8, 4, 16, 8),
+    # a length that is no multiple of the chunk: padded at the end
+    "padded": (20, 16, 64, 1, 16, 8),
+}
+# PR 27's bounds: float32 against float32, and a bfloat16 program against
+# the same program in float32
+SSD_RTOL = {"float32": 1e-4, "bfloat16": 2e-2}
+
+
+def _ssd_op_inputs(case, dtype, seed=0):
+    import jax.numpy as jnp
+    length, h, p, g, n, _ = SSD_CASES[case]
+    rng = np.random.default_rng(seed)
+    shapes = [(1, length, h, p), (1, length, h), (h,), (1, length, g, n),
+              (1, length, g, n), (h,), (h,)]
+    args = [jnp.asarray(rng.standard_normal(s), jnp.float32) for s in shapes]
+    args[1] = args[1] - 2.0     # steps of a tenth or so, as dt_bias is drawn
+    args[2] = jnp.log(jnp.asarray(rng.uniform(1, 16, h), jnp.float32))
+    # x, B and C in the compute dtype; the parameters stay float32
+    return [a.astype(dtype) if i in (0, 3, 4) else a
+            for i, a in enumerate(args)]
+
+
+@pytest.fixture
+def scan_on_the_kernel(clean_tier, monkeypatch):
+    """``_contrib_mamba2_ssd`` with its scan on the kernel in interpret
+    mode. ``supports`` asks for whole tiles of the chip's registers, which
+    a toy shape has not and the interpreter does not need: it is taken out
+    here, and tests/test_chip_compile.py holds it to the chip's compiler."""
+    from mxnet_tpu.pallas import registry
+    monkeypatch.setattr(pallas.get_kernel("mamba2_ssd"), "supports", None)
+    monkeypatch.setattr(pallas, "dispatch", lambda name, *args, **params:
+                        registry.dispatch(name, *args, interpret=True,
+                                          **params))
+
+
+def _rel(got, want):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    return np.abs(got - want).max() / np.abs(want).max()
+
+
+@pytest.mark.parametrize("dtype", list(SSD_RTOL))
+@pytest.mark.parametrize("case", list(SSD_CASES))
+def test_ssd_kernel_is_its_reference_and_the_recurrence(case, dtype,
+                                                        scan_on_the_kernel):
+    """The whole op, forward: the kernel against the ``jax.numpy`` scan it
+    replaces on a TPU, and against the recurrence one position at a time."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import ssm
+    from test_granite_hybrid import recurrence
+    chunk = SSD_CASES[case][-1]
+    args = _ssd_op_inputs(case, dtype)
+    got = ssm._mamba2_ssd(*args, chunk_size=chunk)
+    assert pallas.tier_provenance()["mamba2_ssd"]["pallas"] == 1
+    assert got.shape == args[0].shape and got.dtype == jnp.dtype(dtype)
+    exact = [a.astype(jnp.float32) for a in args]
+    assert _rel(got, recurrence(*exact)) <= SSD_RTOL[dtype]
+    pallas.set_mode("off")              # the same call on the reference
+    want = ssm._mamba2_ssd(*args, chunk_size=chunk)
+    assert pallas.tier_provenance()["mamba2_ssd"]["xla"] == 1
+    assert _rel(got, want) <= SSD_RTOL[dtype]
+
+
+@pytest.mark.parametrize("dtype", list(SSD_RTOL))
+@pytest.mark.parametrize("case", list(SSD_CASES))
+def test_ssd_kernel_has_every_gradient_of_the_op(case, dtype,
+                                                 scan_on_the_kernel):
+    """x, dt, A_log, B, C, D and dt_bias: the backward kernel (the chunks in
+    reverse, the state's cotangent carried) with autodiff of the step sizes
+    and the cumulative sum around it, against autodiff of the reference and,
+    in float32, of the recurrence (the cosine of an output rounded to
+    bfloat16 is another number: there the two scans are held to each
+    other)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import ssm
+    from test_granite_hybrid import recurrence
+    chunk = SSD_CASES[case][-1]
+    args = _ssd_op_inputs(case, dtype, seed=1)
+    every = tuple(range(len(args)))
+
+    def grads(fn, operands):
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(
+            fn(*a).astype(jnp.float32))), every)(*operands)
+
+    def op(*a):
+        return ssm._mamba2_ssd(*a, chunk_size=chunk)
+
+    got = grads(op, args)
+    assert pallas.tier_provenance()["mamba2_ssd"]["pallas"] == 1
+    exact = grads(recurrence, [a.astype(jnp.float32) for a in args])
+    pallas.set_mode("off")
+    want = grads(op, args)
+    for g, w, e, a in zip(got, want, exact, args):
+        assert g.shape == a.shape and g.dtype == a.dtype
+        assert _rel(g, w) <= SSD_RTOL[dtype]
+        assert dtype != "float32" or _rel(g, e) <= SSD_RTOL[dtype]
+
+
+def test_ssd_tiles_are_read_from_the_shapes():
+    """A block of heads lies inside one group and takes up to 512 lanes; a
+    lane tile holds the heads that fit 128 lanes."""
+    from mxnet_tpu.pallas.ssd import ssd_tiles
+    assert ssd_tiles(8, 64) == (8, 2)       # Nemotron: a group of 8
+    assert ssd_tiles(64, 64) == (8, 2)      # Granite: 8 of a group's 64
+    assert ssd_tiles(4, 128) == (4, 1)
+    assert ssd_tiles(1, 64) == (1, 1)       # a head a group: half a tile
+    spec = pallas.get_kernel("mamba2_ssd")
+    import jax
+    import jax.numpy as jnp
+
+    def operands(h, p, g, n, length=256, dtype=jnp.bfloat16):
+        sds = jax.ShapeDtypeStruct
+        return (sds((1, length, h, p), dtype), sds((1, length, h),
+                                                   jnp.float32),
+                sds((1, length, h), jnp.float32), sds((1, length, g, n),
+                                                      dtype),
+                sds((1, length, g, n), dtype), sds((h,), jnp.float32))
+
+    assert spec.supports(*operands(64, 64, 8, 128), chunk_size=128) is None
+    assert spec.supports(*operands(64, 64, 1, 128), chunk_size=256) is None
+    for bad, params in ((operands(4, 64, 4, 128), {"chunk_size": 128}),
+                        (operands(8, 64, 1, 16), {"chunk_size": 128}),
+                        (operands(8, 64, 1, 128), {"chunk_size": 64})):
+        assert spec.supports(*bad, **params).startswith("tile:")
+    assert spec.supports(*operands(8, 64, 1, 128, dtype=jnp.float16),
+                         chunk_size=128).startswith("dtype:")
+    assert spec.supports(*operands(8, 64, 1, 128, length=200),
+                         chunk_size=128).startswith("shape:")
+
+
+def test_traced_scan_counts_the_path_it_took(clean_tier, monkeypatch):
+    """``mxnet_tpu_ssd_scans_traced_total{chunk,length,path}``: a scan traced
+    where a TPU is the backend stages the kernel (beside its reference, for
+    the lowering to choose) and counts ``kernel``; on the CPU, or at a shape
+    the kernel declines, it counts ``xla``."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import observability
+    from mxnet_tpu.ops import ssm
+    from mxnet_tpu.pallas import registry
+
+    def counted():
+        return dict(observability.snapshot()["metrics"].get(
+            ssm.SCAN_COUNT_METRIC, {}).get("values", {}))
+
+    def trace(heads):
+        shapes = [(1, 256, heads, 64), (1, 256, heads), (heads,),
+                  (1, 256, 1, 128), (1, 256, 1, 128), (heads,), (heads,)]
+        dtypes = [jnp.bfloat16, jnp.float32, jnp.float32, jnp.bfloat16,
+                  jnp.bfloat16, jnp.float32, jnp.float32]
+        return str(jax.make_jaxpr(
+            lambda *a: ssm._mamba2_ssd(*a, chunk_size=128))(
+                *[jax.ShapeDtypeStruct(s, d)
+                  for s, d in zip(shapes, dtypes)]))
+
+    before = counted()
+    assert "pallas_call" not in trace(8)            # the CPU: the reference
+    monkeypatch.setattr(registry, "_backend", lambda: "tpu")
+    text = trace(8)
+    assert "pallas_call" in text and "platform_index" in text
+    assert "pallas_call" not in trace(1)            # declined: half a tile
+    after = counted()
+
+    def grew(path):
+        key = f"chunk=128,length=256,path={path}"
+        return after.get(key, 0) - before.get(key, 0)
+
+    assert (grew("kernel"), grew("xla")) == (1, 2)
+    prov = pallas.tier_provenance()["mamba2_ssd"]
+    assert prov["pallas"] == 1 and prov["fallback_reasons"] == {
+        "backend:cpu": 1, "tile:p64_heads_per_group1_n128_chunk128": 1}
