@@ -76,6 +76,25 @@ def _linear(units, in_units, prefix):
                     prefix=prefix)
 
 
+def embed_tokens(F, tokens, weight, vocab, units, multiplier=1.0):
+    """``weight[tokens] * multiplier``: (B, S) ids -> (B, S, units),
+    ``weight`` (vocab, units)."""
+    with device_scope("embed"):
+        h = F.Embedding(tokens, weight, input_dim=vocab, output_dim=units)
+        return h if multiplier == 1.0 else h * multiplier
+
+
+def project_logits(F, h, final_norm, weight, vocab, scaling=1.0):
+    """``final_norm(h) weight^T / scaling``: the head over ``weight`` (vocab,
+    units), the embedding's where the head is tied."""
+    with device_scope("norm"):
+        h = final_norm(h)
+    with device_scope("lm_head"):
+        logits = F.FullyConnected(h, weight, num_hidden=vocab, no_bias=True,
+                                  flatten=False)
+        return logits if scaling == 1.0 else logits * (1.0 / scaling)
+
+
 class GatedMLP(HybridBlock):
     """``W_out(silu(g) * u)`` with ``[g, u] = split(W_in x)``, no bias."""
 
@@ -265,17 +284,11 @@ class GraniteHybridModel(HybridBlock):
                                          prefix="final_norm_")
 
     def hybrid_forward(self, F, tokens, embed_weight):
-        with device_scope("embed"):
-            h = F.Embedding(tokens, embed_weight, input_dim=self._vocab,
-                            output_dim=self._units) \
-                * self._embedding_multiplier
+        h = embed_tokens(F, tokens, embed_weight, self._vocab, self._units,
+                         self._embedding_multiplier)
         h = self.layers(h)
-        with device_scope("norm"):
-            h = self.final_norm(h)
-        with device_scope("lm_head"):
-            return F.FullyConnected(h, embed_weight, num_hidden=self._vocab,
-                                    no_bias=True, flatten=False) \
-                * (1.0 / self._logits_scaling)
+        return project_logits(F, h, self.final_norm, embed_weight,
+                              self._vocab, self._logits_scaling)
 
 
 # https://huggingface.co/ibm-granite/granite-4.0-h-micro/blob/main/config.json

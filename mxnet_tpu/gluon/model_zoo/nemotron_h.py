@@ -24,7 +24,8 @@ from ...observability.instrument import device_scope
 from .. import nn
 from ..block import HybridBlock
 from ..contrib.nn import RoutedExperts
-from .granite_hybrid import GroupedQueryAttention, Mamba2Mixer
+from .granite_hybrid import (GroupedQueryAttention, Mamba2Mixer,
+                             embed_tokens, project_logits)
 
 __all__ = ["NemotronHLayer", "NemotronHModel", "FirstOutputLoss",
            "nemotron_h", "nemotron_3_nano_30b_a3b",
@@ -117,9 +118,7 @@ class NemotronHModel(HybridBlock):
                 "head_weight", shape=(vocab_size, hidden_size))
 
     def hybrid_forward(self, F, tokens, embed_weight, head_weight):
-        with device_scope("embed"):
-            h = F.Embedding(tokens, embed_weight, input_dim=self._vocab,
-                            output_dim=self._units)
+        h = embed_tokens(F, tokens, embed_weight, self._vocab, self._units)
         routes, rows, scores = [], [], []
         for kind, layer in zip(self._pattern, self.layers._children.values()):
             if kind == "E" and self._return_routes:
@@ -129,11 +128,8 @@ class NemotronHModel(HybridBlock):
                 scores.append(scored)
             else:
                 h = layer(h)
-        with device_scope("norm"):
-            h = self.final_norm(h)
-        with device_scope("lm_head"):
-            logits = F.FullyConnected(h, head_weight, num_hidden=self._vocab,
-                                      no_bias=True, flatten=False)
+        logits = project_logits(F, h, self.final_norm, head_weight,
+                                self._vocab)
         if not self._return_routes:
             return logits
         return [logits] + routes + scores \

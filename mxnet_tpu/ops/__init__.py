@@ -24,3 +24,4 @@ from . import quantization    # ref: src/operator/quantization/
 from . import sequence        # ref: src/operator/sequence_*.cc
 from . import ssm             # state-space scan (Mamba-2): no reference analog
 from . import moe             # routed experts held here: no reference analog
+from . import retention       # power retention (linear attention): no analog

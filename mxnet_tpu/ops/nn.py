@@ -474,6 +474,27 @@ def _rms_norm(x, gamma, axis=-1, eps=1e-6):
             * gamma.astype(jnp.float32)).astype(x.dtype)
 
 
+@register("_contrib_rotary_embedding", num_inputs=1,
+          params=[OpParam("theta", float, 10000.0)],
+          doc="Rotary position embedding over the whole last axis of x (B, "
+              "S, heads, D), D even, rotate-half convention: coordinates i "
+              "and i + D/2 of row s turn by the angle s * theta^(-2i/D), s = "
+              "0 .. S-1. Angles, sines and the rotation in float32, returned "
+              "in x's dtype (new op; no reference analog)")
+def _rotary_embedding(x, theta=10000.0):
+    if x.ndim != 4 or x.shape[-1] % 2:
+        raise MXNetError(f"rotary_embedding: x (B, S, heads, D) with D even "
+                         f"expected, got {x.shape}")
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq
+    cos = jnp.cos(angles)[:, None, :]               # one angle for all heads
+    sin = jnp.sin(angles)[:, None, :]
+    lo, hi = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin],
+                           axis=-1).astype(x.dtype)
+
+
 # ---------------------------------------------------------------------------
 # Dropout (ref: src/operator/nn/dropout.cc) — explicit PRNG key threading
 # ---------------------------------------------------------------------------
