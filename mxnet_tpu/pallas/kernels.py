@@ -18,6 +18,9 @@ Four kernels, each dispatched by a benchmark cell:
 - ``mamba2_ssd`` — the chunked Mamba-2 scan (``ops/ssm.py``) as one pass
   over the chunks with the running state in fast memory, forward and
   backward; kernel and reference live in :mod:`.ssd`.
+- ``power_retention`` — chunked power retention (``ops/retention.py``) as
+  one pass over a head's chunks with its state in fast memory, forward and
+  backward; kernel and reference live in :mod:`.retention`.
 
 (Another, ``conv_epilogue``, was the ResNet lever until it lost on the
 chip — a 2-D view of a tiled NCHW activation is a physical re-layout,
@@ -39,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import MXNetError
+from . import retention as _retention  # noqa: F401  (power_retention)
 from . import ssd as _ssd      # noqa: F401  (registers mamba2_ssd)
 from .registry import (block_ok, default_block, dispatch,
                        register_kernel)

@@ -26,6 +26,7 @@ from mxnet_tpu import autograd, gluon, nd, observability, parallel
 from mxnet_tpu.gluon.model_zoo import brumby
 from mxnet_tpu.ops import nn as nn_ops
 from mxnet_tpu.ops import retention
+from mxnet_tpu.pallas import retention as retention_kernel
 
 from chipbench import manifest
 from chipbench.models import brumby_14b_base as bm
@@ -174,13 +175,14 @@ def test_the_second_power_over_pairs_is_the_square_of_the_product():
     for dim in (8, 7, 128):
         u, w = (jnp.asarray(rng.standard_normal((dim, 5)), jnp.float32)
                 for _ in range(2))
-        pairs = retention.pair_features(u, weighted=True)
+        pairs = retention_kernel.pair_features(u, weighted=True)
         assert pairs.shape == ((dim // 2 + 1) * dim, 5)
-        assert close(jnp.sum(pairs * retention.pair_features(w), 0),
+        assert close(jnp.sum(pairs * retention_kernel.pair_features(w), 0),
                      np.asarray(jnp.sum(u * w, 0) ** 2), 1e-5)
     # 65 x 128 products at the published head size, 64 of them twice
-    assert retention.pair_features(jnp.ones((128, 1))).shape == (8320, 1)
-    assert retention.pair_weights(128).sum() * 128 == 128 * 128
+    assert retention_kernel.pair_features(
+        jnp.ones((128, 1))).shape == (8320, 1)
+    assert retention_kernel.pair_weights(128).sum() * 128 == 128 * 128
 
 
 def test_a_state_that_is_not_carried_is_caught():
@@ -461,3 +463,83 @@ def test_bad_configurations_are_refused():
         brumby.brumby(rope_scaling={"type": "yarn"}, **small)
     with pytest.raises(TypeError):
         brumby.brumby(rope_thetta=1e6, **small)
+
+
+# -- the operator on the kernel tier (pallas/retention.py, interpret mode) ----
+
+# the op with its scan on the tier's kernel in interpret mode (off the TPU the
+# tier runs the jax.numpy scan): tests/test_pallas.py's fixtures
+from test_pallas import clean_tier, retention_on_the_kernel  # noqa: E402,F401
+
+# (length, query heads, key/value heads, chunk) at the published head size:
+# a head is one lane tile, a chunk whole tiles of the a[t, s] form
+KERNEL_SHAPES = {"one_chunk": (128, 2, 1, 128),
+                 "three_chunks": (384, 3, 1, 128)}
+# each of the shapes in both dtypes and under both kinds of gate (a case
+# compiles the interpreted kernels anew: 10-20 s)
+KERNEL_CASES = [("one_chunk", "slow", "float32"),
+                ("one_chunk", "fast", "bfloat16"),
+                ("three_chunks", "slow", "float32"),
+                ("three_chunks", "slow", "bfloat16"),
+                ("three_chunks", "fast", "float32"),
+                ("three_chunks", "fast", "bfloat16")]
+# float32: the order of summation alone (read: 2e-7 to 9e-6). bfloat16: held
+# to the scan's own distance from the float32 form, times KERNEL_ROOM: the
+# kernel rounds a cotangent to the compute dtype where it is the operand of a
+# product, as every operand of its products; autodiff of the scan multiplies
+# the float32 cotangent as it is (read: forward 1.0, gradients up to 1.7)
+KERNEL_ROOM = 2.5
+
+
+def kernel_inputs(shape, gates, dtype, seed=0):
+    length, heads, groups, chunk = KERNEL_SHAPES[shape]
+    rng = np.random.default_rng(seed)
+    q, k, v, log_g = retention_inputs(length, heads, groups, 128, gates,
+                                      seed=seed, bsz=1)
+    if gates == "slow":     # a row still weighs a quarter three chunks on
+        log_g = jnp.asarray(-rng.uniform(0.1, 1.0, log_g.shape) / chunk,
+                            jnp.float32)
+    return [t.astype(dtype) for t in (q, k, v)] + [log_g], chunk
+
+
+def rel(got, want):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    return np.abs(got - want).max() / np.abs(want).max()
+
+
+@pytest.mark.parametrize("shape,gates,dtype", KERNEL_CASES,
+                         ids=["-".join(c) for c in KERNEL_CASES])
+def test_kernel_is_the_a_ts_form_and_the_scan(shape, gates, dtype,
+                                              retention_on_the_kernel):
+    """Forward and every gradient (q, k, v, log_g) of the op on the fused
+    kernel against the benchmark's plain ``a[t, s]`` form in float32 and
+    against the ``jax.numpy`` scan the kernel replaces on a TPU."""
+    from mxnet_tpu import pallas
+    args, chunk = kernel_inputs(shape, gates, dtype)
+    every = tuple(range(4))
+
+    def both(fn, operands):
+        def loss(*a):
+            y = fn(*a)
+            return jnp.sum(jnp.sin(y.astype(jnp.float32))), y
+        grads, y = jax.grad(loss, every, has_aux=True)(*operands)
+        return (y,) + grads
+
+    def op(*a):
+        return retention._power_retention(*a, chunk_size=chunk)
+
+    got = both(op, args)
+    assert pallas.tier_provenance()["power_retention"]["pallas"] == 1
+    assert got[0].dtype == jnp.dtype(dtype)
+    exact = both(lambda *a: bm.retention_reference(*a, eps=EPS, block=128),
+                 [a.astype(jnp.float32) for a in args])
+    pallas.set_mode("off")              # the same call on the jax.numpy scan
+    scan = both(op, args)
+    assert pallas.tier_provenance()["power_retention"]["xla"] == 1
+    for name, g, s, e, a in zip(("y", "q", "k", "v", "log_g"), got, scan,
+                                exact, [args[0]] + args):
+        assert g.shape == e.shape and g.dtype == s.dtype, name
+        if dtype == "float32":
+            assert rel(g, e) <= RTOL and rel(g, s) <= RTOL, name
+        else:
+            assert rel(g, e) <= KERNEL_ROOM * rel(s, e) + RTOL, name

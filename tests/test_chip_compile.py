@@ -305,3 +305,55 @@ def test_scan_kernel_compiles_at_the_cells_shapes(one_chip, monkeypatch, cell,
     assert text.count("tpu_custom_call") == kernels
     assert " while(" not in text
     assert pallas.tier_provenance()["mamba2_ssd"]["pallas"] - before == 1
+
+
+# the retention operator as the Brumby cell traces it, as its comparison's
+# state check calls it with every chunk a sequence of its own, and at the
+# kernel's float32 example: (B, L, H, G, chunk, dtype), heads of 128
+RETENTION_SHAPES = {
+    "brumby_14b_base": (1, 8192, 40, 8, 1024, jnp.bfloat16),
+    "state_check_one_chunk": (8, 1024, 10, 2, 1024, jnp.bfloat16),
+    "example_float32": (1, 256, 4, 2, 128, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("what", ["forward", "backward"])
+@pytest.mark.parametrize("case", list(RETENTION_SHAPES))
+def test_retention_kernel_compiles_at_the_cells_shapes(one_chip, monkeypatch,
+                                                       case, what):
+    """``_contrib_power_retention`` as a cell traces it (the cumulative sum
+    and the tier's dispatch), forward and ``jax.grad`` over q, k, v and the
+    log-gates: ``supports`` takes the shapes, the forward is one kernel and
+    the backward two (the forward again, writing the boundary states, and
+    the pass in reverse), and no ``while`` is left of the scans over the
+    key/value heads and the chunks. 64 MiB of fast memory are asked for:
+    the states, their cotangents and a group of expanded rows."""
+    from mxnet_tpu.ops import retention
+    from mxnet_tpu.pallas import registry
+    bsz, length, h, g, chunk, dtype = RETENTION_SHAPES[case]
+    monkeypatch.setattr(registry, "runs_on", lambda args: ("tpu", True))
+
+    def sds(shape, dtype=dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    structs = (sds((bsz, length, h, 128)), sds((bsz, length, g, 128)),
+               sds((bsz, length, g, 128)),
+               sds((bsz, length, g), jnp.float32))
+
+    def fwd(*a):
+        return retention._power_retention(*a, chunk_size=chunk)
+
+    def loss(*a):
+        return fwd(*a).astype(jnp.float32).sum()
+
+    before = pallas.tier_provenance().get("power_retention", {}).get(
+        "pallas", 0)
+    if what == "forward":
+        text, kernels = _compile(fwd, *structs).as_text(), 1
+    else:
+        text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3)),
+                        *structs).as_text()
+        kernels = 2
+    assert text.count("tpu_custom_call") == kernels
+    assert " while(" not in text
+    assert pallas.tier_provenance()["power_retention"]["pallas"] - before == 1

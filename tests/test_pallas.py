@@ -894,3 +894,146 @@ def test_traced_scan_counts_the_path_it_took(clean_tier, monkeypatch):
     prov = pallas.tier_provenance()["mamba2_ssd"]
     assert prov["pallas"] == 1 and prov["fallback_reasons"] == {
         "backend:cpu": 1, "tile:p64_heads_per_group1_n128_chunk128": 1}
+
+
+# -- the fused retention kernel (power_retention) -----------------------------
+
+def _retention_op_inputs(length, heads, groups, dim, dtype="float32", seed=0):
+    import jax.numpy as jnp
+    rng = np.random.default_rng(seed)
+
+    def unit(n):
+        t = rng.standard_normal((1, length, n, dim))
+        return t / np.sqrt(np.mean(t * t, -1, keepdims=True))
+
+    q, k = unit(heads), unit(groups)
+    v = rng.standard_normal((1, length, groups, dim))
+    # gates that remember a chunk of 128 or so: the state carries
+    log_g = -rng.uniform(0.25, 4.0, (1, length, groups)) / 128
+    return [jnp.asarray(t, dtype) for t in (q, k, v)] \
+        + [jnp.asarray(log_g, jnp.float32)]
+
+
+@pytest.fixture
+def retention_on_the_kernel(clean_tier, monkeypatch):
+    """``_contrib_power_retention`` with its scan on the kernel in interpret
+    mode; ``supports`` stays (the shapes here are whole register tiles)."""
+    from mxnet_tpu.pallas import registry
+    monkeypatch.setattr(pallas, "dispatch", lambda name, *args, **params:
+                        registry.dispatch(name, *args, interpret=True,
+                                          **params))
+
+
+@pytest.mark.parametrize("length", [256, 200], ids=["whole_chunks", "padded"])
+def test_retention_op_runs_on_the_kernel(length, retention_on_the_kernel):
+    """The whole op through the tier: two key/value heads of two query heads
+    each, two chunks of 128 (the second padded where the length is 200),
+    against the same call on the ``jax.numpy`` scan."""
+    from mxnet_tpu.ops import retention
+    args = _retention_op_inputs(length, 4, 2, 128)
+    got = retention._power_retention(*args, chunk_size=128)
+    assert pallas.tier_provenance()["power_retention"] == {
+        "pallas": 1, "xla": 0, "fallback_reasons": {}}
+    assert got.shape == args[0].shape and got.dtype == args[0].dtype
+    pallas.set_mode("off")
+    want = retention._power_retention(*args, chunk_size=128)
+    assert pallas.tier_provenance()["power_retention"]["xla"] == 1
+    assert _rel(got, want) <= 1e-4
+
+
+def test_retention_kernel_declines_what_is_no_whole_tile(
+        retention_on_the_kernel, tmp_path):
+    """A head of 8 coordinates and a chunk that is no multiple of 128 run
+    the ``jax.numpy`` scan, counted and journaled with the reason; so does
+    what the kernel has no arithmetic for."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.diagnostics import reset_journal
+    from mxnet_tpu.ops import retention
+    from mxnet_tpu.pallas.retention import pair_span, row_tile
+    spec = pallas.get_kernel("power_retention")
+    sds = jax.ShapeDtypeStruct
+
+    def operands(length=2048, heads=40, groups=8, dim=128, chunk=1024,
+                 dtype=jnp.bfloat16, cs=jnp.float32):
+        return (sds((1, length, heads, dim), dtype),
+                sds((1, length, groups, dim), dtype),
+                sds((1, length, groups, dim), dtype),
+                sds((1, groups, length // chunk, chunk), cs))
+
+    assert spec.supports(*operands(), chunk_size=1024) is None
+    assert spec.supports(*operands(length=1024), chunk_size=1024) is None
+    assert spec.supports(*operands(dtype=jnp.float32, chunk=128),
+                         chunk_size=128) is None
+    assert spec.supports(*operands(dim=8), chunk_size=1024) \
+        == "tile:d8_dv8_chunk1024"
+    assert spec.supports(*operands(length=2000, chunk=100),
+                         chunk_size=100).startswith("tile:")
+    assert spec.supports(*operands(dtype=jnp.float16),
+                         chunk_size=1024).startswith("dtype:")
+    assert spec.supports(*operands(cs=jnp.bfloat16),
+                         chunk_size=1024) == "dtype:cs_bfloat16"
+    assert spec.supports(*operands(heads=12), chunk_size=1024).startswith(
+        "shape:")
+    assert spec.supports(*operands(), chunk_size=512).startswith("shape:")
+    # 65 offsets in groups of 13; tiles of 256 rows, 128 where they must be
+    assert (pair_span(128), row_tile(1024), row_tile(384)) == (13, 256, 128)
+
+    jpath = str(tmp_path / "journal.jsonl")
+    reset_journal(jpath)
+    try:
+        small = _retention_op_inputs(24, 4, 2, 8)
+        retention._power_retention(*small, chunk_size=8)
+        odd = _retention_op_inputs(200, 2, 1, 128)
+        retention._power_retention(*odd, chunk_size=100)
+    finally:
+        reset_journal(None)
+    prov = pallas.tier_provenance()["power_retention"]
+    assert prov["pallas"] == 0 and prov["fallback_reasons"] == {
+        "tile:d8_dv8_chunk8": 1, "tile:d128_dv128_chunk100": 1}
+    reasons = [r["reason"] for r in _journal_records(jpath)
+               if r.get("kind") == "pallas_fallback"
+               and r.get("kernel") == "power_retention"]
+    assert reasons == ["tile:d8_dv8_chunk8", "tile:d128_dv128_chunk100"]
+
+
+def test_traced_retention_counts_the_path_it_took(clean_tier, monkeypatch):
+    """``mxnet_tpu_power_retentions_traced_total{chunk,length,path}``: a
+    retention traced where a TPU is the backend stages the kernel (beside its
+    reference, for the lowering to choose) and counts ``kernel``; on the CPU,
+    or at a shape the kernel declines, it counts ``xla``."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import observability
+    from mxnet_tpu.ops import retention
+    from mxnet_tpu.pallas import registry
+
+    def counted():
+        return dict(observability.snapshot()["metrics"].get(
+            retention.RETENTION_COUNT_METRIC, {}).get("values", {}))
+
+    def trace(dim):
+        shapes = [(1, 256, 4, dim), (1, 256, 2, dim), (1, 256, 2, dim),
+                  (1, 256, 2)]
+        dtypes = [jnp.bfloat16] * 3 + [jnp.float32]
+        return str(jax.make_jaxpr(
+            lambda *a: retention._power_retention(*a, chunk_size=128))(
+                *[jax.ShapeDtypeStruct(s, d)
+                  for s, d in zip(shapes, dtypes)]))
+
+    before = counted()
+    assert "pallas_call" not in trace(128)          # the CPU: the reference
+    monkeypatch.setattr(registry, "_backend", lambda: "tpu")
+    text = trace(128)
+    assert "pallas_call" in text and "platform_index" in text
+    assert "pallas_call" not in trace(64)           # declined: half a tile
+    after = counted()
+
+    def grew(path):
+        key = f"chunk=128,length=256,path={path}"
+        return after.get(key, 0) - before.get(key, 0)
+
+    assert (grew("kernel"), grew("xla")) == (1, 2)
+    prov = pallas.tier_provenance()["power_retention"]
+    assert prov["pallas"] == 1 and prov["fallback_reasons"] == {
+        "backend:cpu": 1, "tile:d64_dv64_chunk128": 1}
