@@ -21,6 +21,14 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
+# Set-up stage `import` (docs/observability.md): from here to the last line
+# of this file. The observability package is stdlib-only; JAX is imported
+# inside the stage, further down.
+from .observability import instrument as _instrument
+
+_import_stage = _instrument.setup_stage("import")
+_import_stage.__enter__()
+
 # Multi-host: when launched by tools/launch.py (MXTPU_* env protocol), the
 # coordination service must be joined BEFORE any jax backend touch — do it
 # at package import, the earliest point we control (the kvstore would be
@@ -107,3 +115,6 @@ from . import rnn
 from . import attribute
 from .attribute import AttrScope
 from . import name
+
+_import_stage.__exit__(None, None, None)
+del _import_stage, _instrument

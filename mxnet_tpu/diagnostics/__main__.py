@@ -253,8 +253,13 @@ def _summ_aot(ar) -> str:
 
 
 def _summ_metrics(mt) -> str:
+    stages = (mt.get("setup") or {}).get("stages") or {}
+    slowest = sorted(stages.items(), key=lambda kv: -kv[1]["self_s"])[:3]
     return (f"metrics: {mt['families']} families, "
-            f"{int(mt.get('compiles_total', 0))} compiles")
+            f"{int(mt.get('compiles_total', 0))} compiles"
+            + ("; set-up: " + ", ".join(
+                f"{name} {st['self_s']:.1f}s" for name, st in slowest)
+               if slowest else ""))
 
 
 def _summ_lint(lt) -> str:

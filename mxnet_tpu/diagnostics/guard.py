@@ -199,7 +199,10 @@ def ensure_backend(deadline_s=None, tag=None) -> dict:
 
         timer = threading.Timer(deadline, _on_stall)
         timer.daemon = True
-        with j.phase("backend_dial"):
+        # set-up stage `backend_start` (docs/observability.md); the import
+        # waits for the dial: this package loads nothing of mxnet_tpu
+        from ..observability.instrument import setup_stage
+        with j.phase("backend_dial"), setup_stage("backend_start"):
             j.event("backend_dial_begin", tag=tag, deadline_s=deadline)
             timer.start()
             t0 = time.perf_counter()

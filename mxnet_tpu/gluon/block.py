@@ -32,7 +32,10 @@ from .. import _rng, autograd
 from .. import ndarray as nd
 from ..base import MXNetError, RECOMPUTE_KEEP, _as_np_dtype
 from ..context import Context, current_context
-from .parameter import (DeferredInitializationError, Parameter, ParameterDict)
+from ..observability import instrument as _obs
+from ..observability import stages as _stages
+from .parameter import (DEFERRED, DeferredInitializationError, Parameter,
+                        ParameterDict)
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock", "functional_apply"]
 
@@ -316,12 +319,21 @@ class Block:
 
     # -- execution -----------------------------------------------------------
     def __call__(self, *args, **kwargs):
+        if DEFERRED and _stages.current() != "deferred_shapes" \
+                and self._resolves_deferred():
+            # the eager call that resolves this net's deferred shapes
+            with _obs.setup_stage("deferred_shapes"):
+                return Block.__call__(self, *args, **kwargs)
         for hook in self._forward_pre_hooks:
             hook(self, args)
         out = self.forward(*args, **kwargs)
         for hook in self._forward_hooks:
             hook(self, args, out)
         return out
+
+    def _resolves_deferred(self):
+        return any(p._deferred_init
+                   for p in self.collect_params().values())
 
     def forward(self, *args, **kwargs):
         raise NotImplementedError
@@ -479,7 +491,7 @@ class HybridBlock(Block):
         pending = any(p._data is None
                       for p in self.collect_params().values())
         if pending:
-            with autograd.pause():
+            with _obs.setup_stage("deferred_shapes"), autograd.pause():
                 super().__call__(*args)
 
     def _build_fn(self, training, n_args, ctx):

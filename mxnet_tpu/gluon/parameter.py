@@ -10,6 +10,7 @@ is a *sharded* parameter on a mesh (see mxnet_tpu.parallel), not N copies.
 """
 from __future__ import annotations
 
+import weakref
 from collections import OrderedDict
 from typing import List, Optional
 
@@ -19,11 +20,17 @@ from .. import initializer as _init_mod
 from .. import ndarray as nd
 from ..base import MXNetError, _as_np_dtype, mx_real_t
 from ..context import Context, cpu, current_context
+from ..observability.instrument import setup_stage
 
 __all__ = ["DeferredInitializationError", "Parameter", "Constant",
            "ParameterDict", "tensor_types"]
 
 tensor_types = (nd.NDArray,)
+
+# Parameters whose initialization waits for a shape: empty in steady state,
+# so ``Block.__call__`` looks for a deferred shape of its own only while
+# some net in the process has one.
+DEFERRED = weakref.WeakSet()
 
 
 class DeferredInitializationError(MXNetError):
@@ -135,6 +142,7 @@ class Parameter:
                     f"cannot initialize {self.name}: shape {self.shape} is "
                     f"incomplete and allow_deferred_init=False")
             self._deferred_init = (init, default_init)
+            DEFERRED.add(self)
             return
         self._finish_init(init, default_init)
 
@@ -145,13 +153,15 @@ class Parameter:
         if isinstance(initializer, str):
             initializer = _init_mod.create(initializer)
         desc = _init_mod.InitDesc(self.name, attrs=dict(self._attrs))
-        data = nd.empty(self.shape, dtype=self.dtype, ctx=cpu())
-        initializer(desc, data)
-        self._data = [nd.NDArray(data._data, ctx=c, dtype=self.dtype)
-                      for c in self._ctx_list]
-        self._deferred_init = ()
-        if self.grad_req != "null":
-            self._init_grad()
+        with setup_stage("initialize"):
+            data = nd.empty(self.shape, dtype=self.dtype, ctx=cpu())
+            initializer(desc, data)
+            self._data = [nd.NDArray(data._data, ctx=c, dtype=self.dtype)
+                          for c in self._ctx_list]
+            self._deferred_init = ()
+            DEFERRED.discard(self)
+            if self.grad_req != "null":
+                self._init_grad()
 
     def _finish_deferred_init(self):
         if not self._deferred_init:
@@ -233,6 +243,7 @@ class Parameter:
             self._data = [nd.NDArray(data._data, ctx=c, dtype=self.dtype)
                           for c in self._ctx_list]
             self._deferred_init = ()
+            DEFERRED.discard(self)
             if self.grad_req != "null":
                 self._init_grad()
         else:
@@ -393,9 +404,10 @@ class ParameterDict:
                    force_reinit=False):
         if init is None:
             init = _init_mod.Uniform()
-        for param in self.values():
-            param.initialize(None, ctx, default_init=init,
-                             force_reinit=force_reinit)
+        with setup_stage("initialize"):
+            for param in self.values():
+                param.initialize(None, ctx, default_init=init,
+                                 force_reinit=force_reinit)
 
     def zero_grad(self):
         for param in self.values():

@@ -21,6 +21,10 @@ One substrate every subsystem records into:
 - :mod:`.scopes` — ``device_scopes()``: which HLO instruction of a live
   trainer's programs belongs to which ``jax.named_scope``, for joining
   with a profiler trace.
+- :mod:`.stages` — ``setup_report()``: set-up by stage (import,
+  initialize, deferred shapes, placement, each program's first call) with
+  JAX's own trace / lower / compile / cache events booked to the stage
+  that was open.
 
 Every journal record written inside a span carries ``trace_id``/
 ``span_id`` (the provider hook in diagnostics.journal), so the
@@ -33,7 +37,7 @@ wedged.
 from __future__ import annotations
 
 from . import (aggregate, export, flight, instrument, metrics, report,
-               scopes, trace)
+               scopes, stages, trace)
 from .aggregate import (aggregate_chrome, critical_path, scan_run_dir,
                         timeline_report)
 from .export import (chrome_trace_from_journal, export_chrome,
@@ -43,6 +47,7 @@ from .metrics import (Counter, Gauge, LatencySummary, MetricsRegistry,
                       Summary, default_registry, prometheus_text,
                       reset_metrics)
 from .scopes import device_scopes
+from .stages import setup_report
 from .trace import (SpanContext, Tracer, adopt_trace, annotate, configure,
                     current_context, current_ids, current_span, enabled,
                     event, get_tracer, identity, reset_tracer, span,
@@ -58,18 +63,19 @@ __all__ = [
     "export_chrome",
     "flight", "get_tracer", "identity", "install_from_env", "instrument",
     "metrics", "prometheus_text", "report", "reset_metrics",
-    "reset_tracer", "scan_run_dir", "scopes", "serve_metrics", "snapshot",
-    "span",
+    "reset_tracer", "scan_run_dir", "scopes", "serve_metrics",
+    "setup_report", "snapshot", "span", "stages",
     "start_span", "timeline_report", "to_chrome_trace", "trace",
 ]
 
 
 def snapshot() -> dict:
-    """One JSON-able telemetry snapshot: the full metrics registry plus
-    tracer accounting — the provenance block ``bench.py`` embeds in
-    BENCH artifacts (``"observability": ...``) and ``doctor --metrics``
-    reads back."""
+    """One JSON-able telemetry snapshot: the full metrics registry, the
+    set-up stages and tracer accounting — the provenance block
+    ``bench.py`` embeds in BENCH artifacts (``"observability": ...``) and
+    ``doctor --metrics`` reads back."""
     return {"metrics": default_registry().snapshot(),
+            "setup": setup_report(),
             "trace": get_tracer().stats()}
 
 

@@ -1,5 +1,5 @@
 """Trace CLI: ``python -m mxnet_tpu.observability
-dump|report|aggregate|timeline``.
+dump|report|setup|aggregate|timeline``.
 
 ``dump``       convert ONE JSONL journal's ``kind="span"`` records
                (written with ``MXNET_TPU_TRACE=journal``) to Chrome
@@ -7,6 +7,11 @@ dump|report|aggregate|timeline``.
                (ui.perfetto.dev → Open trace).
 ``report``     print the stdlib trace summary (``doctor --trace`` body)
                as one JSON line.
+``setup``      print the set-up stages as a table (where a slow start
+               went): from a metrics snapshot JSON (``--metrics``, a
+               dump of ``observability.snapshot()``) with JAX's events
+               outside every stage and the longest programs by name, or
+               from a journal's ``setup.<stage>`` spans (``--journal``).
 ``aggregate``  merge a POD RUN DIRECTORY (per-process journals +
                flight-recorder dumps, ``MXNET_TPU_TRACE_DIR`` during
                the run) into one anchor-aligned Perfetto trace — one
@@ -55,6 +60,13 @@ def main(argv=None) -> int:
     r = sub.add_parser("report", help="summarize journal span records; "
                                       "ONE JSON line on stdout")
     r.add_argument("--journal", required=True)
+    st = sub.add_parser("setup", help="set-up stages as a table, from a "
+                                      "metrics snapshot or a journal")
+    src = st.add_mutually_exclusive_group(required=True)
+    src.add_argument("--metrics", help="snapshot JSON "
+                     "(observability.snapshot() dump or a BENCH artifact)")
+    src.add_argument("--journal", help="JSONL journal of a run with "
+                     "MXNET_TPU_TRACE=journal")
     a = sub.add_parser("aggregate",
                        help="merge a pod run dir (per-process journals "
                             "+ flight dumps) into one Perfetto trace")
@@ -78,6 +90,25 @@ def main(argv=None) -> int:
             print(json.dumps({"ok": False, "error": str(e)}), flush=True)
             return 1
         _write_doc(doc, args.out)
+        return 0
+
+    if args.cmd == "setup":
+        if args.metrics:
+            rep = report.metrics_report(args.metrics)
+            setup = rep.get("setup") if rep.get("ok") else None
+            error = rep.get("error", "no set-up stages in the snapshot")
+        else:
+            try:
+                setup = report.setup_from_journal(args.journal)
+            except OSError as e:
+                setup, error = None, str(e)
+            else:
+                error = ("no setup.<stage> spans in journal (was "
+                         "MXNET_TPU_TRACE=journal set?)")
+        if not setup or not setup.get("stages"):
+            print(json.dumps({"ok": False, "error": error}), flush=True)
+            return 1
+        print(report.setup_table(setup), flush=True)
         return 0
 
     if args.cmd == "aggregate":

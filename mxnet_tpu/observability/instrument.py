@@ -13,6 +13,12 @@ commit protocol all record the same two shapes of signal:
   (``.step`` / ``.run_steps`` for the call), so a profiler session
   shows the phases on the clock of the device trace; with no session an
   annotation is a flag test in C++, so there is no switch for it;
+- **set-up stages** (``import``, ``initialize``, ``deferred_shapes``,
+  ``place``, ``build_step``, ``first_call{<program>}``, ``inspect``,
+  ``backend_start``): :func:`setup_stage`, built like a step phase and
+  always observed into ``mxnet_tpu_setup_stage_s{stage,time}``; JAX's own
+  trace / lower / compile / cache events are booked to the stage that was
+  open (:mod:`.stages`, ``observability.setup_report()``);
 - **compile events**: every jit-cache-miss site wraps its build in
   :func:`compile_span`, so XLA trace/lower/compile time lands in
   ``mxnet_tpu_xla_compiles_total{site}`` /
@@ -28,11 +34,12 @@ from __future__ import annotations
 import contextlib
 import time
 
-from . import trace
+from . import stages, trace
 from .metrics import default_registry
 
 __all__ = ["aot_load_span", "call_span", "compile_span", "device_scope",
-           "maybe_compile_span", "step_phase", "ANNOTATION_PREFIX",
+           "maybe_compile_span", "maybe_setup_stage", "setup_stage",
+           "step_phase", "ANNOTATION_PREFIX",
            "PHASE_METRIC", "COMPILE_COUNT_METRIC",
            "COMPILE_MS_METRIC", "AOT_LOAD_COUNT_METRIC",
            "AOT_LOAD_MS_METRIC"]
@@ -101,6 +108,37 @@ def step_phase(trainer, phase, **attrs):
         finally:
             _phase_summary().labels(trainer=trainer, phase=phase).observe(
                 (time.perf_counter() - t0) * 1000.0)
+
+
+@contextlib.contextmanager
+def setup_stage(stage, **attrs):
+    """One stage of set-up: always charged to the stage's books
+    (:mod:`.stages`: inclusive and self seconds, JAX's events while it is
+    the innermost stage open), annotated as ``mxnet_tpu.setup.<stage>`` for
+    a profiler session, traced as ``setup.<stage>`` when
+    ``MXNET_TPU_TRACE`` is on (the span carries ``self_s`` and what JAX
+    built inside). ``attrs`` name the stage's subject and are part of its
+    key: ``setup_stage("first_call", program="step")`` is
+    ``first_call{step}``. Opening the stage that is already innermost
+    does nothing."""
+    frame = stages.open_stage(stages.stage_key(stage, attrs))
+    if frame is None:
+        yield
+        return
+    name = "setup." + stage
+    with trace.span(name, **attrs) as sp, _annotation(name, **attrs):
+        try:
+            yield
+        finally:
+            sp.set_attrs(**stages.close_stage(frame))
+
+
+def maybe_setup_stage(pending, stage, **attrs):
+    """``setup_stage`` when ``pending`` (this call is the program's first),
+    else a null context."""
+    if pending:
+        return setup_stage(stage, **attrs)
+    return contextlib.nullcontext()
 
 
 @contextlib.contextmanager

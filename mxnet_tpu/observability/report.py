@@ -7,7 +7,11 @@ span/trace counts, per-name duration stats, the slowest spans.
 ``metrics_report`` reads a metrics snapshot back out of a JSON file —
 either a raw ``observability.snapshot()`` dump or a BENCH artifact
 carrying one under ``"observability"`` — and summarizes compile
-counts/times and step-phase percentiles.
+counts/times, step-phase percentiles and the set-up stages.
+``setup_table`` prints a ``setup_report()`` (from such a snapshot, or
+rebuilt from a journal's ``setup.<stage>`` spans by
+``setup_from_journal``) as the table an operator reads a slow start from
+(``python -m mxnet_tpu.observability setup``).
 
 Same contract as serving/guardrails reports: no jax, junk lines
 tolerated, always returns a dict with ``ok``.
@@ -16,7 +20,10 @@ from __future__ import annotations
 
 import json
 
-__all__ = ["metrics_report", "read_span_records", "trace_report"]
+from .stages import CACHE, PHASES, Books
+
+__all__ = ["metrics_report", "read_span_records", "setup_from_journal",
+           "setup_table", "trace_report"]
 
 
 def _iter_records(path):
@@ -149,4 +156,66 @@ def metrics_report(path) -> dict:
     phases = metrics.get("mxnet_tpu_step_phase_ms", {})
     if isinstance(phases.get("values"), dict):
         out["step_phase_ms"] = phases["values"]
+    if isinstance(obs, dict) and isinstance(obs.get("setup"), dict):
+        out["setup"] = obs["setup"]
     return out
+
+
+def setup_from_journal(path) -> dict:
+    """A ``setup_report()``-shaped dict rebuilt from the ``setup.<stage>``
+    spans of a journal (``MXNET_TPU_TRACE=journal``): the stages in the
+    order they first opened, with what each span carried (``self_s``,
+    JAX's seconds by phase, programs by what the cache did). What fell
+    outside every stage and the table by ``fun_name`` are not in a
+    journal. Raises OSError when the file is unreadable."""
+    spans = [r for r in read_span_records(path)
+             if str(r.get("name", "")).startswith("setup.")
+             and r.get("dur_s") is not None]
+    stages: dict = {}
+    for rec in sorted(spans, key=lambda r: r.get("start_s") or 0.0):
+        carried = rec.get("attrs") or {}
+        key = carried.get("stage") or rec["name"][len("setup."):]
+        books = stages.setdefault(key, Books())
+        books.count += 1
+        books.inclusive_s += float(rec["dur_s"])
+        books.self_s += float(carried.get("self_s", rec["dur_s"]))
+        for phase in PHASES:
+            books.jax_s[phase] += float(carried.get(phase + "_s", 0.0))
+        for cache in CACHE:
+            books.programs[cache] += int(carried.get("programs_" + cache, 0))
+    return {"stages": {key: books.to_dict()
+                       for key, books in stages.items()}}
+
+
+def setup_table(report) -> str:
+    """A ``setup_report()`` as text: a row a stage (in the order they first
+    opened) and one for ``outside``, then the longest rows of the table by
+    ``fun_name``."""
+    head = (f"{'stage':<28}{'n':>4}{'incl s':>9}{'self s':>9}{'trace':>8}"
+            f"{'lower':>8}{'compile':>8}{'load':>8}{'hit':>6}{'miss':>6}"
+            f"{'quick':>6}")
+
+    def row(name, st):
+        jax_s, progs = st.get("jax_s") or {}, st.get("programs") or {}
+        return (f"{name:<28}{st.get('count', 0):>4}"
+                f"{st.get('inclusive_s', 0.0):>9.3f}"
+                f"{st.get('self_s', 0.0):>9.3f}"
+                + "".join(f"{jax_s.get(p, 0.0):>8.3f}" for p in PHASES)
+                + "".join(f"{progs.get(c, 0):>6}" for c in CACHE))
+
+    lines = [head] + [row(name, st) for name, st
+                      in (report.get("stages") or {}).items()]
+    if report.get("outside"):
+        lines.append(row("(outside every stage)", report["outside"]))
+    rows = report.get("programs") or []
+    if rows:
+        lines += ["", f"{'program (fun_name)':<36}{'n':>4}{'trace':>8}"
+                      f"{'lower':>8}{'compile':>8}{'load':>8}{'hit':>5}"
+                      f"{'miss':>5}  first built in"]
+        for r in rows:
+            lines.append(
+                f"{str(r.get('fun_name'))[:35]:<36}{r.get('count', 0):>4}"
+                + "".join(f"{r.get(p + '_s', 0.0):>8.3f}" for p in PHASES)
+                + f"{r.get('hits', 0):>5}{r.get('misses', 0):>5}"
+                  f"  {r.get('stage')}")
+    return "\n".join(lines)

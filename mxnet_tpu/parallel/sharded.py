@@ -455,6 +455,13 @@ class ShardedTrainer(GuardedTrainerMixin):
             self._block._ensure_ready(tuple(
                 a if isinstance(a, nd.NDArray) else nd.array(a)
                 for a in args))
+        with _obs.setup_stage("place"):
+            self._place()
+        self._prepared = True
+
+    def _place(self):
+        """Parameters, aux state, optimizer state and guard counters onto
+        the mesh."""
         trainable, aux = self._block._param_split()
         self._trainable, self._aux = trainable, aux
         self._tr_specs = [self._param_spec(p) for p in trainable]
@@ -481,9 +488,9 @@ class ShardedTrainer(GuardedTrainerMixin):
         self._guard_state = tuple(
             self._shard(s, PartitionSpec())
             for s in _guard.init_guard_state())
-        self._prepared = True
 
     # -- the compiled step ---------------------------------------------------
+    @_obs.setup_stage("build_step")
     def _build_step(self, n_inputs):
         block, loss_block, opt = self._block, self._loss, self._optimizer
         wds = [opt._get_wd(i) for i in range(len(self._trainable))]
@@ -668,6 +675,8 @@ class ShardedTrainer(GuardedTrainerMixin):
             from .mesh import use_mesh
             # mesh-aware ops (ring attention) trace under use_mesh
             with _obs.step_phase("sharded_trainer", "compiled_step"), \
+                    _obs.maybe_setup_stage(compiling, "first_call",
+                                           program="step"), \
                     _obs.maybe_compile_span(compiling,
                                             "sharded_trainer.step",
                                             shapes=cshapes), \
@@ -717,9 +726,11 @@ class ShardedTrainer(GuardedTrainerMixin):
         self._prepare(batch[:-1])
         if self._step_fn is None:
             self._step_fn = self._build_step(len(batch) - 1)
-        return self._lower(
-            self._step_fn, 0,
-            [self._shard_batch_arg(b) for b in batch]).compile().as_text()
+        with _obs.setup_stage("inspect"):
+            return self._lower(
+                self._step_fn, 0,
+                [self._shard_batch_arg(b) for b in batch]
+            ).compile().as_text()
 
     def program_texts(self) -> dict:
         """``{"step": text, "run_steps(<k>)": text}``: optimized HLO of every
@@ -728,7 +739,8 @@ class ShardedTrainer(GuardedTrainerMixin):
         first called with. Every instruction's ``op_name`` metadata holds
         the ``jax.named_scope`` names it was traced under
         (``observability.device_scopes``). Takes no step, draws no key and
-        costs nothing until it is called."""
+        costs nothing until it is called. What it lowers and compiles is
+        booked to the set-up stage ``inspect``, not to a trainer's."""
         texts = {}
         for num_steps, batch in self._program_batches.items():
             if num_steps:
@@ -737,8 +749,9 @@ class ShardedTrainer(GuardedTrainerMixin):
             else:
                 name, fn = "step", self._step_fn
             if fn is not None:      # dropped by an AMP change or a new mesh
-                texts[name] = self._lower(
-                    fn, num_steps, batch).compile().as_text()
+                with _obs.setup_stage("inspect"):
+                    texts[name] = self._lower(
+                        fn, num_steps, batch).compile().as_text()
         return texts
 
     # -- guard bookkeeping: GuardedTrainerMixin (docs/guardrails.md) ----------
@@ -760,6 +773,38 @@ class ShardedTrainer(GuardedTrainerMixin):
             # the retraced program's dtype may have changed with it —
             # BEFORE the rebuild reads _compute_dtype/_scaler
             self._resolve_scaler()
+
+    @_obs.setup_stage("build_step")
+    def _build_multi(self, num_steps):
+        """The program of ``num_steps`` steps (``lax.scan`` over the step
+        body), jitted with the step's shardings."""
+        raw = self._raw_step
+        in_sh, out_sh, donate = self._shardings
+        rep_sh = out_sh[4]
+
+        def multi(tr, aux, states, gstate, root, scalars, *b):
+            root, rng = jax.random.split(root)  # as the step's program
+            (t, rescale, lscale), lrs = scalars[:3], scalars[3:]
+
+            # lrs: (num_steps,) host-evaluated schedule — each inner
+            # step sees the SAME lr a separate step() call would
+            def body(carry, i):
+                tr_, aux_, states_, gs_, t_ = carry
+                k = jax.random.fold_in(rng, i)
+                ntr, naux, nst, gs2, loss, (fin, gn), _ = raw(
+                    tr_, aux_, states_, gs_, k, lrs[i], t_, rescale,
+                    lscale, *b)
+                return (ntr, naux, nst, gs2, t_ + 1.0), (loss, fin, gn)
+
+            (tr, aux, states, gstate, _), (losses, fins, gns) = \
+                jax.lax.scan(body, (tr, aux, states, gstate, t),
+                             jnp.arange(num_steps))
+            return (tr, aux, states, gstate, losses, fins, gns,
+                    losses[-1], jax.random.key_data(root))
+
+        return jax.jit(multi, in_shardings=in_sh,
+                       out_shardings=out_sh[:4] + (rep_sh,) * 5,
+                       donate_argnums=donate)
 
     def run_steps(self, *batch, num_steps=8):
         """Run ``num_steps`` train steps as ONE compiled program
@@ -784,34 +829,7 @@ class ShardedTrainer(GuardedTrainerMixin):
             self._multi_fns = {}
         compiling = key not in self._multi_fns
         if compiling:
-            raw = self._raw_step
-            in_sh, out_sh, donate = self._shardings
-            rep_sh = out_sh[4]
-
-            def multi(tr, aux, states, gstate, root, scalars, *b):
-                root, rng = jax.random.split(root)  # as the step's program
-                (t, rescale, lscale), lrs = scalars[:3], scalars[3:]
-
-                # lrs: (num_steps,) host-evaluated schedule — each inner
-                # step sees the SAME lr a separate step() call would
-                def body(carry, i):
-                    tr_, aux_, states_, gs_, t_ = carry
-                    k = jax.random.fold_in(rng, i)
-                    ntr, naux, nst, gs2, loss, (fin, gn), _ = raw(
-                        tr_, aux_, states_, gs_, k, lrs[i], t_, rescale,
-                        lscale, *b)
-                    return (ntr, naux, nst, gs2, t_ + 1.0), (loss, fin, gn)
-
-                (tr, aux, states, gstate, _), (losses, fins, gns) = \
-                    jax.lax.scan(body, (tr, aux, states, gstate, t),
-                                 jnp.arange(num_steps))
-                return (tr, aux, states, gstate, losses, fins, gns,
-                        losses[-1], jax.random.key_data(root))
-
-            self._multi_fns[key] = jax.jit(
-                multi, in_shardings=in_sh,
-                out_shardings=out_sh[:4] + (rep_sh,) * 5,
-                donate_argnums=donate)
+            self._multi_fns[key] = self._build_multi(num_steps)
         t = self._num_update + 1
         self._num_update += num_steps
         with _obs.call_span("sharded_trainer", "run_steps", start_step=t,
@@ -839,6 +857,9 @@ class ShardedTrainer(GuardedTrainerMixin):
                     self._optimizer.rescale_grad, self._loss_scale())
             from .mesh import use_mesh
             with _obs.step_phase("sharded_trainer", "compiled_step"), \
+                    _obs.maybe_setup_stage(
+                        compiling, "first_call",
+                        program=f"run_steps({num_steps})"), \
                     _obs.maybe_compile_span(compiling,
                                             "sharded_trainer.run_steps",
                                             num_steps=num_steps,
@@ -863,7 +884,8 @@ class ShardedTrainer(GuardedTrainerMixin):
         args = batch[:-1]
         self._prepare(args)
         self._maybe_invalidate_amp()
-        if self._eval_fn is None:
+        compiling = self._eval_fn is None
+        if compiling:
             block, loss_block = self._block, self._loss
 
             def eval_step(tr, aux, key, *b):
@@ -881,8 +903,10 @@ class ShardedTrainer(GuardedTrainerMixin):
         batch_datas = [self._shard_batch_arg(b) for b in batch]
         tr = [p._data[0]._data for p in self._trainable]
         aux = [p._data[0]._data for p in self._aux]
-        loss_val, outs = self._eval_fn(tr, aux, _rng.next_key(),
-                                       *batch_datas)
+        with _obs.maybe_setup_stage(compiling, "first_call",
+                                    program="evaluate"):
+            loss_val, outs = self._eval_fn(tr, aux, _rng.next_key(),
+                                           *batch_datas)
         self.last_outputs = [nd.NDArray(o, _skip_device_put=True)
                              for o in outs]
         return nd.NDArray(loss_val, _skip_device_put=True)

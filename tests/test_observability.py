@@ -20,7 +20,8 @@ import mxnet_tpu as mx
 from mxnet_tpu import gluon, io, observability, parallel, sym
 from mxnet_tpu.diagnostics import journal
 from mxnet_tpu.guardrails import GuardConfig
-from mxnet_tpu.observability import export, instrument, metrics, trace
+from mxnet_tpu.observability import (export, instrument, metrics, stages,
+                                     trace)
 from mxnet_tpu.observability.report import metrics_report, trace_report
 from mxnet_tpu.serving import Server, ServerConfig
 from mxnet_tpu.testing import faults
@@ -321,7 +322,9 @@ def test_smoke_traced_training_step_perfetto_export(tmp_path, ring):
             "ckpt_commit"} <= names
     _containment(doc, "sharded_trainer.compiled_step",
                  "sharded_trainer.step")
-    _containment(doc, "xla_compile", "sharded_trainer.compiled_step")
+    # the program's first call is a set-up stage, which nests the compile
+    _containment(doc, "setup.first_call", "sharded_trainer.compiled_step")
+    _containment(doc, "xla_compile", "setup.first_call")
     # exactly one compile event for two same-shape steps
     compiles = [e for e in doc["traceEvents"] if e["name"] == "xla_compile"]
     assert len(compiles) == 1
@@ -845,6 +848,10 @@ def test_observability_cli_dump_and_report(tmp_path):
                MXNET_TPU_JOURNAL=jf, MXNET_TPU_TRACE="journal")
     r = subprocess.run([sys.executable, "-c", code], env=env, timeout=240)
     assert r.returncode == 0
+    # the tools read files: run with tracing on they would journal their
+    # own import beside the run's spans
+    env = dict(__import__('os').environ, JAX_PLATFORMS="cpu")
+    env.pop("MXNET_TPU_TRACE", None)
     r = subprocess.run(
         [sys.executable, "-m", "mxnet_tpu.observability", "dump",
          "--journal", jf, "--out", out],
@@ -853,12 +860,357 @@ def test_observability_cli_dump_and_report(tmp_path):
     with open(out, encoding="utf-8") as f:
         doc = json.load(f)
     _assert_chrome_doc(doc)
-    assert {e["name"] for e in doc["traceEvents"]} == {"cli_root",
-                                                       "cli_child"}
+    # importing the package is a set-up stage, so a span of the journal
+    assert {e["name"] for e in doc["traceEvents"]} == {
+        "setup.import", "cli_root", "cli_child"}
     r = subprocess.run(
         [sys.executable, "-m", "mxnet_tpu.observability", "report",
          "--journal", jf],
         env=env, capture_output=True, text=True, timeout=240)
     assert r.returncode == 0, r.stderr
     rep = json.loads(r.stdout.strip().splitlines()[-1])
-    assert rep["ok"] and rep["spans"] == 2
+    assert rep["ok"] and rep["spans"] == 3
+    r = subprocess.run(
+        [sys.executable, "-m", "mxnet_tpu.observability", "setup",
+         "--journal", jf],
+        env=env, capture_output=True, text=True, timeout=240)
+    assert r.returncode == 0, r.stderr
+    table = r.stdout.splitlines()
+    assert table[0].split()[:2] == ["stage", "n"]
+    assert table[1].split()[:2] == ["import", "1"]
+
+
+# -- set-up stages (observability/stages.py) ----------------------------------
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+@pytest.fixture
+def fresh_stages():
+    stages.reset()
+    yield
+    stages.reset()
+
+
+def test_setup_stages_nest_and_self_time_excludes_the_children(fresh_stages):
+    import time
+    with instrument.setup_stage("place"):
+        time.sleep(0.02)
+        with instrument.setup_stage("initialize"):
+            with instrument.setup_stage("initialize"):    # re-entered: no-op
+                assert stages.current() == "initialize"
+                time.sleep(0.03)
+        assert stages.current() == "place"
+    assert stages.current() is None
+    rep = observability.setup_report()["stages"]
+    assert [k for k in rep if k != "import"] == ["place", "initialize"]
+    place, init = rep["place"], rep["initialize"]
+    assert place["count"] == init["count"] == 1
+    assert init["inclusive_s"] == pytest.approx(init["self_s"])
+    assert init["self_s"] >= 0.03
+    assert place["inclusive_s"] >= 0.05
+    assert place["self_s"] == pytest.approx(
+        place["inclusive_s"] - init["inclusive_s"])
+    # the registry family is fed, tracing off, and no span was made
+    assert trace.mode() == "off" and trace.get_tracer().spans() == []
+    values = metrics.snapshot()[stages.STAGE_METRIC]["values"]
+    assert values["stage=place,time=inclusive"]["count"] == 1
+    assert values["stage=initialize,time=self"]["sum"] == pytest.approx(
+        init["self_s"], abs=1e-3)
+
+
+def test_setup_stage_is_a_span_with_what_it_held_when_tracing(fresh_stages,
+                                                             ring):
+    from jax import monitoring
+    with instrument.setup_stage("first_call", program="step"):
+        monitoring.record_event_duration_secs(TRACE_EVENT, 0.5,
+                                              fun_name="step")
+    (span,) = ring.spans()
+    assert span["name"] == "setup.first_call"
+    assert span["attrs"]["program"] == "step"
+    assert span["attrs"]["stage"] == "first_call{step}"
+    assert span["attrs"]["trace_s"] == 0.5
+    assert 0 <= span["attrs"]["self_s"] <= span["dur_s"] + 1e-6
+
+
+def test_jax_events_book_to_the_innermost_stage_or_outside(fresh_stages):
+    from jax import monitoring
+    monitoring.record_event_duration_secs(COMPILE_EVENT, 0.25,
+                                          fun_name="jit(loose)")
+    with instrument.setup_stage("place"):
+        monitoring.record_event_duration_secs(LOWER_EVENT, 1.0,
+                                              fun_name="jit(put)")
+        with instrument.setup_stage("first_call", program="step"):
+            # as JAX raises them: a helper traced inside the step's trace
+            monitoring.record_scalar(TRACE_EVENT, 0.0, fun_name="step")
+            monitoring.record_scalar(TRACE_EVENT, 0.0, fun_name="helper")
+            monitoring.record_event_duration_secs(TRACE_EVENT, 1.5,
+                                                  fun_name="helper")
+            monitoring.record_event_duration_secs(TRACE_EVENT, 4.0,
+                                                  fun_name="step")
+            monitoring.record_event_duration_secs(COMPILE_EVENT, 2.0,
+                                                  fun_name="jit(step)")
+    rep = observability.setup_report()
+    first = rep["stages"]["first_call{step}"]
+    assert first["jax_s"] == {"trace": 4.0, "lower": 0.0, "compile": 2.0,
+                              "cache_load": 0.0}
+    assert first["programs"] == {"hit": 0, "miss": 0, "uncached": 1}
+    assert rep["stages"]["place"]["jax_s"]["lower"] == 1.0
+    assert rep["stages"]["place"]["programs"]["uncached"] == 0
+    assert rep["outside"]["jax_s"]["compile"] == 0.25
+    assert rep["outside"]["programs"]["uncached"] == 1
+    rows = {r["fun_name"]: r for r in rep["programs"]}
+    assert rows["step"]["trace_s"] == 2.5 and rows["helper"]["trace_s"] == 1.5
+    assert rows["step"]["compile_s"] == 2.0 and rows["step"]["count"] == 1
+    assert rows["step"]["stage"] == "first_call{step}"
+    assert rows["loose"]["stage"] == "outside"
+    assert rep["programs"][0]["fun_name"] == "step"     # longest first
+    snap = metrics.snapshot()
+    assert snap[stages.PROGRAM_S_METRIC]["values"][
+        "stage=first_call{step},phase=trace"] == 4.0
+    assert snap[stages.PROGRAMS_METRIC]["values"][
+        "stage=outside,cache=uncached"] == 1.0
+
+
+def test_a_real_jit_is_booked_to_the_stage_it_ran_in(fresh_stages):
+    import jax
+    import jax.numpy as jnp
+
+    def fresh_function_of_this_test(x):
+        return jnp.tanh(x) * 3.0 + 1.0
+
+    with instrument.setup_stage("first_call", program="fresh"):
+        jax.jit(fresh_function_of_this_test)(jnp.ones((3, 5)))
+    jax.jit(lambda x: x - 2.5)(jnp.ones((3, 5)))
+    rep = observability.setup_report()
+    stage = rep["stages"]["first_call{fresh}"]
+    assert sum(stage["programs"].values()) >= 1
+    assert stage["jax_s"]["trace"] > 0 and stage["jax_s"]["lower"] > 0
+    assert stage["jax_s"]["compile"] + stage["jax_s"]["cache_load"] > 0
+    # the phases of a stage are parts of its wall time
+    assert sum(stage["jax_s"].values()) <= stage["inclusive_s"] + 1e-3
+    rows = {r["fun_name"]: r for r in observability.setup_report(
+        top=stages.MAX_NAMES)["programs"]}
+    row = rows["fresh_function_of_this_test"]
+    assert row["stage"] == "first_call{fresh}" and row["count"] == 1
+    assert rows["<lambda>"]["stage"] == "outside"
+    assert sum(rep["outside"]["programs"].values()) >= 1
+
+
+def test_cache_hit_miss_and_uncached_are_told_apart(fresh_stages, tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    keys = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in keys}
+
+    def fresh():
+        # a new function each time, so that JAX's in-memory caches miss
+        # and the persistent cache is asked: same name, same program
+        def cached_function_of_this_test(x):
+            return jnp.sin(x) @ jnp.cos(x).T + 7.0
+        return jax.jit(cached_function_of_this_test)
+
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        cc.reset_cache()
+        x = jnp.ones((4, 6))
+        # too quick to be written: built, and not a miss
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e6)
+        with instrument.setup_stage("first_call", program="quick"):
+            fresh()(x)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        with instrument.setup_stage("first_call", program="cold"):
+            fresh()(x)
+        with instrument.setup_stage("first_call", program="warm"):
+            fresh()(x)
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    rep = observability.setup_report()["stages"]
+    assert rep["first_call{quick}"]["programs"] == {
+        "hit": 0, "miss": 0, "uncached": 1}
+    assert rep["first_call{cold}"]["programs"] == {
+        "hit": 0, "miss": 1, "uncached": 0}
+    warm = rep["first_call{warm}"]
+    assert warm["programs"] == {"hit": 1, "miss": 0, "uncached": 0}
+    assert warm["jax_s"]["cache_load"] > 0 and warm["jax_s"]["compile"] == 0
+    assert rep["first_call{cold}"]["jax_s"]["cache_load"] == 0
+    (row,) = [r for r in observability.setup_report()["programs"]
+              if r["fun_name"] == "cached_function_of_this_test"]
+    assert (row["count"], row["hits"], row["misses"]) == (3, 1, 1)
+    assert row["stage"] == "first_call{quick}"
+
+
+def test_the_table_by_fun_name_is_bounded(fresh_stages):
+    from jax import monitoring
+    for i in range(stages.MAX_NAMES + 40):
+        monitoring.record_event_duration_secs(COMPILE_EVENT, 0.001,
+                                              fun_name=f"jit(f{i})")
+    rep = observability.setup_report(top=10 * stages.MAX_NAMES)
+    assert rep["names"] == stages.MAX_NAMES + 1
+    rows = {r["fun_name"]: r for r in rep["programs"]}
+    assert rows[stages.OTHER]["count"] == 40
+    assert rep["outside"]["programs"]["uncached"] == stages.MAX_NAMES + 40
+    assert len(observability.setup_report(top=5)["programs"]) == 5
+
+
+def test_stages_of_many_threads_lose_no_update(fresh_stages):
+    """More threads than cores open stages and raise JAX's events at once:
+    each thread's events go to its own innermost stage, and no count or
+    second is lost."""
+    import sys
+    from jax import monitoring
+    threads, turns = 24, 150
+    failed = []
+
+    def work(i):
+        mine = f"first_call{{t{i}}}"
+        try:
+            for _ in range(turns):
+                with instrument.setup_stage("place"):
+                    with instrument.setup_stage("first_call",
+                                                program=f"t{i}"):
+                        assert stages.current() == mine
+                        monitoring.record_event_duration_secs(
+                            COMPILE_EVENT, 0.5, fun_name="jit(shared)")
+                    monitoring.record_event_duration_secs(
+                        LOWER_EVENT, 0.25, fun_name="jit(shared)")
+        except Exception as e:      # reported by the main thread
+            failed.append(repr(e))
+
+    before = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        pool = [threading.Thread(target=work, args=(i,))
+                for i in range(threads)]
+        for t in pool:
+            t.start()
+        for t in pool:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(before)
+    assert not failed and not any(t.is_alive() for t in pool)
+    rep = observability.setup_report(top=5)
+    place = rep["stages"]["place"]
+    assert place["count"] == threads * turns
+    assert place["jax_s"]["lower"] == pytest.approx(0.25 * threads * turns)
+    assert place["programs"]["uncached"] == 0
+    for i in range(threads):
+        own = rep["stages"][f"first_call{{t{i}}}"]
+        assert own["count"] == turns
+        assert own["programs"]["uncached"] == turns
+        assert own["jax_s"]["compile"] == pytest.approx(0.5 * turns)
+    (row,) = [r for r in rep["programs"] if r["fun_name"] == "shared"]
+    assert row["count"] == threads * turns
+    assert sum(rep["outside"]["programs"].values()) == 0
+
+
+def test_sharded_trainer_leaves_its_stages_in_order(fresh_stages):
+    """import, initialize, deferred_shapes, place, build_step and the first
+    call, in the order they opened; made under the zero-device-read
+    contract; ``program_texts()`` is booked to ``inspect``."""
+    import jax
+    assert trace.mode() == "off"
+    net = gluon.nn.HybridSequential()
+    with net.name_scope():
+        net.add(gluon.nn.Dense(16, activation="relu"))     # shape deferred
+        net.add(gluon.nn.Dense(4))
+    rng = np.random.RandomState(0)
+    x = rng.randn(16, 8).astype(np.float32)
+    y = rng.randint(0, 4, (16,))
+    with jax.transfer_guard_device_to_host("disallow"):
+        net.initialize()
+        tr = parallel.ShardedTrainer(
+            net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+            optimizer_params={"learning_rate": 0.1},
+            mesh=parallel.make_mesh({"data": -1}))
+        tr.step(x, y)
+        tr.step(x, y)
+        tr.run_steps(x, y, num_steps=2)
+    rep = observability.setup_report()
+    named = ["import", "initialize", "deferred_shapes", "place",
+             "build_step", "first_call{step}", "first_call{run_steps(2)}"]
+    assert [k for k in rep["stages"] if k in named] == named
+    assert trace.get_tracer().spans() == []
+    s = rep["stages"]
+    assert s["import"]["count"] == 1 and s["import"]["inclusive_s"] > 0
+    # the deferred parameters were initialized inside the shape pass
+    assert s["deferred_shapes"]["count"] == 1
+    assert s["deferred_shapes"]["self_s"] < s["deferred_shapes"]["inclusive_s"]
+    assert s["place"]["count"] == 1 and s["build_step"]["count"] == 2
+    assert s["first_call{step}"]["count"] == 1      # the second step: none
+    assert s["first_call{step}"]["programs"]["uncached"] >= 1
+    assert s["first_call{step}"]["jax_s"]["trace"] > 0
+    assert "inspect" not in s
+    before = sum(s["first_call{step}"]["jax_s"].values())
+    assert set(tr.program_texts()) == {"step", "run_steps(2)"}
+    after = observability.setup_report()["stages"]
+    assert after["inspect"]["count"] == 2
+    assert sum(after["first_call{step}"]["jax_s"].values()) == before
+    assert observability.snapshot()["setup"]["stages"].keys() == after.keys()
+
+
+def test_an_eager_call_that_resolves_shapes_is_the_same_stage(fresh_stages):
+    from mxnet_tpu.gluon import parameter
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(6), gluon.nn.Dense(2, in_units=6))
+    net.initialize()
+    assert len(parameter.DEFERRED) >= 1
+    net(mx.nd.ones((3, 5)))                 # resolves the first layer's
+    rep = observability.setup_report()["stages"]
+    assert rep["deferred_shapes"]["count"] == 1
+    assert not [p for p in net.collect_params().values()
+                if p in parameter.DEFERRED]
+    net(mx.nd.ones((3, 5)))                 # nothing left to resolve
+    assert observability.setup_report()["stages"][
+        "deferred_shapes"]["count"] == 1
+
+
+def test_setup_table_and_the_journal_say_the_same(fresh_stages, jfile):
+    from mxnet_tpu.observability.report import (setup_from_journal,
+                                                setup_table)
+    trace.configure(mode="journal")
+    tr, x, y = _sharded()
+    tr.step(x, y)
+    live = observability.setup_report()
+    told = setup_from_journal(jfile)["stages"]
+    mine = {k: v for k, v in live["stages"].items() if k != "import"}
+    assert list(told) == list(mine)
+    for key, stage in mine.items():
+        assert told[key]["count"] == stage["count"]
+        assert told[key]["self_s"] == pytest.approx(stage["self_s"],
+                                                    abs=1e-4)
+        assert told[key]["programs"] == stage["programs"]
+    table = setup_table(live).splitlines()
+    assert table[0].split()[0] == "stage"
+    assert [line.split()[0] for line in table[1:1 + len(live["stages"])]] \
+        == list(live["stages"])
+    assert any(line.startswith("step ") for line in table)
+
+
+def test_observability_imports_without_jax():
+    """No module of the package imports JAX when it is loaded: the
+    exporters must work while everything else is wedged."""
+    import ast
+    import os
+    here = os.path.dirname(observability.__file__)
+    for name in sorted(os.listdir(here)):
+        if not name.endswith(".py"):
+            continue
+        with open(os.path.join(here, name), encoding="utf-8") as f:
+            tree = ast.parse(f.read())
+        for node in tree.body:          # module level only
+            names = []
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom) and node.level == 0:
+                names = [node.module or ""]
+            assert not [n for n in names if n.split(".")[0] == "jax"], name
