@@ -13,7 +13,8 @@ op; these are new TPU-side capability (ROADMAP R6).
   ``mamba2_ssd`` (``pallas/ssd.py``): one fused pass over the chunks on a
   TPU, the ``jax.numpy`` scan elsewhere;
 - ``_contrib_gated_rms_norm``: ``RMSNorm(y * silu(z))`` behind the scan, over
-  all channels or over each of ``groups`` runs of them;
+  all channels or over each of ``groups`` runs of them (those as products
+  with the 0/1 group matrix, so that nothing is viewed by group);
 - ``_contrib_swiglu``: ``silu(g) * u`` over the two halves of the last axis.
 
 But for that kernel all four are plain ``jax.numpy`` / ``lax`` that XLA
@@ -32,6 +33,7 @@ from ..base import MXNetError
 from .registry import OpParam, register
 
 SCAN_COUNT_METRIC = "mxnet_tpu_ssd_scans_traced_total"
+NORM_COUNT_METRIC = "mxnet_tpu_gated_norms_traced_total"
 
 _F32 = jnp.float32
 
@@ -46,6 +48,18 @@ def _count_traced_scan(chunk, length, path):
         SCAN_COUNT_METRIC, "chunked state-space scans traced into a program",
         ("chunk", "length", "path")).labels(
             chunk=str(chunk), length=str(length), path=path).inc()
+
+
+def _count_traced_norm(groups, channels):
+    """One gated norm traced into a program, by groups, channels and form
+    (``plain``: one group; ``grouped``: the product form): trace-time only,
+    as the scans are counted."""
+    from ..observability.metrics import default_registry
+    default_registry().counter(
+        NORM_COUNT_METRIC, "gated RMS norms traced into a program",
+        ("groups", "channels", "form")).labels(
+            groups=str(groups), channels=str(channels),
+            form="plain" if groups == 1 else "grouped").inc()
 
 
 def _act(x, act_type):
@@ -123,16 +137,28 @@ def _mamba2_ssd(x, dt, a_log, b, c, d, dt_bias, chunk_size=256):
               "computed in float32, returned in y's dtype.")
 def _gated_rms_norm(y, z, gamma, eps=1e-5, groups=1):
     g = y.astype(_F32) * jax.nn.silu(z.astype(_F32))
+    channels = g.shape[-1]
+    if isinstance(g, jax.core.Tracer):
+        _count_traced_norm(groups, channels)
     if groups == 1:
         ms = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
         normed = g * lax.rsqrt(ms + eps)
     else:
-        if g.shape[-1] % groups:
-            raise MXNetError(f"gated_rms_norm: {g.shape[-1]} channels are no "
+        if channels % groups:
+            raise MXNetError(f"gated_rms_norm: {channels} channels are no "
                              f"multiple of groups {groups}")
-        by_group = g.reshape(g.shape[:-1] + (groups, -1))
-        ms = jnp.mean(jnp.square(by_group), axis=-1, keepdims=True)
-        normed = (by_group * lax.rsqrt(ms + eps)).reshape(g.shape)
+        # group sums and per-channel scale as float32 products with the
+        # (C, groups) 0/1 matrix of "channel c is in group j", so that g
+        # keeps its (..., C) layout: a (..., groups, C / groups) view puts
+        # the groups on a TPU's sublanes and costs a broadcast and a
+        # reshape of the whole array (PERF.md sec. 5.1, PR 37). HIGHEST:
+        # the default precision rounds g^2 to bf16.
+        member = jnp.repeat(jnp.eye(groups, dtype=_F32), channels // groups,
+                            axis=0)
+        ms = jnp.matmul(jnp.square(g), member,
+                        precision=lax.Precision.HIGHEST) / (channels // groups)
+        normed = g * jnp.matmul(lax.rsqrt(ms + eps), member.T,
+                                precision=lax.Precision.HIGHEST)
     return (normed * gamma.astype(_F32)).astype(y.dtype)
 
 

@@ -357,3 +357,55 @@ def test_retention_kernel_compiles_at_the_cells_shapes(one_chip, monkeypatch,
     assert text.count("tpu_custom_call") == kernels
     assert " while(" not in text
     assert pallas.tier_provenance()["power_retention"]["pallas"] - before == 1
+
+
+def _relayouts(text, elements):
+    """The entry computation's ``copy``, ``reshape``, ``broadcast`` and
+    ``transpose`` instructions whose result has ``elements`` or more."""
+    import re
+    entry = text[text.index("\nENTRY"):]
+    found = []
+    for dims, kind in re.findall(
+            r"= \w+\[([\d,]*)\]\S* (copy|reshape|broadcast|transpose)\(",
+            entry):
+        size = 1
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        if size >= elements:
+            found.append(kind)
+    return found
+
+
+@pytest.mark.parametrize("form", ["products", "reshaped"])
+def test_grouped_norm_at_nemotrons_shape_relays_out_nothing(one_chip, form):
+    """``_contrib_gated_rms_norm(groups=8)`` at one Nemotron layer's
+    ``(1, 8192, 4096)``, forward and backward: the product form leaves no
+    re-layout of an ``L x C`` array in the program; the reshape form it
+    replaced (PR 37) leaves several, which is what this test would see
+    again."""
+    from jax import lax
+    from mxnet_tpu.ops import ssm
+    length, channels, groups = 8192, 4096, 8
+
+    def reshaped(y, z, gamma):
+        g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        by_group = g.reshape(g.shape[:-1] + (groups, -1))
+        ms = jnp.mean(jnp.square(by_group), axis=-1, keepdims=True)
+        return ((by_group * lax.rsqrt(ms + 1e-5)).reshape(g.shape)
+                * gamma).astype(y.dtype)
+
+    norm = reshaped if form == "reshaped" else (
+        lambda *a: ssm._gated_rms_norm(*a, groups=groups))
+
+    def forward_backward(y, z, gamma, cotangent):
+        return jax.vjp(norm, y, z, gamma)[1](cotangent)
+
+    row = jax.ShapeDtypeStruct((1, length, channels), jnp.bfloat16,
+                               sharding=one_chip)
+    gamma = jax.ShapeDtypeStruct((channels,), jnp.float32, sharding=one_chip)
+    found = _relayouts(_compile(forward_backward, row, row, gamma,
+                                row).as_text(), length * channels)
+    if form == "products":
+        assert found == []
+    else:
+        assert {"copy", "reshape", "broadcast"} <= set(found)

@@ -84,6 +84,108 @@ def test_one_group_is_to_the_bit_what_the_norm_gave_before(dtype):
                           np.asarray(before))
 
 
+def reshaped_norm(y, z, gamma, groups, eps=1e-5):
+    """The grouped norm as it was until PR 37: ``g`` viewed ``(..., groups,
+    C / groups)``, each group's mean square taken over its last axis."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    by_group = g.reshape(g.shape[:-1] + (groups, -1))
+    ms = jnp.mean(jnp.square(by_group), axis=-1, keepdims=True)
+    normed = (by_group * lax.rsqrt(ms + eps)).reshape(g.shape)
+    return (normed * gamma.astype(jnp.float32)).astype(y.dtype)
+
+
+def norm_inputs(width, dtype, seed=2):
+    rng = np.random.default_rng(seed)
+    y, z, cotangent = (jnp.asarray(rng.standard_normal((2, 6, width)), dtype)
+                       for _ in range(3))
+    gamma = jnp.asarray(rng.standard_normal(width), jnp.float32)
+    return y, z, gamma, cotangent
+
+
+def equations(jaxpr):
+    """Every equation of a closed jaxpr, those of nested jaxprs too."""
+    for eqn in jaxpr.jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            inners = param if isinstance(param, (list, tuple)) else [param]
+            for inner in inners:
+                if hasattr(inner, "jaxpr") and hasattr(inner.jaxpr, "eqns"):
+                    yield from equations(inner)
+
+
+@pytest.mark.parametrize("width", [64, 4096])
+def test_grouped_products_are_the_reshaped_norm_in_float32(width):
+    y, z, gamma, cotangent = norm_inputs(width, jnp.float32)
+    assert close(ssm._gated_rms_norm(y, z, gamma, groups=8),
+                 np.asarray(reshaped_norm(y, z, gamma, 8)), 1e-6)
+
+    def weighed(norm):
+        return lambda *a: jnp.sum(norm(*a) * cotangent)
+
+    got = jax.grad(weighed(lambda *a: ssm._gated_rms_norm(*a, groups=8)),
+                   (0, 1, 2))(y, z, gamma)
+    want = jax.grad(weighed(lambda *a: reshaped_norm(*a, 8)),
+                    (0, 1, 2))(y, z, gamma)
+    for g, w in zip(got, want):
+        assert close(g, np.asarray(w), 1e-6)
+
+
+def test_grouped_products_in_bf16_are_within_a_spacing_of_the_reshaped_norm():
+    y, z, gamma, _ = norm_inputs(4096, jnp.bfloat16)
+    got = np.asarray(ssm._gated_rms_norm(y, z, gamma, groups=8), np.float32)
+    want = np.asarray(reshaped_norm(y, z, gamma, 8), np.float32)
+    # bf16 keeps 8 significant bits: the spacing of a value in [2^e, 2^e+1)
+    spacing = 2.0 ** (np.floor(np.log2(np.abs(want) + 1e-30)) - 7)
+    assert (np.abs(got - want) <= spacing).all()
+    assert (got != want).mean() < 0.01
+
+
+def test_grouped_products_view_nothing_by_group_and_run_at_highest():
+    """No reshape of an ``L x C`` array, forward or backward, and every
+    product at ``Precision.HIGHEST`` (the default rounds ``g^2`` to bf16 on
+    a TPU)."""
+    y, z, gamma, cotangent = norm_inputs(4096, jnp.bfloat16)
+    program = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(ssm._gated_rms_norm(*a, groups=8) * cotangent),
+        (0, 1, 2)))(y, z, gamma)
+    found = list(equations(program))
+    assert not [e for e in found if e.primitive.name == "reshape"
+                and e.outvars[0].aval.size >= y.size]
+    products = [e for e in found if e.primitive.name == "dot_general"]
+    assert len(products) == 4       # two forward, their two transposes
+    for e in products:
+        assert all(p == lax.Precision.HIGHEST for p in e.params["precision"])
+        assert all(v.aval.dtype == jnp.float32 for v in e.invars)
+
+
+@pytest.mark.parametrize("groups", [3, 7])
+def test_grouped_products_refuse_groups_that_do_not_divide_the_channels(
+        groups):
+    y, z, gamma, _ = norm_inputs(64, jnp.float32)
+    with pytest.raises(mx.MXNetError, match="no multiple"):
+        ssm._gated_rms_norm(y, z, gamma, groups=groups)
+
+
+def test_traced_norm_counts_its_form():
+    """``mxnet_tpu_gated_norms_traced_total{groups,channels,form}``: one
+    traced call of each form, and nothing for an eager call."""
+    def counted():
+        return dict(observability.snapshot()["metrics"].get(
+            ssm.NORM_COUNT_METRIC, {}).get("values", {}))
+
+    y, z, gamma, _ = norm_inputs(64, jnp.bfloat16)
+    before = counted()
+    for groups in (1, 8):
+        jax.make_jaxpr(lambda *a: ssm._gated_rms_norm(*a, groups=groups))(
+            y, z, gamma)
+    ssm._gated_rms_norm(y, z, gamma, groups=8)
+    after = counted()
+    moved = {k: v - before.get(k, 0) for k, v in after.items()
+             if v != before.get(k, 0)}
+    assert moved == {"groups=1,channels=64,form=plain": 1,
+                     "groups=8,channels=64,form=grouped": 1}
+
+
 def recurrence(x, dt, a_log, b, c, d, dt_bias):
     """``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t +
     D x_t``, one position at a time; a group serves consecutive heads."""
