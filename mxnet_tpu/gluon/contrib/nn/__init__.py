@@ -214,19 +214,24 @@ class MoEFFN(HybridBlock):
 
 
 class FeedForward(HybridBlock):
-    """``W2 relu(W1 x)^2`` without biases: the form of
-    :class:`RoutedExperts`' experts, and its shared expert."""
+    """``W2 relu(W1 x)^2`` without biases, or with ``gated`` ``W2 (silu(g) *
+    u)``, ``[g | u] = W1 x`` (``W1`` of ``2 * hidden_size`` rows): the forms
+    of :class:`RoutedExperts`' experts, and its shared expert."""
 
-    def __init__(self, units, hidden_size, **kwargs):
+    def __init__(self, units, hidden_size, gated=False, **kwargs):
         super().__init__(**kwargs)
         from ...nn import Dense
+        self._gated = bool(gated)
         with self.name_scope():
-            self.w_in = Dense(hidden_size, flatten=False, use_bias=False,
-                              in_units=units, prefix="in_")
+            self.w_in = Dense(hidden_size * (2 if gated else 1),
+                              flatten=False, use_bias=False, in_units=units,
+                              prefix="in_")
             self.w_out = Dense(units, flatten=False, use_bias=False,
                                in_units=hidden_size, prefix="out_")
 
     def hybrid_forward(self, F, x):
+        if self._gated:
+            return self.w_out(F.contrib.swiglu(self.w_in(x)))
         return self.w_out(F.square(F.relu(self.w_in(x))))
 
 
@@ -236,19 +241,27 @@ class RoutedExperts(HybridBlock):
     the chips (net-new TPU capability; ops in ``ops/moe.py``).
 
     The router scores every token over **all** ``num_experts`` in float32
-    (sigmoid of ``x W_r^T``), takes the ``k`` largest of ``score +
-    bias`` (``router_bias``: a buffer no gradient reaches, the
-    correction of auxiliary-loss-free balancing; it chooses and does not
-    weigh), and weighs the chosen by their scores (divided by their sum if
-    ``norm_topk_prob``, times ``scaling_factor``). The layer holds the
-    ``experts_held`` experts from ``first_expert`` on (all of them by
-    default) and computes, for the pairs whose expert it holds, ``W2_e
-    relu(W1_e x)^2`` as two grouped products over rows ordered by expert;
-    pairs whose expert lives on another chip add nothing here, and the
-    partial result is what the block returns (the exchange that would add
-    the other chips' parts is not this block's). No pair is dropped. A
-    shared expert of width ``shared_hidden_size`` (0: none), the same form,
-    runs on every token and is added.
+    (``scoring`` ``"sigmoid"`` of ``x W_r^T``, or its ``"softmax"`` over the
+    experts), takes the ``k`` largest of ``score + bias`` (``router_bias``:
+    a buffer no gradient reaches, the correction of auxiliary-loss-free
+    balancing; it chooses and does not weigh), with ``n_group`` > 1 only
+    among the experts of the ``topk_group`` groups whose largest ``score +
+    bias`` is largest (group-limited choice over ``n_group`` runs of
+    consecutive experts), and weighs the chosen by their scores (divided by
+    their sum if ``norm_topk_prob``, times ``scaling_factor``). The layer
+    holds the ``experts_held`` experts from ``first_expert`` on (all of them
+    by default) and computes, for the pairs whose expert it holds, ``W2_e
+    relu(W1_e x)^2`` (``gated``: ``W2_e (silu(g) * u)``, ``[g | u] = W1_e
+    x``) as two grouped products over rows ordered by expert; pairs whose
+    expert lives on another chip add nothing here, and the partial result
+    is what the block returns (the exchange that would add the other chips'
+    parts is not this block's). No pair is dropped, unless
+    ``capacity_factor`` > 0: then this chip is one device of an expert-
+    parallel group and computes at most that factor times its share of the
+    pairs (``ops.moe.device_budget``), those of largest score, as
+    DeepSeek-V2 trains; the routes mark the others dropped (id less
+    ``num_experts``). A shared expert of width ``shared_hidden_size`` (0:
+    none), the same form, runs on every token and is added.
 
     ``x``: (B, S, units) -> (B, S, units); with ``return_routes`` the
     block returns ``(y, routes, rows, scores)``: the chosen experts (B, S,
@@ -267,7 +280,9 @@ class RoutedExperts(HybridBlock):
 
     def __init__(self, units, hidden_size, num_experts, k=2, first_expert=0,
                  experts_held=None, norm_topk_prob=True, scaling_factor=1.0,
-                 shared_hidden_size=0, return_routes=False, **kwargs):
+                 shared_hidden_size=0, return_routes=False,
+                 scoring="sigmoid", n_group=1, topk_group=1, gated=False,
+                 capacity_factor=0.0, **kwargs):
         super().__init__(**kwargs)
         held = num_experts - first_expert if experts_held is None \
             else experts_held
@@ -278,11 +293,20 @@ class RoutedExperts(HybridBlock):
                 f"are not among {num_experts}")
         if not 0 < k <= num_experts:
             raise MXNetError(f"RoutedExperts: k {k} of {num_experts} experts")
+        if num_experts % n_group or not 0 < topk_group <= n_group:
+            raise MXNetError(
+                f"RoutedExperts: {num_experts} experts in n_group {n_group}, "
+                f"topk_group {topk_group}")
         self._experts, self._k = int(num_experts), int(k)
         self._first, self._held = int(first_expert), int(held)
+        self._gated = bool(gated)
         self._route = dict(top_k=self._k,
                            norm_topk_prob=bool(norm_topk_prob),
-                           scaling_factor=float(scaling_factor))
+                           scaling_factor=float(scaling_factor),
+                           scoring=str(scoring), n_group=int(n_group),
+                           topk_group=int(topk_group),
+                           capacity_factor=float(capacity_factor),
+                           first_expert=self._first, experts_held=self._held)
         self._return_routes = bool(return_routes)
         with self.name_scope():
             self.router_weight = self.params.get(
@@ -291,7 +315,8 @@ class RoutedExperts(HybridBlock):
                 "router_bias", shape=(num_experts,), init="zeros",
                 differentiable=False)
             self.expert_w1 = self.params.get(
-                "expert_w1", shape=(held, units, hidden_size))
+                "expert_w1",
+                shape=(held, units, hidden_size * (2 if gated else 1)))
             self.expert_w2 = self.params.get(
                 "expert_w2", shape=(held, hidden_size, units))
             self.expert_rows = self.params.get(
@@ -301,7 +326,7 @@ class RoutedExperts(HybridBlock):
                 "steps", shape=(1,), init="zeros", dtype="int32",
                 differentiable=False)
             self.shared = FeedForward(
-                units, shared_hidden_size,
+                units, shared_hidden_size, gated=gated,
                 prefix="shared_") if shared_hidden_size else None
         _live_routed.add(self)
 
@@ -314,13 +339,19 @@ class RoutedExperts(HybridBlock):
                        expert_w2, expert_rows, steps):
         from .... import autograd
         from ....observability.instrument import device_scope
+        from ....ops.moe import device_budget
         with device_scope("moe.router"):
             weights, routes, scores = F.contrib.moe_route(
                 x, router_weight, router_bias, **self._route)
+        factor = self._route["capacity_factor"]
+        capacity = device_budget(int(np.prod(x.shape[:-1])), self._k,
+                                 self._held, self._experts,
+                                 factor) if factor > 0 else 0
         # the op opens moe.dispatch, moe.experts and moe.combine itself
         y, rows = F.contrib.moe_experts(
             x, weights, routes, expert_w1, expert_w2,
-            first_expert=self._first, num_experts=self._experts)
+            first_expert=self._first, num_experts=self._experts,
+            gated=self._gated, capacity=capacity)
         if autograd.is_training():
             expert_rows._rebind(expert_rows._data + rows._data)
             steps._rebind(steps._data + 1)

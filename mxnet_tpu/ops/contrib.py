@@ -312,15 +312,19 @@ def _interleaved_valatt(qkv, att, heads=None):
 FLASH_COUNT_METRIC = "mxnet_tpu_flash_attention_traced_total"
 
 
-def _count_traced_attention(branch, block_q="", block_k=""):
+def _count_traced_attention(branch, block_q="", block_k="", qk=0, v=0,
+                            padded=""):
     """One attention call traced into a program, by the branch it took
-    (``dense``, ``tpu_kernel``, ``portable``) and that branch's query and
-    key tiles: trace-time only, so a compiled step never counts."""
+    (``dense``, ``tpu_kernel``, ``portable``), that branch's query and key
+    tiles, the query/key and value head sizes, and what was padded with
+    zeros to reach the kernel (``v``: the value head to the query's; empty:
+    nothing): trace-time only, so a compiled step never counts."""
     from ..observability.metrics import default_registry
     default_registry().counter(
         FLASH_COUNT_METRIC, "flash-attention calls traced into a program",
-        ("branch", "block_q", "block_k")).labels(
-            branch=branch, block_q=str(block_q), block_k=str(block_k)).inc()
+        ("branch", "block_q", "block_k", "qk", "v", "padded")).labels(
+            branch=branch, block_q=str(block_q), block_k=str(block_k),
+            qk=str(qk), v=str(v), padded=padded).inc()
 
 
 def _tpu_flash_attention(q, k, v, causal, scale):
@@ -410,6 +414,32 @@ def _library_flash_bwd(causal, scale, vjp, g):
 _library_flash.defvjp(_library_flash_fwd, _library_flash_bwd)
 
 
+def _kernel_head(d):
+    """The head size the library kernel is called with for heads of ``d``:
+    ``d`` up to 128, past it the next multiple of 128 (the kernel refuses
+    others)."""
+    return d if d <= 128 else -(-d // 128) * 128
+
+
+def attention_branch(q, k, v):
+    """The branch ``_contrib_flash_attention`` takes for these operands
+    (arrays or tracers of [B, H, S, D]): ``dense`` up to 1024 keys, else
+    ``tpu_kernel`` where they run on a TPU with the kernel tier on and the
+    library kernel takes their shapes (a value head no wider than the
+    query's; a head past 128 is padded to a multiple of 128), else
+    ``portable``."""
+    if k.shape[-2] <= 1024:
+        return "dense"
+    from ..pallas import mode as _pallas_mode
+    from ..pallas.registry import runs_on
+    if runs_on((q, k, v))[0] == "tpu" and _pallas_mode() != "off" and \
+            q.shape[-2] % 128 == 0 and k.shape[-2] % 128 == 0 and \
+            q.shape[-1] >= 64 and v.shape[-1] <= q.shape[-1] and \
+            q.dtype in (jnp.bfloat16, jnp.float32):
+        return "tpu_kernel"
+    return "portable"
+
+
 @register("_contrib_flash_attention", num_inputs=3,
           params=[OpParam("block_size", int, 512),
                   OpParam("causal", bool, False),
@@ -417,12 +447,15 @@ _library_flash.defvjp(_library_flash_fwd, _library_flash_bwd)
           doc="Blockwise online-softmax attention on [B, H, S, D] inputs — "
               "memory-efficient long-context attention (net-new TPU "
               "capability, SURVEY §5.7; no reference analog — MXNet 1.x "
-              "used full attention). Sequence-parallel variant: "
+              "used full attention). v may have a head size of its own "
+              "(the output's). Sequence-parallel variant: "
               "mxnet_tpu.parallel.ring_attention.")
 def _flash_attention(q, k, v, block_size=512, causal=False, sm_scale=None):
     from ..parallel.ring_attention import blockwise_attention
     scale = float(q.shape[-1]) ** -0.5 if sm_scale is None else sm_scale
-    if k.shape[-2] <= 1024:
+    sizes = dict(qk=q.shape[-1], v=v.shape[-1])
+    branch = attention_branch(q, k, v)
+    if branch == "dense":
         # short KV: one fused softmax(QK^T)V straight on the MXU via the
         # shared dense-attention definition (attention_reference — one
         # mask convention, fp32-accumulated row sums). The s_q x s_kv
@@ -431,7 +464,7 @@ def _flash_attention(q, k, v, block_size=512, causal=False, sm_scale=None):
         # ~20x at S=128, at the library's default tiles — see
         # docs/perf_notes.md).
         from ..parallel.ring_attention import attention_reference
-        _count_traced_attention("dense")
+        _count_traced_attention("dense", **sizes)
         return attention_reference(q, k, v, causal=causal, scale=scale)
     # on TPU hardware route to the library's hand-tiled Pallas kernel
     # (MXU-tiled blocks, VMEM-resident online softmax), called with the
@@ -443,27 +476,38 @@ def _flash_attention(q, k, v, block_size=512, causal=False, sm_scale=None):
     # (tests/test_chip_compile.py compiles this call for a v5e). Inside
     # jit the platform is only known at lowering (pallas.runs_on), so
     # there both paths are staged and the lowering keeps one.
-    from ..pallas import mode as _pallas_mode
     from ..pallas.registry import runs_on
 
     def portable(q, k, v):
         return blockwise_attention(q, k, v, block_size=block_size,
                                    causal=causal, scale=scale)
 
-    platform, staged = runs_on((q, k, v))
-    if platform == "tpu" and _pallas_mode() != "off" and \
-            q.shape[-2] % 128 == 0 and k.shape[-2] % 128 == 0 and \
-            q.shape[-1] >= 64 and q.dtype in (jnp.bfloat16, jnp.float32):
+    if branch == "tpu_kernel":
+        head = _kernel_head(q.shape[-1])
+        width = v.shape[-1]
+
         def on_tpu(q, k, v):
-            return _tpu_flash_attention(q, k, v, causal, scale)
-        tiles = _flash_tiles(q.shape[-2], k.shape[-2], q.shape[-1], q.dtype)
-        _count_traced_attention("tpu_kernel", tiles.block_q,
-                                tiles.block_k_major)
-        if staged:
+            if head == q.shape[-1] == width:
+                return _tpu_flash_attention(q, k, v, causal, scale)
+            # the library kernel takes one head size for q, k and v, and
+            # past 128 only whole multiples of 128: the heads ride in zeros
+            # to that size (zeros add nothing to a score; the scale is
+            # given), and the columns the value's zeros give are cut off
+            def padded(t):
+                return jnp.pad(t, [(0, 0)] * (t.ndim - 1)
+                               + [(0, head - t.shape[-1])])
+            return _tpu_flash_attention(padded(q), padded(k), padded(v),
+                                        causal, scale)[..., :width]
+        tiles = _flash_tiles(q.shape[-2], k.shape[-2], head, q.dtype)
+        _count_traced_attention(
+            "tpu_kernel", tiles.block_q, tiles.block_k_major,
+            padded=("qkv" if head > q.shape[-1] else "v"
+                    if width < head else ""), **sizes)
+        if runs_on((q, k, v))[1]:
             return lax.platform_dependent(q, k, v, tpu=on_tpu,
                                           default=portable)
         return on_tpu(q, k, v)
-    _count_traced_attention("portable", block_k=block_size)
+    _count_traced_attention("portable", block_k=block_size, **sizes)
     return portable(q, k, v)
 
 

@@ -13,6 +13,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as _np
 from jax import lax
 
 from ..base import MXNetError, _as_np_dtype
@@ -474,22 +475,73 @@ def _rms_norm(x, gamma, axis=-1, eps=1e-6):
             * gamma.astype(jnp.float32)).astype(x.dtype)
 
 
+def yarn_mscale(factor, mscale):
+    """YaRN's attention temperature: ``0.1 * mscale * ln(factor) + 1`` for a
+    factor over 1, else 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inverse_frequencies(dim, theta, factor, original_length, beta_fast,
+                             beta_slow):
+    """The ``dim / 2`` inverse frequencies of YaRN (Peng et al., 2023, as
+    DeepSeek-V2 applies it), float64 numpy: ``theta^(-2i/dim)`` where a
+    coordinate turns more than ``beta_fast`` times over ``original_length``
+    positions, that divided by ``factor`` where it turns fewer than
+    ``beta_slow`` times, and a linear ramp over the coordinates between, whose
+    ends are the floor and the ceiling of ``d(n) = dim ln(original_length /
+    (2 pi n)) / (2 ln theta)``."""
+    def turns(n):
+        return dim * math.log(original_length / (n * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(turns(beta_fast)), 0)
+    hi = min(math.ceil(turns(beta_slow)), dim - 1)
+    if hi == lo:
+        hi += 0.001
+    extra = theta ** (-_np.arange(0, dim, 2, dtype=_np.float64) / dim)
+    ramp = _np.clip((_np.arange(dim // 2) - lo) / (hi - lo), 0.0, 1.0)
+    return extra * (1.0 - ramp) + extra / factor * ramp
+
+
 @register("_contrib_rotary_embedding", num_inputs=1,
-          params=[OpParam("theta", float, 10000.0)],
+          params=[OpParam("theta", float, 10000.0),
+                  OpParam("scaling_factor", float, None),
+                  OpParam("original_max_position_embeddings", int, 4096),
+                  OpParam("beta_fast", float, 32.0),
+                  OpParam("beta_slow", float, 1.0),
+                  OpParam("mscale", float, 1.0),
+                  OpParam("mscale_all_dim", float, 0.0)],
           doc="Rotary position embedding over the whole last axis of x (B, "
               "S, heads, D), D even, rotate-half convention: coordinates i "
               "and i + D/2 of row s turn by the angle s * theta^(-2i/D), s = "
-              "0 .. S-1. Angles, sines and the rotation in float32, returned "
-              "in x's dtype (new op; no reference analog)")
-def _rotary_embedding(x, theta=10000.0):
+              "0 .. S-1. With scaling_factor, YaRN's frequencies "
+              "(yarn_inverse_frequencies: original_max_position_embeddings, "
+              "beta_fast, beta_slow) and cos and sin times yarn_mscale("
+              "factor, mscale) / yarn_mscale(factor, mscale_all_dim). Angles, "
+              "sines and the rotation in float32, returned in x's dtype "
+              "(new op; no reference analog)")
+def _rotary_embedding(x, theta=10000.0, scaling_factor=None,
+                      original_max_position_embeddings=4096, beta_fast=32.0,
+                      beta_slow=1.0, mscale=1.0, mscale_all_dim=0.0):
     if x.ndim != 4 or x.shape[-1] % 2:
         raise MXNetError(f"rotary_embedding: x (B, S, heads, D) with D even "
                          f"expected, got {x.shape}")
     half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if scaling_factor is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        inv_freq = jnp.asarray(yarn_inverse_frequencies(
+            x.shape[-1], theta, scaling_factor,
+            original_max_position_embeddings, beta_fast, beta_slow),
+            jnp.float32)
     angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq
     cos = jnp.cos(angles)[:, None, :]               # one angle for all heads
     sin = jnp.sin(angles)[:, None, :]
+    if scaling_factor is not None:
+        ratio = yarn_mscale(scaling_factor, mscale) \
+            / yarn_mscale(scaling_factor, mscale_all_dim)
+        if ratio != 1.0:
+            cos, sin = cos * ratio, sin * ratio
     lo, hi = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin],
                            axis=-1).astype(x.dtype)

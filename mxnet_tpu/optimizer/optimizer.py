@@ -84,13 +84,17 @@ class Optimizer:
     def _get_lr(self, index):
         lr = (self.lr_scheduler(self.num_update) if self.lr_scheduler
               else self.lr)
+        return lr * self._get_lr_mult(index)
+
+    def _get_lr_mult(self, index):
+        """What the learning rate of parameter ``index`` is multiplied by."""
         if index in self.param_dict:
-            lr *= self.param_dict[index].lr_mult
-        elif index in self.lr_mult:
-            lr *= self.lr_mult[index]
-        elif index in self.idx2name:
-            lr *= self.lr_mult.get(self.idx2name[index], 1.0)
-        return lr
+            return self.param_dict[index].lr_mult
+        if index in self.lr_mult:
+            return self.lr_mult[index]
+        if index in self.idx2name:
+            return self.lr_mult.get(self.idx2name[index], 1.0)
+        return 1.0
 
     def _get_wd(self, index):
         wd = self.wd

@@ -341,7 +341,7 @@ def _blockwise_ref(q, k, v, block_size=512, causal=False, scale=None,
         # there; a non-positive kmax means every row's set is empty.
         kmax = i + length + s_kv - s_q
         if kmax <= 0:
-            outs.append(jnp.zeros(qc.shape, q.dtype))
+            outs.append(jnp.zeros(qc.shape[:-1] + v.shape[-1:], q.dtype))
             continue
         outs.append(attention_reference(
             qc, k[..., :kmax, :], v[..., :kmax, :], causal=True,

@@ -101,14 +101,15 @@ def _blockwise_impl(q, k, v, block_size=512, causal=False, scale=None):
     n_blocks = s_k // block_size   # perf knob, not a correctness contract)
     kb = jnp.moveaxis(k.reshape(k.shape[:-2] + (n_blocks, block_size, d)),
                       -3, 0)
-    vb = jnp.moveaxis(v.reshape(v.shape[:-2] + (n_blocks, block_size, d)),
-                      -3, 0)
+    vb = jnp.moveaxis(v.reshape(v.shape[:-2] + (n_blocks, block_size,
+                                                v.shape[-1])), -3, 0)
     s_q = q.shape[-2]
     # derive accumulators from q so their device-varying type matches under
     # shard_map (a plain zeros constant is 'unvarying' and scan rejects the
-    # carry mismatch)
+    # carry mismatch); the output's rows are as wide as v's
     zero_like_q = (q * 0).astype(jnp.float32)
-    o0 = zero_like_q
+    o0 = zero_like_q if v.shape[-1] == d else \
+        zero_like_q[..., :1] * jnp.zeros(v.shape[-1], jnp.float32)
     l0 = zero_like_q[..., 0]
     m0 = zero_like_q[..., 0] + _NEG
     q_pos = jnp.arange(s_q)

@@ -494,8 +494,9 @@ class ShardedTrainer(GuardedTrainerMixin):
     def _build_step(self, n_inputs):
         block, loss_block, opt = self._block, self._loss, self._optimizer
         wds = [opt._get_wd(i) for i in range(len(self._trainable))]
-        lr_mults = [opt._get_lr(i) / max(opt.learning_rate, 1e-30)
-                    for i in range(len(self._trainable))]
+        # the multipliers themselves: a ratio of learning rates is 0 / 0
+        # while a warm-up starts from 0
+        lr_mults = [opt._get_lr_mult(i) for i in range(len(self._trainable))]
         clip = opt.clip_gradient if opt.clip_gradient is not None else -1.0
         guard_clip = (self._guard_cfg.clip_norm
                       if self._guard_cfg is not None else None)

@@ -257,6 +257,71 @@ def test_routed_experts_compile_forward_and_backward(one_chip, monkeypatch):
     assert pallas.tier_provenance()["grouped_matmul"]["pallas"] - before == 4
 
 
+# the deepseek_v2 cell's attention core: 8 heads held, one 8192-token
+# sequence, query/key heads of 128 + 64, value heads of 128, bf16
+MLA_SHAPES = {"heads": 8, "seq": 8192, "qk": 192, "v": 128,
+              "scale": 192 ** -0.5 * 1.2607986 ** 2}
+
+
+def test_latent_attention_core_compiles_at_the_cells_shape(one_chip,
+                                                           monkeypatch):
+    """``_contrib_flash_attention`` with a value head smaller than the
+    query's, as the latent attention block calls it at the cell's shape:
+    the library kernel with every head padded to 256 (it refuses 192),
+    forward and ``jax.grad`` through it."""
+    from mxnet_tpu.ops import contrib
+    from mxnet_tpu.pallas import registry
+    m = MLA_SHAPES
+    # the process's backend is the CPU: say what the chip run will say
+    monkeypatch.setattr(registry, "runs_on", lambda args: ("tpu", False))
+    qk = jax.ShapeDtypeStruct((1, m["heads"], m["seq"], m["qk"]),
+                              jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, m["heads"], m["seq"], m["v"]),
+                             jnp.bfloat16, sharding=one_chip)
+
+    def fwd(q, k, v):
+        return contrib._flash_attention(q, k, v, causal=True,
+                                        sm_scale=m["scale"])
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    assert contrib.attention_branch(qk, qk, v) == "tpu_kernel"
+    assert jax.eval_shape(fwd, qk, qk, v).shape[-1] == m["v"]
+    assert "tpu_custom_call" in _compile(fwd, qk, qk, v).as_text()
+    grad = _compile(jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v)
+    assert grad.as_text().count("tpu_custom_call") >= 3
+
+
+def test_gated_routed_experts_compile_forward_and_backward(one_chip,
+                                                           monkeypatch):
+    """``_contrib_moe_experts`` with gated experts at the deepseek_v2 cell's
+    shapes (8192 tokens, 6 experts a token, 10 held of 160, 5120 -> 2 x 1536
+    -> 5120), ``jax.grad`` of it: the two grouped products of both buffer
+    sizes on the library's kernels."""
+    from mxnet_tpu.ops import moe
+    from mxnet_tpu.pallas import registry
+    monkeypatch.setattr(registry, "runs_on", lambda args: ("tpu", True))
+    tokens, k, held, units, hidden = 8192, 6, 10, 5120, 1536
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    structs = (sds((tokens, units)), sds((tokens, k), jnp.float32),
+               sds((tokens, k), jnp.int32), sds((held, units, 2 * hidden)),
+               sds((held, hidden, units)))
+
+    def loss(x, w, ids, w1, w2):
+        return moe._moe_experts(x, w, ids, w1, w2, num_experts=160,
+                                gated=True)[0].astype(jnp.float32).sum()
+
+    before = pallas.tier_provenance().get("grouped_matmul", {}).get(
+        "pallas", 0)
+    grad = _compile(jax.grad(loss, argnums=(0, 1, 3, 4)), *structs)
+    assert grad.as_text().count("tpu_custom_call") >= 12
+    assert pallas.tier_provenance()["grouped_matmul"]["pallas"] - before == 4
+
+
 # one layer's scan of the two hybrid cells: (L, H, P, G, N, chunk), bf16
 SSD_SHAPES = {
     "granite_4_0_h_micro": (4096, 64, 64, 1, 128, 256),

@@ -284,10 +284,9 @@ def test_arguments_are_checked():
     with pytest.raises(MXNetError, match="moe_route"):
         nd.contrib.moe_route(nd.array(tokens()), block.expert_w2.data(),
                              block.router_bias.data())
-    # one form of expert and one score: the settings that chose others are
-    # gone, not ignored
-    for gone in ({"activation": "silu"}, {"gated": True},
-                 {"score_func": "softmax"}):
+    # the forms of expert and score are named by ``gated`` and ``scoring``
+    # (a configuration uses each); other spellings are refused, not ignored
+    for gone in ({"activation": "silu"}, {"score_func": "softmax"}):
         with pytest.raises(TypeError):
             cnn.RoutedExperts(U, F, E, **gone)
     with pytest.raises(MXNetError, match="moe_experts"):

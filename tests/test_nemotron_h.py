@@ -405,7 +405,8 @@ def test_device_scopes_names_the_expert_layers_instructions(mesh):
     trainer.run_steps(x, y, num_steps=2)
     after = observability.snapshot()["metrics"][moe.MOE_COUNT_METRIC][
         "values"]
-    key = "experts=16,held=4,top_k=3,rows=512,grouped=ragged_dot"
+    key = ("experts=16,held=4,top_k=3,rows=512,grouped=ragged_dot,"
+           "expert=relu2")
     assert after[key] - before.get(key, 0) >= 2
     record = [record for name, record
               in observability.device_scopes().items()

@@ -556,3 +556,32 @@ def test_sharded_run_steps_respects_lr_schedule():
     wb = [np.asarray(p._data[0]._data) for p in tr_b._trainable]
     for a, b in zip(wa, wb):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6)
+
+
+def test_warmup_from_zero_updates_weights():
+    """A warm-up that starts at lr 0 still moves the weights from the first
+    step on: the per-parameter multipliers are the multipliers, not a ratio
+    of learning rates (0 / 0 while the schedule starts at 0)."""
+    import numpy as np
+    from mxnet_tpu import lr_scheduler
+
+    rng = np.random.RandomState(0)
+    x = rng.randn(16, 6).astype(np.float32)
+    y = rng.randint(0, 4, (16,))
+    mesh = parallel.make_mesh({"data": 8})
+    mx.random.seed(17)
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(8, activation="relu"), gluon.nn.Dense(4))
+    net.initialize()
+    schedule = lr_scheduler.MultiFactorScheduler(
+        step=[100], factor=0.5, base_lr=0.2, warmup_steps=10)
+    opt = mx.optimizer.create("sgd", learning_rate=0.2,
+                              lr_scheduler=schedule)
+    trainer = parallel.ShardedTrainer(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), opt, mesh=mesh)
+    trainer.prepare(x)
+    before = [np.asarray(p._data[0]._data) for p in trainer._trainable]
+    trainer.run_steps(x, y, num_steps=2)
+    after = [np.asarray(p._data[0]._data) for p in trainer._trainable]
+    assert schedule(0) == 0.0 and schedule(1) == 0.02
+    assert all(np.abs(a - b).max() > 0 for a, b in zip(after, before))
