@@ -9,6 +9,7 @@ reference's Resource-managed RNG states, ref: src/resource.cc).
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -586,7 +587,64 @@ def _dropout(x, rng=None, p=0.5, mode="training", axes=(), training=False):
           doc="Lookup table (ref: indexing_op.cc Embedding)")
 def _embedding(indices, weight, input_dim=None, output_dim=None,
                dtype="float32", sparse_grad=False):
-    return jnp.take(weight, indices.astype(jnp.int32), axis=0)
+    ids = indices.astype(jnp.int32)
+    grouped = _grouped_table_gradient(ids, weight)
+    if isinstance(ids, jax.core.Tracer):
+        _count_traced_embedding(weight, ids, grouped)
+    if grouped:
+        return _take_rows(weight.shape[0], weight, ids)
+    return jnp.take(weight, ids, axis=0)
+
+
+EMBEDDING_GRAD_COUNT_METRIC = "mxnet_tpu_embedding_grads_traced_total"
+
+
+def _grouped_table_gradient(ids, weight):
+    """Whether the lookup's backward is the tier's ``embedding_grad``: where
+    the tier would take it (on a TPU, not partitioned by the compiler, not
+    switched off). Asked in the forward, so everywhere else the lookup is
+    the plain ``jnp.take`` and its backward autodiff's, to the instruction.
+    On a v5e the kernel is no slower than autodiff's scatter-add at any of
+    the tables the benchmark's cells run, 768 to 5120 units wide, and 14
+    times faster from 4232 units up, where XLA gathers the sorted cotangent
+    in a fusion of its own first (PERF.md sec. 6, PR 39)."""
+    from ..pallas.registry import declines
+    cotangent = jax.ShapeDtypeStruct(ids.shape + weight.shape[1:],
+                                     weight.dtype)
+    return declines("embedding_grad", ids, cotangent,
+                    rows=weight.shape[0]) is None
+
+
+def _count_traced_embedding(weight, ids, grouped):
+    """One lookup traced into a program, by the table's rows and units, the
+    ids looked up and the form its table's gradient takes (``grouped``: the
+    tier's kernel where the program is lowered for a TPU; ``scatter``:
+    autodiff's): trace-time only, so a compiled step never counts."""
+    from ..observability.metrics import default_registry
+    default_registry().counter(
+        EMBEDDING_GRAD_COUNT_METRIC,
+        "embedding lookups traced into a program, by their table "
+        "gradient's form", ("rows", "units", "tokens", "path")).labels(
+            rows=str(weight.shape[0]), units=str(weight.shape[-1]),
+            tokens=str(ids.size),
+            path="grouped" if grouped else "scatter").inc()
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _take_rows(rows, weight, ids):
+    return jnp.take(weight, ids, axis=0)
+
+
+def _take_rows_fwd(rows, weight, ids):
+    return jnp.take(weight, ids, axis=0), ids
+
+
+def _take_rows_bwd(rows, ids, cotangent):
+    from ..pallas import dispatch
+    return dispatch("embedding_grad", ids, cotangent, rows=rows), None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """Seed kernels of the guarded Pallas tier (docs/pallas.md).
 
-Four kernels, each dispatched by a benchmark cell:
+The kernels, each dispatched by a benchmark cell:
 
 - ``matmul_epilogue`` — the BERT lever (~56% MFU inside XLA's matmul
   fusions, dropout-mask traffic measured 24% of a step pre-rbg): bias +
@@ -21,6 +21,9 @@ Four kernels, each dispatched by a benchmark cell:
 - ``power_retention`` — chunked power retention (``ops/retention.py``) as
   one pass over a head's chunks with its state in fast memory, forward and
   backward; kernel and reference live in :mod:`.retention`.
+- ``embedding_grad`` — the table's gradient of an embedding lookup
+  (``ops/nn.py`` ``Embedding``'s backward) as the library's ``tgmm`` over
+  blocks of table rows, with autodiff's scatter-add as the reference.
 
 (Another, ``conv_epilogue``, was the ResNet lever until it lost on the
 chip — a 2-D view of a tiled NCHW activation is a physical re-layout,
@@ -36,6 +39,7 @@ own, held to autodiff of their references by tests/test_pallas.py.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -546,6 +550,123 @@ def _grouped_example():
         "groups of (2688, 1856) on a v5e (PERF.md sec. 6, PR 32).")
 def _grouped_pallas(lhs, rhs, group_sizes, interpret=False):
     return _grouped_library(lhs, rhs, group_sizes, bool(interpret))
+
+
+# ---------------------------------------------------------------------------
+# embedding gradient: the table's rows in blocks, one grouped product a block
+# ---------------------------------------------------------------------------
+# rows of the table a group of the grouped product covers
+EMBEDDING_BLOCK_ROWS = 256
+
+
+def _embedding_grad_ref(ids, cotangent, rows):
+    """The table's gradient of ``jnp.take(table, ids, axis=0)`` for a table
+    of ``rows`` rows, ``cotangent`` being ``ids.shape + (width,)``:
+    autodiff's own, a scatter-add of the cotangent's rows into a zero table
+    (an id in ``[-rows, 0)`` counts from the end, as the lookup reads it;
+    any other id outside ``[0, rows)`` adds nothing)."""
+    table = jax.ShapeDtypeStruct((rows, cotangent.shape[-1]), cotangent.dtype)
+    return jax.linear_transpose(lambda w: jnp.take(w, ids, axis=0),
+                                table)(cotangent)[0]
+
+
+def _bf16_parts(x):
+    """float32 ``x`` as three bfloat16 terms whose sum is ``x`` to float32's
+    24 bits: a product of 0/1 with each is exact in one bf16 pass, whatever
+    precision the kernel's compiler gives a float32 product."""
+    parts = []
+    for _ in range(3):
+        parts.append(x.astype(jnp.bfloat16))
+        x = x - parts[-1].astype(jnp.float32)
+    return jnp.concatenate(parts, axis=1)
+
+
+def _embedding_grad_call(ids, cotangent, rows, interpret):
+    """Sort the ids and gather the cotangent's rows in that order; cut the
+    table into blocks of ``EMBEDDING_BLOCK_ROWS`` rows, each a group; the
+    library's ``tgmm`` then multiplies each block's ``(block rows, tokens)``
+    0/1 matrix ("sorted token t is row r of this block") by the block's
+    rows of the cotangent, accumulating in float32 and writing each block
+    once (an empty block as zeros)."""
+    block_rows = EMBEDDING_BLOCK_ROWS
+    width = cotangent.shape[-1]
+    ids, cotangent = ids.reshape(-1), cotangent.reshape(-1, width)
+    tokens = ids.shape[0]
+    blocks = -(-rows // block_rows)
+    ids = jnp.where(ids < 0, ids + rows, ids)
+    valid = (ids >= 0) & (ids < rows)
+    # dropped ids sort after every block's and belong to no group
+    key = jnp.where(valid, ids, blocks * block_rows)
+    key, order = jax.lax.sort_key_val(
+        key, jax.lax.iota(jnp.int32, tokens))
+    sizes = jnp.bincount(key // block_rows, length=blocks).astype(jnp.int32)
+    onehot = ((key[:, None] % block_rows
+               == jax.lax.broadcasted_iota(jnp.int32, (1, block_rows), 1))
+              & (key < blocks * block_rows)[:, None])
+    rhs = jnp.take(cotangent, order, axis=0)
+    split = cotangent.dtype == jnp.float32
+    if split:
+        rhs = _bf16_parts(rhs)
+    # bf16 operands: the package's process-wide HIGHEST is refused there
+    with jax.default_matmul_precision("default"):
+        out = _megablox().tgmm(
+            onehot.astype(jnp.bfloat16).swapaxes(0, 1), rhs, sizes,
+            cotangent.dtype,
+            grouped_weight_tiles(tokens, block_rows, rhs.shape[1]),
+            interpret=interpret)
+    if split:
+        high, mid, low = jnp.split(out, 3, axis=2)
+        out = high + mid + low
+    # whole blocks through a barrier: without it XLA moves the consumer's
+    # cast to float32 (the optimizer's) ahead of the reshape, where it fuses
+    # with nothing, and writes a float32 copy of the table (compiled for a
+    # v5e, a 12800 x 5120 table and an Adam update: 394 MB of temporaries
+    # against the scatter's 215; with it 131)
+    return jax.lax.optimization_barrier(
+        out.reshape(blocks * block_rows, width))[:rows]
+
+
+def _embedding_grad_supports(ids, cotangent, rows):
+    """A table of ``rows`` rows of ``cotangent.shape[-1]`` (a table of more
+    dimensions gives a cotangent of another shape), integer ids, float32 or
+    bfloat16, and a whole number of the kernel's 8-row tiles of ids."""
+    if cotangent.shape[:-1] != ids.shape or cotangent.ndim != ids.ndim + 1:
+        return f"shape:ids{ids.shape}_cotangent{cotangent.shape}"
+    if not jnp.issubdtype(ids.dtype, jnp.integer):
+        return f"dtype:ids_{ids.dtype}"
+    if cotangent.dtype not in (jnp.bfloat16, jnp.float32):
+        return f"dtype:cotangent_{cotangent.dtype}"
+    tokens = math.prod(ids.shape)
+    if tokens % 8 or tokens == 0 or rows <= 0:
+        return f"tokens:{tokens}_rows:{rows}"
+    return None
+
+
+def _embedding_grad_example():
+    rng = np.random.RandomState(5)
+    # ids on both ends of a block, rows no id hits, a last block the table
+    # fills in part, an id counted from the end, ids outside the table
+    ids = np.concatenate([rng.randint(0, 600, 48), [0, 255, 256, 511, 599,
+                                                    -1, 600, -601]])
+    ids = jnp.asarray(ids, jnp.int32)
+    return [((ids, jnp.asarray(rng.randn(56, width), jnp.float32)),
+             {"rows": 600}) for width in (1280, 128)]
+
+
+@register_kernel(
+    "embedding_grad", xla_reference=_embedding_grad_ref, tolerance=1e-5,
+    backends=("tpu",), supports=_embedding_grad_supports,
+    example=_embedding_grad_example,
+    doc="The table's gradient of an embedding lookup (ops/nn.py Embedding's "
+        "backward): the ids sorted, the table cut into blocks of "
+        "EMBEDDING_BLOCK_ROWS rows, and per block a 0/1 matrix times the "
+        "block's rows of the cotangent on the library's tgmm (float32 "
+        "accumulation, each block written once). The reference is "
+        "autodiff's scatter-add, which XLA lowers with a separate sorted "
+        "gather once a row is wider than 4224 elements: 14 times the "
+        "kernel's time at 5120 on a v5e (PERF.md sec. 6, PR 39).")
+def _embedding_grad_pallas(ids, cotangent, interpret=False, rows=None):
+    return _embedding_grad_call(ids, cotangent, int(rows), bool(interpret))
 
 
 # ---------------------------------------------------------------------------

@@ -37,7 +37,7 @@ from typing import Callable, Dict, Optional, Sequence, Tuple
 from ..base import MXNetError
 
 __all__ = ["KernelSpec", "register_kernel", "get_kernel", "kernels",
-           "dispatch", "mode", "set_mode", "tier_provenance",
+           "dispatch", "declines", "mode", "set_mode", "tier_provenance",
            "reset_provenance", "MODES", "block_ok", "default_block"]
 
 MODES = ("auto", "on", "off")
@@ -312,6 +312,40 @@ def reset_provenance() -> None:
         _tuned_logged.clear()
 
 
+def _gate(spec: "KernelSpec", m: str, args, params,
+          interpret: bool) -> Tuple[Optional[str], bool]:
+    """``(reason, staged)``: why :func:`dispatch` would run the reference
+    on ``args`` (None: it would run the kernel), and whether the operands
+    are traced. The selection order is :func:`dispatch`'s."""
+    if m == "off":
+        return "mode_off", False
+    if spec.supports is not None:
+        reason = spec.supports(*args, **params)
+        if reason is not None:
+            return reason, False
+    if interpret:
+        return None, False
+    platform, staged = runs_on(args)
+    if platform not in spec.backends:
+        return f"backend:{platform}", staged
+    # a kernel with every platform among its backends is plain JAX; the
+    # others are Mosaic kernels, which only a TPU lowering takes and the
+    # compiler cannot partition
+    if not _PORTABLE <= set(spec.backends) \
+            and (n := _auto_partitioned(args, staged)) > 1:
+        return f"auto_partition:{n}dev", staged
+    return None, staged
+
+
+def declines(name: str, *args, **params) -> Optional[str]:
+    """The reason :func:`dispatch` would fall back on these operands, or
+    None where it would take the kernel; counts and journals nothing. For
+    an op that has to choose its own form before the kernel is called (the
+    embedding lookup, whose backward is the kernel). ``args`` may hold
+    ``jax.ShapeDtypeStruct`` where an operand does not exist yet."""
+    return _gate(get_kernel(name), mode(), args, params, False)[0]
+
+
 def dispatch(name: str, *args, interpret: bool = False, **params):
     """Run kernel ``name``: the custom tier where it is verified to
     apply, the XLA reference everywhere else.
@@ -349,22 +383,8 @@ def dispatch(name: str, *args, interpret: bool = False, **params):
     """
     spec = get_kernel(name)
     m = mode()
-    reason = None
-    if m == "off":
-        reason = "mode_off"
-    if reason is None and spec.supports is not None:
-        reason = spec.supports(*args, **params)
-    # a kernel with every platform among its backends is plain JAX; the
-    # others are Mosaic kernels, which only a TPU lowering takes and the
-    # compiler cannot partition
+    reason, staged = _gate(spec, m, args, params, interpret)
     mosaic = not _PORTABLE <= set(spec.backends)
-    staged = False
-    if reason is None and not interpret:
-        platform, staged = runs_on(args)
-        if platform not in spec.backends:
-            reason = f"backend:{platform}"
-        elif mosaic and (n := _auto_partitioned(args, staged)) > 1:
-            reason = f"auto_partition:{n}dev"
     # dispatch decisions ride the active trace span (if any): a traced
     # step's span says which kernel tier compiled into it, and why a
     # fallback happened (docs/observability.md)

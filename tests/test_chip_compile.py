@@ -474,3 +474,44 @@ def test_grouped_norm_at_nemotrons_shape_relays_out_nothing(one_chip, form):
         assert found == []
     else:
         assert {"copy", "reshape", "broadcast"} <= set(found)
+
+
+# the cells' embedding tables (rows, units, tokens), bf16: the two 5120-wide
+# decoders, Nemotron's, Granite's (tied to its head), BERT's words and its
+# two token types
+EMBEDDING_TABLES = {
+    "deepseek_v2": (12800, 5120, 8192),
+    "brumby_14b_base": (18992, 5120, 8192),
+    "nemotron_3_nano_30b_a3b": (16384, 2688, 8192),
+    "granite_4_0_h_micro": (25088, 2048, 4096),
+    "bert_words": (30522, 768, 16384),
+    "bert_token_types": (2, 768, 16384),
+}
+
+
+@pytest.mark.parametrize("cell", list(EMBEDDING_TABLES))
+def test_embedding_grad_compiles_at_the_cells_tables(one_chip, monkeypatch,
+                                                     cell):
+    """``Embedding`` as a cell traces it, forward and ``jax.grad`` over the
+    table: the lookup is XLA's gather, the table's gradient the tier's
+    ``embedding_grad`` (one ``tgmm`` custom call), and ``supports`` takes
+    the shapes."""
+    from mxnet_tpu.ops import nn
+    from mxnet_tpu.pallas import registry
+    rows, units, tokens = EMBEDDING_TABLES[cell]
+    monkeypatch.setattr(registry, "runs_on", lambda args: ("tpu", True))
+    table = jax.ShapeDtypeStruct((rows, units), jnp.bfloat16,
+                                 sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, tokens), jnp.int32, sharding=one_chip)
+    _accepted(pallas.get_kernel("embedding_grad"),
+              (ids, jax.ShapeDtypeStruct((1, tokens, units), jnp.bfloat16)),
+              {"rows": rows})
+
+    def loss(w, ids):
+        return nn._embedding(ids, w).astype(jnp.float32).sum()
+
+    before = pallas.tier_provenance().get("embedding_grad", {}).get(
+        "pallas", 0)
+    text = _compile(jax.grad(loss), table, ids).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert pallas.tier_provenance()["embedding_grad"]["pallas"] - before == 1

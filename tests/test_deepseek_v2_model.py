@@ -717,3 +717,29 @@ def small_model(name):
 def test_the_other_decoders_trace_what_they_traced_before(name):
     tokens = np.random.default_rng(1).integers(0, 64, (2, 16))
     assert model_digest(small_model(name), tokens) == BEFORE[name]
+
+
+@pytest.mark.parametrize("units", [5120, 768])
+def test_the_table_takes_its_gradient_on_the_kernel(units, monkeypatch):
+    """The lookup as a TPU program traces it: the table's gradient, of the
+    cell's 5120-wide embedding or of a 768-wide one, is the tier's grouped
+    product, staged beside autodiff's scatter; the CPU's program (the
+    digests above are the CPU's) has no kernel."""
+    from mxnet_tpu import pallas
+    from mxnet_tpu.pallas import registry
+    op = ops.get("Embedding").fn
+
+    def grad_text():
+        def loss(w, ids):
+            return sq(op(ids, w, input_dim=64, output_dim=units))
+        return str(jax.make_jaxpr(jax.grad(loss))(
+            jax.ShapeDtypeStruct((64, units), jnp.bfloat16),
+            jax.ShapeDtypeStruct((2, 16), jnp.int32)))
+
+    assert "pallas_call" not in grad_text()
+    before = pallas.tier_provenance().get("embedding_grad", {}).get(
+        "pallas", 0)
+    monkeypatch.setattr(registry, "runs_on", lambda args: ("tpu", True))
+    assert "pallas_call" in grad_text()
+    assert pallas.tier_provenance()["embedding_grad"]["pallas"] \
+        - before == 1
