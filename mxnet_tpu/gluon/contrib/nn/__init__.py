@@ -276,13 +276,25 @@ class RoutedExperts(HybridBlock):
     callback); ``mxnet_tpu.observability`` exports them as
     ``mxnet_tpu_moe_expert_rows{layer,expert}`` and
     ``mxnet_tpu_moe_steps{layer}`` whenever a snapshot is taken.
+
+    With ``bias_update_rate`` > 0 the bias moves, as auxiliary-loss-free
+    balancing trains it (DeepSeek-V3, arXiv:2412.19437 sec. 2.1.2): in each
+    training step the block counts the pairs the router sent to each of
+    the ``num_experts`` experts, ``c``, and rebinds ``router_bias`` to
+    ``b + rate * sign(mean(c) - c)`` once, after the step's choice (a
+    recomputed forward, ``HybridBlock.recompute()``, carries the state of
+    the first); it adds ``c`` to the int32 counter ``router_rows``
+    (``expert_load()``'s ``"router_rows"``, exported as
+    ``mxnet_tpu_moe_router_rows{layer,expert}``). At 0 the bias is frozen
+    and the block has no such counter.
     """
 
     def __init__(self, units, hidden_size, num_experts, k=2, first_expert=0,
                  experts_held=None, norm_topk_prob=True, scaling_factor=1.0,
                  shared_hidden_size=0, return_routes=False,
                  scoring="sigmoid", n_group=1, topk_group=1, gated=False,
-                 capacity_factor=0.0, **kwargs):
+                 capacity_factor=0.0, norm_eps=1e-20, bias_update_rate=0.0,
+                 **kwargs):
         super().__init__(**kwargs)
         held = num_experts - first_expert if experts_held is None \
             else experts_held
@@ -306,7 +318,9 @@ class RoutedExperts(HybridBlock):
                            scoring=str(scoring), n_group=int(n_group),
                            topk_group=int(topk_group),
                            capacity_factor=float(capacity_factor),
-                           first_expert=self._first, experts_held=self._held)
+                           first_expert=self._first, experts_held=self._held,
+                           norm_eps=float(norm_eps))
+        self._bias_rate = float(bias_update_rate)
         self._return_routes = bool(return_routes)
         with self.name_scope():
             self.router_weight = self.params.get(
@@ -325,6 +339,10 @@ class RoutedExperts(HybridBlock):
             self.steps = self.params.get(
                 "steps", shape=(1,), init="zeros", dtype="int32",
                 differentiable=False)
+            self.router_rows = self.params.get(
+                "router_rows", shape=(num_experts,), init="zeros",
+                dtype="int32", differentiable=False) \
+                if self._bias_rate > 0 else None
             self.shared = FeedForward(
                 units, shared_hidden_size, gated=gated,
                 prefix="shared_") if shared_hidden_size else None
@@ -336,7 +354,7 @@ class RoutedExperts(HybridBlock):
         return self._first, self._held
 
     def hybrid_forward(self, F, x, router_weight, router_bias, expert_w1,
-                       expert_w2, expert_rows, steps):
+                       expert_w2, expert_rows, steps, router_rows=None):
         from .... import autograd
         from ....observability.instrument import device_scope
         from ....ops.moe import device_budget
@@ -355,10 +373,32 @@ class RoutedExperts(HybridBlock):
         if autograd.is_training():
             expert_rows._rebind(expert_rows._data + rows._data)
             steps._rebind(steps._data + 1)
+            if router_rows is not None:
+                counts = balance_counts(routes._data, self._experts)
+                router_rows._rebind(router_rows._data + counts)
+                router_bias._rebind(moved_bias(router_bias._data, counts,
+                                               self._bias_rate))
         if self.shared is not None:
             with device_scope("moe.shared"):
                 y = y + self.shared(x)
         return (y, routes, rows, scores) if self._return_routes else y
+
+
+def balance_counts(routes, experts):
+    """The pairs ``routes`` (..., k) sent to each of the ``experts``, (E,)
+    int32; a pair dropped past a device's budget (id less E) was sent."""
+    import jax.numpy as jnp
+    return jnp.bincount(jnp.mod(routes, experts).reshape(-1),
+                        length=experts).astype(jnp.int32)
+
+
+def moved_bias(bias, counts, rate):
+    """``bias + rate * sign(mean(counts) - counts)``: auxiliary-loss-free
+    balancing's step, in the bias's dtype."""
+    import jax.numpy as jnp
+    load = counts.astype(jnp.float32)
+    return (bias.astype(jnp.float32) + rate * jnp.sign(
+        jnp.mean(load) - load)).astype(bias.dtype)
 
 
 # -- the expert load, for observability ---------------------------------------
@@ -366,27 +406,32 @@ _live_routed = weakref.WeakSet()
 
 EXPERT_ROWS_METRIC = "mxnet_tpu_moe_expert_rows"
 EXPERT_STEPS_METRIC = "mxnet_tpu_moe_steps"
+ROUTER_ROWS_METRIC = "mxnet_tpu_moe_router_rows"
 
 
 def expert_load():
     """``{layer: {"first_expert", "rows": [...], "steps"}}`` of the live
     :class:`RoutedExperts` blocks whose counters hold concrete arrays: the
     rows each held expert has received, summed over the training steps, and
-    the count of those steps. Reads the device."""
+    the count of those steps; a block whose bias moves adds
+    ``"router_rows"``, the pairs its router sent to each of all the experts
+    over the same steps. Reads the device."""
     import jax
     out = {}
     for block in list(_live_routed):
-        rows, steps = block.expert_rows._data, block.steps._data
-        if not rows or not steps:
+        counters = [block.expert_rows, block.steps] + (
+            [block.router_rows] if block.router_rows is not None else [])
+        if not all(c._data for c in counters):
             continue
-        rows, steps = rows[0]._data, steps[0]._data
-        if isinstance(rows, jax.core.Tracer) \
-                or isinstance(steps, jax.core.Tracer):
+        arrays = [c._data[0]._data for c in counters]
+        if any(isinstance(a, jax.core.Tracer) for a in arrays):
             continue
-        out[block.prefix.rstrip("_")] = {
-            "first_expert": block._first,
-            "rows": [int(n) for n in np.asarray(rows)],
-            "steps": int(np.asarray(steps)[0])}
+        said = {"first_expert": block._first,
+                "rows": [int(n) for n in np.asarray(arrays[0])],
+                "steps": int(np.asarray(arrays[1])[0])}
+        if len(arrays) == 3:
+            said["router_rows"] = [int(n) for n in np.asarray(arrays[2])]
+        out[block.prefix.rstrip("_")] = said
     return out
 
 
@@ -405,6 +450,15 @@ def _export_expert_load(registry):
         for i, n in enumerate(said["rows"]):
             rows.labels(layer=layer,
                         expert=str(said["first_expert"] + i)).set(n)
+    routed = {layer: said["router_rows"] for layer, said in load.items()
+              if "router_rows" in said}
+    if routed:
+        gauge = registry.gauge(
+            ROUTER_ROWS_METRIC, "pairs a router sent to an expert, summed "
+            "over the training steps", ("layer", "expert"))
+        for layer, counts in routed.items():
+            for i, n in enumerate(counts):
+                gauge.labels(layer=layer, expert=str(i)).set(n)
 
 
 from ....observability import metrics as _metrics     # noqa: E402

@@ -112,31 +112,44 @@ class GatedMLP(HybridBlock):
 class GroupedQueryAttention(HybridBlock):
     """Causal self-attention with ``num_kv_heads`` key/value heads, each
     serving ``num_heads // num_kv_heads`` query heads, over
-    ``_contrib_flash_attention``. No bias, no position embedding;
-    ``sm_scale`` multiplies the scores (``None``: ``head_dim ** -0.5``)."""
+    ``_contrib_flash_attention``. No bias; ``sm_scale`` multiplies the
+    scores (``None``: ``head_dim ** -0.5``). By default no position
+    embedding and no norm; ``qk_norm``: q and k each through an RMSNorm
+    over each head (a weight of ``head_dim``, eps 1e-5), and
+    ``rope_theta``: then rotary over the whole head (rotate-half, that
+    base)."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim=None,
-                 sm_scale=None, **kwargs):
+                 sm_scale=None, qk_norm=False, rope_theta=None, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise MXNetError(f"num_heads {num_heads} is no multiple of "
                              f"num_kv_heads {num_kv_heads}")
         head_dim = head_dim or units // num_heads
         self._heads, self._kv_heads = num_heads, num_kv_heads
-        self._sm_scale = sm_scale
+        self._sm_scale, self._theta = sm_scale, rope_theta
         with self.name_scope():
             self.q_proj = _linear(num_heads * head_dim, units, "q_")
             self.k_proj = _linear(num_kv_heads * head_dim, units, "k_")
             self.v_proj = _linear(num_kv_heads * head_dim, units, "v_")
             self.o_proj = _linear(units, num_heads * head_dim, "o_")
+            self.q_norm = nn.RMSNorm(epsilon=1e-5, in_channels=head_dim,
+                                     prefix="q_norm_") if qk_norm else None
+            self.k_norm = nn.RMSNorm(epsilon=1e-5, in_channels=head_dim,
+                                     prefix="k_norm_") if qk_norm else None
 
     def hybrid_forward(self, F, x):
-        def heads(t, n):                # (B, S, n * D) -> (B, n, S, D)
-            return F.transpose(F.reshape(t, (0, 0, n, -1)), axes=(0, 2, 1, 3))
+        def heads(t, n, norm=None, turn=False):
+            t = F.reshape(t, (0, 0, n, -1))     # (B, S, n * D) -> (B, S, n, D)
+            if norm is not None:
+                t = norm(t)
+            if turn and self._theta is not None:
+                t = F.contrib.rotary_embedding(t, theta=self._theta)
+            return F.transpose(t, axes=(0, 2, 1, 3))    # -> (B, n, S, D)
 
         with device_scope("attention"):
-            q = heads(self.q_proj(x), self._heads)
-            k = heads(self.k_proj(x), self._kv_heads)
+            q = heads(self.q_proj(x), self._heads, self.q_norm, turn=True)
+            k = heads(self.k_proj(x), self._kv_heads, self.k_norm, turn=True)
             v = heads(self.v_proj(x), self._kv_heads)
             share = self._heads // self._kv_heads
             if share > 1:       # query head h reads key/value head h // share
@@ -216,19 +229,25 @@ class Mamba2Mixer(HybridBlock):
 class HybridDecoderLayer(HybridBlock):
     """``h + m * mixer(RMSNorm(h))`` then ``h + m * MLP(RMSNorm(h))``:
     pre-norm, residual multiplier ``m``; ``make_mixer(prefix=...)`` builds
-    a Mamba-2 mixer or grouped-query attention under this layer's names."""
+    the mixer (a Mamba-2 mixer, grouped-query attention ...) under this
+    layer's names, and ``make_mlp(prefix=...)`` the MLP (default a
+    :class:`GatedMLP` of ``hidden_size``). An MLP that returns ``(y,
+    routes, rows, scores)`` (routed experts) makes the layer return ``(h,
+    routes, rows, scores)``."""
 
     def __init__(self, make_mixer, units, hidden_size,
-                 residual_multiplier=1.0, epsilon=1e-5, **kwargs):
+                 residual_multiplier=1.0, epsilon=1e-5, make_mlp=None,
+                 **kwargs):
         super().__init__(**kwargs)
         self._multiplier = residual_multiplier
+        make_mlp = make_mlp or functools.partial(GatedMLP, units, hidden_size)
         with self.name_scope():
             self.input_norm = nn.RMSNorm(epsilon=epsilon, in_channels=units,
                                          prefix="input_norm_")
             self.mixer = make_mixer(prefix="mixer_")
             self.mlp_norm = nn.RMSNorm(epsilon=epsilon, in_channels=units,
                                        prefix="mlp_norm_")
-            self.mlp = GatedMLP(units, hidden_size, prefix="mlp_")
+            self.mlp = make_mlp(prefix="mlp_")
 
     def hybrid_forward(self, F, h):
         with device_scope("norm"):
@@ -236,7 +255,10 @@ class HybridDecoderLayer(HybridBlock):
         h = h + self._multiplier * self.mixer(x)
         with device_scope("norm"):
             x = self.mlp_norm(h)
-        return h + self._multiplier * self.mlp(x)
+        out = self.mlp(x)
+        if isinstance(out, (tuple, list)):
+            return (h + self._multiplier * out[0],) + tuple(out[1:])
+        return h + self._multiplier * out
 
 
 class GraniteHybridModel(HybridBlock):

@@ -5,11 +5,12 @@ no expert layer; ``gluon.contrib.nn.RoutedExperts`` is the block over them.
 - ``_contrib_moe_route``: scores over **all** the experts in float32
   (sigmoid of ``x W_r^T``, or its softmax over the experts), the ``top_k``
   largest of ``score + bias`` (the bias chooses and does not weigh), the
-  chosen scores normalised and scaled. With ``n_group`` > 1 the choice is
-  group-limited: the experts fall into ``n_group`` runs of equal length, a
-  group's score is the largest of its experts', and only the experts of the
-  ``topk_group`` best groups can be chosen. With ``capacity_factor`` > 0
-  the choice is dropped past this device's budget, as DeepSeek-V2 trains:
+  chosen scores divided by their sum plus ``norm_eps`` and scaled. With
+  ``n_group`` > 1 the choice is group-limited: the experts fall into
+  ``n_group`` runs of equal length, a group's score is the largest of its
+  experts', and only the experts of the ``topk_group`` best groups can be
+  chosen. With ``capacity_factor`` > 0 the choice is dropped past this
+  device's budget, as DeepSeek-V2 trains:
   of the pairs whose expert is one of the ``experts_held`` held here, the
   ``device_budget`` of largest score are kept and the others marked
   dropped (their id less E: no expert computes them, and E added gives
@@ -123,7 +124,8 @@ def _device_drop(ids, scores, first, held, capacity_factor):
                   OpParam("topk_group", int, 1),
                   OpParam("capacity_factor", float, 0.0),
                   OpParam("first_expert", int, 0),
-                  OpParam("experts_held", int, 0)],
+                  OpParam("experts_held", int, 0),
+                  OpParam("norm_eps", float, 1e-20)],
           doc="Router of a routed-expert layer. Inputs: x (..., U), the "
               "router's weight (E, U), the bias of the choice (E,). Scores "
               "= sigmoid(x W^T) (scoring 'softmax': its softmax over the E "
@@ -131,9 +133,9 @@ def _device_drop(ids, scores, first, held, capacity_factor):
               "largest of scores + bias, among the experts of the "
               "topk_group groups (n_group runs of E / n_group experts) "
               "whose largest scores + bias are largest when n_group > 1; "
-              "weights = the chosen scores, divided by their sum (+1e-20) "
-              "if norm_topk_prob, times scaling_factor. capacity_factor > 0: "
-              "of the pairs naming the experts_held experts from "
+              "weights = the chosen scores, divided by their sum plus "
+              "norm_eps if norm_topk_prob, times scaling_factor. "
+              "capacity_factor > 0: of the pairs naming the experts_held experts from "
               "first_expert on, those past capacity_factor times their "
               "share of the pairs, lowest score first, are dropped: their "
               "id less E. Returns (weights (..., k) float32, ids (..., k) "
@@ -141,7 +143,7 @@ def _device_drop(ids, scores, first, held, capacity_factor):
 def _moe_route(x, router, bias, top_k=2, norm_topk_prob=True,
                scaling_factor=1.0, scoring="sigmoid", n_group=1,
                topk_group=1, capacity_factor=0.0, first_expert=0,
-               experts_held=0):
+               experts_held=0, norm_eps=1e-20):
     if router.ndim != 2 or router.shape[1] != x.shape[-1]:
         raise MXNetError(f"moe_route: x (..., U) and router (E, U) "
                          f"expected, got {x.shape} and {router.shape}")
@@ -174,7 +176,7 @@ def _moe_route(x, router, bias, top_k=2, norm_topk_prob=True,
         scores, jnp.mod(ids, scores.shape[-1]) if capacity_factor > 0
         else ids, axis=-1)
     if norm_topk_prob:
-        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + norm_eps)
     return weights * scaling_factor, ids, scores
 
 

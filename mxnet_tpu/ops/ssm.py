@@ -70,22 +70,28 @@ def _act(x, act_type):
     raise MXNetError(f"unknown act_type {act_type!r}; one of silu, identity")
 
 
-@register("_contrib_causal_conv1d", num_inputs=3,
-          params=[OpParam("act_type", str, "silu")],
+@register("_contrib_causal_conv1d", num_inputs=-1,
+          params=[OpParam("act_type", str, "silu"),
+                  OpParam("no_bias", bool, False)],
           doc="Depthwise causal 1-D convolution over (B, L, C) with weight "
               "(C, K) and bias (C,): out[t] = act(bias + sum_j w[:, j] * "
-              "x[t + j - (K - 1)]), zeros before the sequence. Accumulated "
-              "in float32, returned in x's dtype.")
-def _causal_conv1d(x, weight, bias, act_type="silu"):
+              "x[t + j - (K - 1)]), zeros before the sequence; no_bias: no "
+              "bias input and no bias term. Accumulated in float32, "
+              "returned in x's dtype.")
+def _causal_conv1d(x, weight, *bias, act_type="silu", no_bias=False):
     if x.ndim != 3 or weight.ndim != 2 or weight.shape[0] != x.shape[-1]:
         raise MXNetError(f"causal_conv1d: x (B, L, C) and weight (C, K) "
                          f"expected, got {x.shape} and {weight.shape}")
+    if len(bias) != (0 if no_bias else 1):
+        raise MXNetError(f"causal_conv1d: {len(bias)} bias inputs with "
+                         f"no_bias={no_bias}")
     taps, length = weight.shape[1], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     w = weight.astype(_F32)
-    acc = bias.astype(_F32)
+    acc = None if no_bias else bias[0].astype(_F32)
     for j in range(taps):
-        acc = acc + padded[:, j:j + length].astype(_F32) * w[:, j]
+        term = padded[:, j:j + length].astype(_F32) * w[:, j]
+        acc = term if acc is None else acc + term
     return _act(acc, act_type).astype(x.dtype)
 
 

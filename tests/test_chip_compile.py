@@ -515,3 +515,76 @@ def test_embedding_grad_compiles_at_the_cells_tables(one_chip, monkeypatch,
     text = _compile(jax.grad(loss), table, ids).as_text()
     assert text.count("tpu_custom_call") == 1
     assert pallas.tier_provenance()["embedding_grad"]["pallas"] - before == 1
+
+
+def test_lfm2_training_step_compiles_at_the_cells_shapes(one_chip,
+                                                         monkeypatch):
+    """One training step of the lfm2_24b_a2b cell as the chip runs it: the
+    nine layers at published widths with every layer recomputed, two
+    8192-token sequences, the loss's gradient and an Adam update of the bf16
+    weights and moments (donated, as the trainer's step donates them). The
+    compiler's memory analysis says what the step needs besides the state:
+    the program must fit the chip's 16 GB, and PERF.md records both numbers
+    (arguments and temporaries) before the cell's first chip run."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import autograd
+    from mxnet_tpu.gluon.block import functional_apply
+    from mxnet_tpu.gluon.model_zoo import lfm2_moe
+    from mxnet_tpu.pallas import registry
+    from chipbench import manifest
+    from chipbench.models import lfm2_24b_a2b as lm
+    monkeypatch.setattr(registry, "runs_on", lambda args: ("tpu", True))
+    args = manifest.load_config(manifest.load_manifest(),
+                                "lfm2_24b_a2b")["args"]
+    held, width = lm.experts_held(args)
+    net = lfm2_moe.lfm2_moe(
+        experts_held=held, return_routes=True, recompute=True,
+        num_experts=width, expert_bias_update_rate=1e-3,
+        **{key: args[key] for key in lm.MODEL_KEYS})
+    trainable, aux = net._param_split()
+    # nothing is allocated: the parameters are only told where they would
+    # live, and the step binds abstract arrays to them
+    for p in trainable + aux:
+        p._ctx_list = [mx.cpu()]
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    weights = [sds(p.shape) for p in trainable]
+    buffers = [sds(p.shape, jnp.dtype(p.dtype)) for p in aux]
+    tokens = sds((2, 8192), jnp.int32)
+
+    def step(weights, moments, buffers, tokens, labels):
+        def loss(weights):
+            outs, _, new = functional_apply(net, jax.random.key(0), weights,
+                                            buffers, [tokens], training=True)
+            logp = jax.nn.log_softmax(outs[0].astype(jnp.float32), -1)
+            return -jnp.mean(jnp.take_along_axis(logp, labels[..., None],
+                                                 -1)), new
+
+        with autograd.pause(train_mode=True):
+            (value, new), grads = jax.value_and_grad(loss, has_aux=True)(
+                weights)
+        out_w, out_m = [], []
+        for w, (m, v), g in zip(weights, moments, grads):
+            g = g.astype(jnp.float32)
+            m = 0.9 * m.astype(jnp.float32) + 0.1 * g
+            v = 0.95 * v.astype(jnp.float32) + 0.05 * g * g
+            w = w.astype(jnp.float32) * (1 - 2e-5) - 2e-4 * m / (
+                jnp.sqrt(v) + 1e-8)
+            out_w.append(w.astype(jnp.bfloat16))
+            out_m.append((m.astype(jnp.bfloat16), v.astype(jnp.bfloat16)))
+        return value, out_w, out_m, new
+
+    compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+        weights, [(w, w) for w in weights], buffers, tokens, tokens).compile()
+    memory = compiled.memory_analysis()
+    arguments = memory.argument_size_in_bytes
+    temporaries = memory.temp_size_in_bytes
+    print(f"lfm2_24b_a2b step for a v5e: arguments {arguments} B, "
+          f"temporaries {temporaries} B")
+    # the state: 832,651,520 bf16 weights and two bf16 moments, 5.0 GB
+    assert arguments == pytest.approx(3 * 2 * 832_651_520, rel=0.01)
+    assert arguments + temporaries < 15.5e9
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 8       # the grouped products
