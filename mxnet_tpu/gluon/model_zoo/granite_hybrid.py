@@ -151,10 +151,7 @@ class GroupedQueryAttention(HybridBlock):
             q = heads(self.q_proj(x), self._heads, self.q_norm, turn=True)
             k = heads(self.k_proj(x), self._kv_heads, self.k_norm, turn=True)
             v = heads(self.v_proj(x), self._kv_heads)
-            share = self._heads // self._kv_heads
-            if share > 1:       # query head h reads key/value head h // share
-                k = F.repeat(k, repeats=share, axis=1)
-                v = F.repeat(v, repeats=share, axis=1)
+            # query head h reads key/value head h // (heads // kv_heads)
             out = F.contrib.flash_attention(q, k, v, causal=True,
                                             sm_scale=self._sm_scale)
             out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)), (0, 0, -1))

@@ -58,6 +58,22 @@ def scope_of(op_name):
     return found[-1] if found else None
 
 
+def _logical_lines(text):
+    """The text's lines, with an instruction printed over several lines
+    joined into one: a custom call's frontend attributes may hold line
+    breaks (the splash attention kernel's ``kernel_metadata``), and the
+    lines after the first start with neither an indent nor ``%``."""
+    lines = []
+    for line in text.splitlines():
+        if lines and line and not line[0].isspace() and \
+                line.rstrip() != "}" and not _MODULE.match(line) and \
+                not _COMPUTATION.match(line) and _INSTRUCTION.match(lines[-1]):
+            lines[-1] += line
+        else:
+            lines.append(line)
+    return lines
+
+
 def scopes_of_program(text) -> dict:
     """``{"module": <HLO module name>, "scopes": {instruction: scope}}`` from
     optimized HLO text. Only instructions with a scope are listed; names are
@@ -65,7 +81,7 @@ def scopes_of_program(text) -> dict:
     module = None
     own, roots, fused_by = {}, {}, {}
     computation = None
-    for line in text.splitlines():
+    for line in _logical_lines(text):
         if module is None:
             m = _MODULE.match(line)
             if m:

@@ -313,18 +313,21 @@ FLASH_COUNT_METRIC = "mxnet_tpu_flash_attention_traced_total"
 
 
 def _count_traced_attention(branch, block_q="", block_k="", qk=0, v=0,
-                            padded=""):
+                            padded="", groups=1):
     """One attention call traced into a program, by the branch it took
-    (``dense``, ``tpu_kernel``, ``portable``), that branch's query and key
-    tiles, the query/key and value head sizes, and what was padded with
-    zeros to reach the kernel (``v``: the value head to the query's; empty:
-    nothing): trace-time only, so a compiled step never counts."""
+    (``dense``, ``tpu_kernel``, ``tpu_splash``, ``portable``), that
+    branch's query and key tiles, the query/key and value head sizes, what
+    was padded with zeros to reach the kernel (``v``: the value head to the
+    query's; empty: nothing), and how many query heads share a key/value
+    head (1: heads equal): trace-time only, so a compiled step never
+    counts."""
     from ..observability.metrics import default_registry
     default_registry().counter(
         FLASH_COUNT_METRIC, "flash-attention calls traced into a program",
-        ("branch", "block_q", "block_k", "qk", "v", "padded")).labels(
+        ("branch", "block_q", "block_k", "qk", "v", "padded",
+         "groups")).labels(
             branch=branch, block_q=str(block_q), block_k=str(block_k),
-            qk=str(qk), v=str(v), padded=padded).inc()
+            qk=str(qk), v=str(v), padded=padded, groups=str(groups)).inc()
 
 
 def _tpu_flash_attention(q, k, v, causal, scale):
@@ -414,6 +417,74 @@ def _library_flash_bwd(causal, scale, vjp, g):
 _library_flash.defvjp(_library_flash_fwd, _library_flash_bwd)
 
 
+# The splash kernel's tiles (PERF.md sec. 6: the sweep on the chip at the
+# three grouped shapes of the benchmark, where one set was the fastest at
+# every shape and the fused backward beat the library's two kernels by
+# 11-17% at every tile). Query and key tiles of a grid step: the largest
+# multiple of 128 up to the cap that divides the sequence; one product's
+# key tile: up to SPLASH_KV_COMPUTE_CAP.
+SPLASH_Q_CAP = 1024
+SPLASH_KV_CAP = 1024
+SPLASH_KV_COMPUTE_CAP = 512
+
+
+def _splash_tiles(s):
+    """The splash kernel's tiles for causal self-attention over ``s``
+    tokens: one set for the forward and the backward, whose dq, dk and dv
+    come from one fused kernel (one recomputation of the scores)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash)
+    q = _largest_tile(s, SPLASH_Q_CAP)
+    kv = _largest_tile(s, SPLASH_KV_CAP)
+    kv_compute = _largest_tile(kv, SPLASH_KV_COMPUTE_CAP)
+    return splash.BlockSizes(
+        block_q=q, block_kv=kv, block_kv_compute=kv_compute,
+        block_q_dkv=q, block_kv_dkv=kv, block_kv_dkv_compute=kv_compute,
+        use_fused_bwd_kernel=True)
+
+
+def _splash_call(q, k, v, scale, interpret=False):
+    """Causal self-attention of [B, Hq, S, D] queries over [B, Hkv, S, D]
+    keys and values on JAX's splash kernel, query head ``h`` reading
+    key/value head ``h // (Hq // Hkv)``: the causal mask as a block map
+    (blocks above the diagonal neither fetched nor computed, the mask
+    applied on the diagonal's alone), the scale folded into q (the kernel
+    takes none), one kernel over the heads and the batch mapped over."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash, splash_attention_mask as masks)
+    heads, s = q.shape[1], q.shape[2]
+    # the library caches the block map by mask and tiles (process_mask)
+    kernel = splash.make_splash_mha_single_device(
+        masks.MultiHeadMask([masks.CausalMask((s, s))] * heads),
+        block_sizes=_splash_tiles(s),
+        interpret=interpret)
+    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    return jax.vmap(kernel)(q, k, v)
+
+
+# custom_vjp for the same reason as _library_flash's: the precision scope
+# has to hold over the backward kernels' trace too.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _splash_flash(q, k, v, scale, interpret=False):
+    with _flash_precision(q):
+        return _splash_call(q, k, v, scale, interpret)
+
+
+def _splash_flash_fwd(q, k, v, scale, interpret):
+    with _flash_precision(q):
+        return jax.vjp(
+            lambda q, k, v: _splash_call(q, k, v, scale, interpret),
+            q, k, v)
+
+
+def _splash_flash_bwd(scale, interpret, vjp, g):
+    with _flash_precision(g):
+        return vjp(g)
+
+
+_splash_flash.defvjp(_splash_flash_fwd, _splash_flash_bwd)
+
+
 def _kernel_head(d):
     """The head size the library kernel is called with for heads of ``d``:
     ``d`` up to 128, past it the next multiple of 128 (the kernel refuses
@@ -421,12 +492,26 @@ def _kernel_head(d):
     return d if d <= 128 else -(-d // 128) * 128
 
 
-def attention_branch(q, k, v):
+def _key_value_groups(q, k):
+    """How many query heads share one key/value head of [B, H, S, D]
+    operands (1 for [B, S, D], which carry no head axis)."""
+    if q.ndim != 4:
+        return 1
+    if q.shape[1] % k.shape[1]:
+        raise MXNetError(
+            f"flash_attention: {q.shape[1]} query heads are no multiple of "
+            f"{k.shape[1]} key/value heads")
+    return q.shape[1] // k.shape[1]
+
+
+def attention_branch(q, k, v, causal=False):
     """The branch ``_contrib_flash_attention`` takes for these operands
-    (arrays or tracers of [B, H, S, D]): ``dense`` up to 1024 keys, else
-    ``tpu_kernel`` where they run on a TPU with the kernel tier on and the
-    library kernel takes their shapes (a value head no wider than the
-    query's; a head past 128 is padded to a multiple of 128), else
+    (arrays or tracers of [B, H, S, D]): ``dense`` up to 1024 keys, else,
+    where they run on a TPU with the kernel tier on and a kernel takes
+    their shapes (a value head no wider than the query's; a head past 128
+    is padded to a multiple of 128), ``tpu_splash`` for causal
+    self-attention with fewer key/value heads than query heads (bf16, or
+    float32 heads of up to 128) and ``tpu_kernel`` for the rest, else
     ``portable``."""
     if k.shape[-2] <= 1024:
         return "dense"
@@ -436,6 +521,12 @@ def attention_branch(q, k, v):
             q.shape[-2] % 128 == 0 and k.shape[-2] % 128 == 0 and \
             q.shape[-1] >= 64 and v.shape[-1] <= q.shape[-1] and \
             q.dtype in (jnp.bfloat16, jnp.float32):
+        # float32 heads past 128 run the splash kernel's backward out of
+        # fast memory at its tiles (tests/test_chip_compile.py)
+        if causal and q.shape[-2] == k.shape[-2] and \
+                _key_value_groups(q, k) > 1 and \
+                (q.dtype == jnp.bfloat16 or q.shape[-1] <= 128):
+            return "tpu_splash"
         return "tpu_kernel"
     return "portable"
 
@@ -448,13 +539,25 @@ def attention_branch(q, k, v):
               "memory-efficient long-context attention (net-new TPU "
               "capability, SURVEY §5.7; no reference analog — MXNet 1.x "
               "used full attention). v may have a head size of its own "
-              "(the output's). Sequence-parallel variant: "
+              "(the output's). k and v may have fewer heads than q "
+              "(grouped-query attention): a number that divides q's, "
+              "query head h reading key/value head h // (Hq // Hkv). "
+              "Sequence-parallel variant: "
               "mxnet_tpu.parallel.ring_attention.")
 def _flash_attention(q, k, v, block_size=512, causal=False, sm_scale=None):
     from ..parallel.ring_attention import blockwise_attention
     scale = float(q.shape[-1]) ** -0.5 if sm_scale is None else sm_scale
-    sizes = dict(qk=q.shape[-1], v=v.shape[-1])
-    branch = attention_branch(q, k, v)
+    groups = _key_value_groups(q, k)
+    sizes = dict(qk=q.shape[-1], v=v.shape[-1], groups=groups)
+    branch = attention_branch(q, k, v, causal)
+
+    def shared(k, v):
+        """k and v repeated to the query's heads, for the paths that take
+        one head count."""
+        if groups == 1:
+            return k, v
+        return jnp.repeat(k, groups, axis=1), jnp.repeat(v, groups, axis=1)
+
     if branch == "dense":
         # short KV: one fused softmax(QK^T)V straight on the MXU via the
         # shared dense-attention definition (attention_reference — one
@@ -465,42 +568,56 @@ def _flash_attention(q, k, v, block_size=512, causal=False, sm_scale=None):
         # docs/perf_notes.md).
         from ..parallel.ring_attention import attention_reference
         _count_traced_attention("dense", **sizes)
-        return attention_reference(q, k, v, causal=causal, scale=scale)
-    # on TPU hardware route to the library's hand-tiled Pallas kernel
-    # (MXU-tiled blocks, VMEM-resident online softmax), called with the
-    # tiles _flash_tiles reads from (S_q, S_kv, D, dtype) and not the
-    # library's 128 everywhere; the jnp blockwise kernel is the portable
-    # path and the CPU-test oracle, and ``block_size`` is its key/value
-    # block and nothing else's. What the library kernel raises is raised:
-    # a quiet second path would hide a slower run on the chip
-    # (tests/test_chip_compile.py compiles this call for a v5e). Inside
-    # jit the platform is only known at lowering (pallas.runs_on), so
-    # there both paths are staged and the lowering keeps one.
+        return attention_reference(q, *shared(k, v), causal=causal,
+                                   scale=scale)
+    # on TPU hardware route to a library Pallas kernel: grouped causal
+    # self-attention to the splash kernel, which reads each key/value head
+    # for its group of query heads and computes only the blocks on or
+    # under the diagonal, at the tiles _splash_tiles gives; the rest to
+    # the hand-tiled flash kernel (MXU-tiled blocks, VMEM-resident online
+    # softmax), called with the tiles _flash_tiles reads from (S_q, S_kv,
+    # D, dtype) and not the library's 128 everywhere. The jnp blockwise
+    # kernel is the portable path and the CPU-test oracle, and
+    # ``block_size`` is its key/value block and nothing else's. What a
+    # library kernel raises is raised: a quiet second path would hide a
+    # slower run on the chip (tests/test_chip_compile.py compiles these
+    # calls for a v5e). Inside jit the platform is only known at lowering
+    # (pallas.runs_on), so there both paths are staged and the lowering
+    # keeps one.
     from ..pallas.registry import runs_on
 
     def portable(q, k, v):
-        return blockwise_attention(q, k, v, block_size=block_size,
+        return blockwise_attention(q, *shared(k, v), block_size=block_size,
                                    causal=causal, scale=scale)
 
-    if branch == "tpu_kernel":
+    if branch in ("tpu_kernel", "tpu_splash"):
         head = _kernel_head(q.shape[-1])
         width = v.shape[-1]
 
+        def kernel(q, k, v):
+            if branch == "tpu_splash":
+                return _splash_flash(q, k, v, scale)
+            return _tpu_flash_attention(q, *shared(k, v), causal, scale)
+
         def on_tpu(q, k, v):
             if head == q.shape[-1] == width:
-                return _tpu_flash_attention(q, k, v, causal, scale)
-            # the library kernel takes one head size for q, k and v, and
-            # past 128 only whole multiples of 128: the heads ride in zeros
-            # to that size (zeros add nothing to a score; the scale is
-            # given), and the columns the value's zeros give are cut off
+                return kernel(q, k, v)
+            # the kernels take one head size for q, k and v, and past 128
+            # only whole multiples of 128: the heads ride in zeros to that
+            # size (zeros add nothing to a score; the scale is given), and
+            # the columns the value's zeros give are cut off
             def padded(t):
                 return jnp.pad(t, [(0, 0)] * (t.ndim - 1)
                                + [(0, head - t.shape[-1])])
-            return _tpu_flash_attention(padded(q), padded(k), padded(v),
-                                        causal, scale)[..., :width]
-        tiles = _flash_tiles(q.shape[-2], k.shape[-2], head, q.dtype)
+            return kernel(padded(q), padded(k), padded(v))[..., :width]
+        if branch == "tpu_splash":
+            tiles = _splash_tiles(q.shape[-2])
+            block_q, block_k = tiles.block_q, tiles.block_kv
+        else:
+            tiles = _flash_tiles(q.shape[-2], k.shape[-2], head, q.dtype)
+            block_q, block_k = tiles.block_q, tiles.block_k_major
         _count_traced_attention(
-            "tpu_kernel", tiles.block_q, tiles.block_k_major,
+            branch, block_q, block_k,
             padded=("qkv" if head > q.shape[-1] else "v"
                     if width < head else ""), **sizes)
         if runs_on((q, k, v))[1]:

@@ -108,7 +108,8 @@ def test_flash_tiles_read_the_shape():
 def test_flash_attention_counts_the_branch_it_traced(s, branch, block_k):
     from mxnet_tpu import observability
     from mxnet_tpu.ops.contrib import FLASH_COUNT_METRIC
-    key = f"branch={branch},block_q=,block_k={block_k},qk=64,v=64,padded="
+    key = (f"branch={branch},block_q=,block_k={block_k},qk=64,v=64,padded=,"
+           "groups=1")
 
     def count():
         return observability.snapshot()["metrics"].get(

@@ -293,6 +293,55 @@ def test_latent_attention_core_compiles_at_the_cells_shape(one_chip,
     assert grad.as_text().count("tpu_custom_call") >= 3
 
 
+# grouped-query attention as the cells call it: (batch, query heads,
+# key/value heads, tokens, query/key head, value head, dtype), and the
+# branch it takes; DeepSeek-V2's latent core has equal heads and stays on
+# the library kernel; float32 grouped heads of 128 take the splash kernel
+GROUPED_SHAPES = {
+    "lfm2_24b_a2b": ((2, 32, 8, 8192, 64, 64, jnp.bfloat16), "tpu_splash"),
+    "granite_4_0_h_micro": ((1, 32, 8, 4096, 64, 64, jnp.bfloat16),
+                            "tpu_splash"),
+    "nemotron_3_nano_30b_a3b": ((1, 32, 2, 8192, 128, 128, jnp.bfloat16),
+                                "tpu_splash"),
+    "deepseek_v2": ((1, 8, 8, 8192, 192, 128, jnp.bfloat16), "tpu_kernel"),
+    "f32_s4096_d128": ((1, 32, 8, 4096, 128, 128, jnp.float32),
+                       "tpu_splash"),
+}
+
+
+@pytest.mark.parametrize("cell", list(GROUPED_SHAPES))
+def test_grouped_attention_compiles_on_its_branch(one_chip, monkeypatch,
+                                                  cell):
+    """``_contrib_flash_attention`` at each cell's attention shape, causal,
+    forward and ``jax.grad``: grouped heads on the splash kernel (its
+    forward and its fused backward), equal heads on the library kernel."""
+    from mxnet_tpu.ops import contrib
+    from mxnet_tpu.pallas import registry
+    (b, hq, hkv, s, qk, vd, dtype), branch = GROUPED_SHAPES[cell]
+    monkeypatch.setattr(registry, "runs_on", lambda args: ("tpu", False))
+
+    def struct(heads, d):
+        return jax.ShapeDtypeStruct((b, heads, s, d), dtype,
+                                    sharding=one_chip)
+
+    q, k, v = struct(hq, qk), struct(hkv, qk), struct(hkv, vd)
+
+    def fwd(q, k, v):
+        return contrib._flash_attention(q, k, v, causal=True)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    assert contrib.attention_branch(q, k, v, causal=True) == branch
+    assert jax.eval_shape(fwd, q, k, v).shape == (b, hq, s, vd)
+    forward = _compile(fwd, q, k, v).as_text()
+    backward = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, v).as_text()
+    assert "tpu_custom_call" in forward
+    assert backward.count("tpu_custom_call") >= (
+        3 if branch == "tpu_kernel" else 2)
+    assert ("splash_mha" in forward + backward) == (branch == "tpu_splash")
+
+
 def test_gated_routed_experts_compile_forward_and_backward(one_chip,
                                                            monkeypatch):
     """``_contrib_moe_experts`` with gated experts at the deepseek_v2 cell's

@@ -144,7 +144,8 @@ def test_flash_attention_takes_a_value_head_smaller_than_the_query(seq):
     branch = "dense" if seq <= 1024 else "portable"
     assert attention_branch(q, k, v) == branch
     key = (f"branch={branch},block_q=,block_k="
-           f"{'' if branch == 'dense' else 512},qk=192,v=128,padded=")
+           f"{'' if branch == 'dense' else 512},qk=192,v=128,padded=,"
+           "groups=1")
 
     def count():
         return observability.snapshot()["metrics"].get(
